@@ -1,0 +1,432 @@
+#!/usr/bin/env python
+"""The quickest proof that tpu_dp still starts on the chip.
+
+    python chip_smoke.py            # one TPU chip (what the driver runs)
+    python chip_smoke.py --chips 4  # one four-chip host: data parallelism
+
+One process; it imports JAX once and starts no child. It exits non-zero —
+with no result line — unless JAX finds a TPU whose kind `tpu_dp.obs.chips`
+knows, and any phase that raises ends the run: nothing is caught.
+
+Default run (one chip):
+
+1. the trainer through `train.main`: ResNet-18 at full width, bf16
+   compute, batch 2048, synthetic data, two epochs of eight steps, eval, a
+   checkpoint, then ``--resume=auto`` for one more epoch;
+2. each Pallas kernel, compiled, against its `jax.numpy` statement at the
+   shapes the trainer gives it;
+3. the trainer once more with ``--train.pallas_xent=true``.
+
+``--chips 4`` runs only the data-parallel comparison: ten steps of the
+same seed and batch stream on one device, on four with the replicated
+(GSPMD) update, and on four with the sharded update; per-step losses must
+agree, state and batch must really be spread over the four devices, and
+the compiled steps must hold the collectives each mode is made of.
+
+The times printed are smoke readings of one short run, not a benchmark.
+The last line of stdout is the result:
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import contextlib
+import io
+import json
+import math
+import shutil
+import sys
+from pathlib import Path
+
+WORK = Path(__file__).resolve().parent / ".chip_smoke"
+
+#: Per-step |loss - loss on one device| allowed in the ``--chips 4``
+#: comparison. The three runs differ only in the order f32 partial sums
+#: are added (per-device then across devices); ten steps of ResNet-18
+#: amplify that to ~1e-5 on a CPU mesh (`tests/test_shard_update.py`,
+#: atol 2e-5) and somewhat more through the MXU's bf16 passes. A gradient
+#: scaled or reduced wrongly moves the loss by >1e-1 within three steps.
+DP_LOSS_ATOL = 2e-3
+
+
+class _Tee(io.TextIOBase):
+    """stdout that also keeps what passes through (train.main's summary)."""
+
+    def __init__(self, stream):
+        self.stream, self.kept = stream, io.StringIO()
+
+    def write(self, s):
+        self.kept.write(s)
+        return self.stream.write(s)
+
+    def flush(self):
+        self.stream.flush()
+
+
+class _CompileClock:
+    """Sums JAX's own compile events: backend seconds, cache hits/misses."""
+
+    def __init__(self):
+        from jax import monitoring
+
+        self.seconds, self.hits, self.requests = 0.0, 0, 0
+        monitoring.register_event_duration_secs_listener(self._duration)
+        monitoring.register_event_listener(self._event)
+
+    def _duration(self, event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.seconds += secs
+
+    def _event(self, event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif event == "/jax/compilation_cache/compile_requests_use_cache":
+            self.requests += 1
+
+    def lap(self):
+        out = (self.seconds, self.hits, self.requests)
+        self.seconds, self.hits, self.requests = 0.0, 0, 0
+        return out
+
+
+def run_trainer(argv, ckpt_dir):
+    """`train.main(argv)`; returns its JSON summary and epoch records."""
+    import train
+
+    tee = _Tee(sys.stdout)
+    with contextlib.redirect_stdout(tee):
+        rc = train.main([*argv, f"--train.ckpt_dir={ckpt_dir}"])
+    assert rc == 0, f"train.main returned {rc}"
+    summary = json.loads(tee.kept.getvalue().strip().splitlines()[-1])
+    records = [json.loads(line) for line in
+               (Path(ckpt_dir) / "metrics.jsonl").read_text().splitlines()]
+    epochs = [r for r in records if "epoch" in r and "loss" in r]
+    return summary, epochs
+
+
+def _check_run(summary, epochs, batch):
+    assert summary["devices"] == 1, summary
+    assert summary["synthetic"] is True, summary
+    losses = [e["loss"] for e in epochs]
+    assert losses and all(math.isfinite(x) for x in losses), losses
+    ev = summary["eval"]
+    assert math.isfinite(ev["loss"]) and 0.0 <= ev["accuracy"] <= 1.0, ev
+    return batch / summary["images_per_sec"] * 1e3  # steady ms/step
+
+
+def _trainer_argv(model, batch, steps):
+    return [
+        f"--model.name={model}", "--model.bf16=true",
+        "--data.dataset=synthetic", f"--data.batch_size={batch}",
+        f"--data.synthetic_train_size={batch * steps}",
+        f"--data.synthetic_test_size={batch}",
+        "--optim.lr=0.05", f"--train.log_every={steps // 2}",
+    ]
+
+
+def phase_trainer(clock, *, model="resnet18", batch=2048, steps=8,
+                  extra=()):
+    """Train two epochs, eval, checkpoint; resume for a third."""
+    ckpt = WORK / "train"
+    argv = [*_trainer_argv(model, batch, steps), *extra]
+    clock.lap()
+    summary, epochs = run_trainer([*argv, "--train.epochs=2"], ckpt)
+    cold_s, _, _ = clock.lap()
+    step_ms = _check_run(summary, epochs, batch)
+    assert [e["epoch"] for e in epochs] == [1, 2], epochs
+    assert epochs[-1]["loss"] < epochs[0]["loss"], (
+        f"loss did not fall: {[e['loss'] for e in epochs]}")
+    saved = sorted(p.name for p in ckpt.glob("step_*"))
+    assert saved, f"no checkpoint under {ckpt}"
+    print(f"smoke reading, first run: compile {cold_s:.1f} s (cold cache), "
+          f"steady {step_ms:.2f} ms/step, "
+          f"{summary['images_per_sec']:.0f} images/s, "
+          f"epoch losses {[round(e['loss'], 4) for e in epochs]}, "
+          f"eval acc {summary['eval']['accuracy']:.3f}, "
+          f"checkpoints {saved}")
+
+    summary, epochs = run_trainer(
+        [*argv, "--train.epochs=3", "--resume=auto"], ckpt)
+    warm_s, hits, requests = clock.lap()
+    _check_run(summary, epochs, batch)
+    assert [e["epoch"] for e in epochs] == [1, 2, 3], (
+        f"resumed run did not start at epoch 3: {epochs}")
+    assert hits > 0, "resumed run compiled everything again: cache unused"
+    print(f"smoke reading, resumed run: epoch 3 loss "
+          f"{epochs[-1]['loss']:.4f}; compile {warm_s:.1f} s with "
+          f"{hits}/{requests} programs served from the compile cache "
+          f"(cold {cold_s:.1f} s | warm {warm_s:.1f} s)")
+
+
+def _assert_kernel(fn, args, name):
+    """Compile ``fn``; its program must hold a Mosaic kernel. Returns
+    the results."""
+    import jax
+
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), (
+        f"{name}: no tpu_custom_call in the compiled program")
+    return compiled(*args)
+
+
+def _close(got, want, atol, name):
+    """max|got - want| <= atol * max|want|, per array."""
+    import numpy as np
+
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert got.shape == want.shape, (name, got.shape, want.shape)
+    assert np.isfinite(got).all(), f"{name}: non-finite output"
+    scale = max(float(np.abs(want).max()), 1e-30)
+    err = float(np.abs(got - want).max()) / scale
+    assert err <= atol, f"{name}: {err:.3e} of max|ref| > {atol:.0e}"
+    return err
+
+
+def phase_kernels():
+    """Each kernel, compiled on the chip, against its jnp statement."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_dp.ops import conv_block, xent
+
+    def xent_ref(logits, labels):
+        logits = logits.astype(jnp.float32)
+        true = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
+        return jax.nn.logsumexp(logits, axis=-1) - true
+
+    for batch, classes in ((2048, 10), (1024, 100)):
+        k1, k2 = jax.random.split(jax.random.PRNGKey(classes))
+        logits = (jax.random.normal(k1, (batch, classes)) * 3).astype(
+            jnp.bfloat16)
+        labels = jax.random.randint(k2, (batch,), 0, classes, jnp.int32)
+        name = f"xent ({batch},{classes}) bf16"
+        fwd = _assert_kernel(xent.softmax_xent, (logits, labels), name)
+        bwd = _assert_kernel(
+            jax.grad(lambda lg, lb: jnp.sum(xent.softmax_xent(lg, lb))),
+            (logits, labels), name + " bwd")
+        ref_bwd = jax.grad(lambda lg: jnp.sum(xent_ref(lg, labels)))(logits)
+        # Forward is f32 on both sides (1e-5: exp/log differ in the last
+        # bits); the gradient is rounded to bf16 (one ulp of 1.0 = 8e-3).
+        e_f = _close(fwd, xent_ref(logits, labels), 1e-5, name)
+        e_b = _close(bwd, ref_bwd, 1e-2, name + " bwd")
+        print(f"kernel {name}: fwd err {e_f:.1e}, bwd err {e_b:.1e} "
+              f"(of max|ref|)")
+
+    ks = jax.random.split(jax.random.PRNGKey(7), 5)
+    shape = (256, 32, 32, 64)
+    x = jax.random.normal(ks[0], shape, jnp.bfloat16)
+    res = jax.random.normal(ks[1], shape, jnp.bfloat16)
+    w = (jax.random.normal(ks[2], (3, 3, 64, 64)) / 24.0).astype(
+        jnp.bfloat16)  # 1/sqrt(9*64): outputs of order one
+    scale = jax.random.normal(ks[3], (64,)) * 0.5 + 1.0
+    shift = jax.random.normal(ks[4], (64,)) * 0.1
+
+    name = f"conv_block {shape} plain"
+    y = _assert_kernel(
+        lambda *a: conv_block.fused_affine_relu_conv(*a, None),
+        (x, w, scale, shift), name)
+    # One bf16 ulp at the top of the output range, as tests/test_conv_block.
+    e = _close(y, conv_block.reference_affine_relu_conv(x, w, scale, shift),
+               1e-2, name)
+    print(f"kernel {name}: err {e:.1e} (of max|ref|)")
+
+    name = f"conv_block {shape} residual+z+stats"
+    y, z, stats = _assert_kernel(
+        lambda *a: conv_block.fused_conv_bn(*a, emit_z=True),
+        (x, w, scale, shift, res), name)
+    y_ref = conv_block.reference_affine_relu_conv(x, w, scale, shift, res)
+    z_ref = jnp.maximum(
+        x.astype(jnp.float32) * scale + shift + res.astype(jnp.float32),
+        0.0).astype(jnp.bfloat16)
+    yf = y_ref.astype(jnp.float32)
+    stats_ref = jnp.stack([jnp.sum(yf, axis=(0, 1, 2)),
+                           jnp.sum(yf * yf, axis=(0, 1, 2))])
+    e_y = _close(y, y_ref, 1e-2, name + " y")
+    e_z = _close(z, z_ref, 1e-2, name + " z")
+    # Sums of 262,144 values that may each differ by a bf16 ulp: the
+    # sum of squares (the larger row, which sets the scale) to 2e-3.
+    e_s = _close(stats, stats_ref, 2e-3, name + " stats")
+    print(f"kernel {name}: y err {e_y:.1e}, z err {e_z:.1e}, "
+          f"stats err {e_s:.1e} (of max|ref|)")
+
+
+def phase_trainer_pallas_xent(clock, *, model="resnet18", batch=2048,
+                              steps=8, extra=()):
+    """One short trainer run with the fused cross-entropy in the step."""
+    clock.lap()
+    summary, epochs = run_trainer(
+        [*_trainer_argv(model, batch, steps), *extra, "--train.epochs=1",
+         "--train.pallas_xent=true"], WORK / "train_pallas_xent")
+    secs, _, _ = clock.lap()
+    step_ms = _check_run(summary, epochs, batch)
+    print(f"smoke reading, pallas_xent run: compile {secs:.1f} s, steady "
+          f"{step_ms:.2f} ms/step, epoch loss {epochs[-1]['loss']:.4f}")
+
+
+def _ten_steps(argv, steps, ckpt_dir):
+    """Build the Trainer a user's flags would build and take ``steps``
+    steps of its own pipeline through its own compiled step. Returns
+    (trainer, per-step losses, first placed batch, label digests)."""
+    import numpy as np
+
+    from tpu_dp.config import parse_cli
+    from tpu_dp.train.trainer import Trainer
+
+    tr = Trainer(parse_cli([*argv, f"--train.ckpt_dir={ckpt_dir}"]))
+    tr.train_pipe.set_epoch(0)
+    losses, digests, first = [], [], None
+    for _, batch in tr.train_pipe.windows(1):
+        if first is None:
+            first = batch
+        digests.append(int(np.asarray(batch["label"], np.int64).sum()))
+        tr.state, metrics = tr.train_step(tr.state, batch)
+        losses.append(float(metrics["loss"]))
+    assert len(losses) == steps, (len(losses), steps)
+    return tr, losses, first, digests
+
+
+def _on_all(tree, devices, name):
+    """Every leaf's sharding spans exactly ``devices``."""
+    import jax
+
+    leaves = jax.tree_util.tree_leaves(tree)
+    assert leaves, name
+    for leaf in leaves:
+        assert leaf.sharding.device_set == devices, (
+            f"{name}: leaf {leaf.shape} lives on "
+            f"{sorted(d.id for d in leaf.sharding.device_set)}")
+    return len(leaves)
+
+
+def compare_data_parallel(*, model="resnet18", batch=512, steps=10,
+                          world=4, atol=DP_LOSS_ATOL):
+    """One device vs ``world`` devices, replicated and sharded update."""
+    import jax
+
+    from tpu_dp.analysis.hlo import count_collectives
+
+    devices = set(jax.devices()[:world])
+    assert len(devices) == world, f"{len(jax.devices())} device(s) < {world}"
+    base = [
+        f"--model.name={model}", "--data.dataset=synthetic",
+        f"--data.batch_size={batch}",
+        f"--data.synthetic_train_size={batch * steps}",
+        f"--data.synthetic_test_size={batch}", "--data.device_resident=off",
+        "--optim.lr=0.05", "--train.seed=0",
+    ]
+    runs = {
+        "one device": [*base, "--parallel.num_devices=1"],
+        "replicated": [*base, f"--parallel.num_devices={world}"],
+        "sharded": [*base, f"--parallel.num_devices={world}",
+                    "--train.update_sharding=sharded"],
+    }
+    losses, digests = {}, {}
+    for name, argv in runs.items():
+        tr, losses[name], first, digests[name] = _ten_steps(
+            argv, steps, WORK / f"dp_{name.replace(' ', '_')}")
+        assert all(math.isfinite(x) for x in losses[name]), losses[name]
+        print(f"{name}: losses {[round(x, 5) for x in losses[name]]}")
+        if name == "one device":
+            assert tr.num_devices == 1
+            continue
+        # Placement: state and batch really spread over all the devices.
+        assert tr.num_devices == world
+        n = _on_all(tr.state.params, devices, f"{name} params")
+        _on_all(tr.state.batch_stats or tr.state.step, devices,
+                f"{name} batch_stats")
+        _on_all(first, devices, f"{name} batch")
+        for key, leaf in first.items():
+            rows = {s.data.shape[0] for s in leaf.addressable_shards}
+            assert rows == {batch // world}, (name, key, rows)
+        opt = jax.tree_util.tree_leaves(tr.state.opt_state)
+        _on_all(opt, devices, f"{name} opt_state")
+        per_device = sum(s.data.size for leaf in opt
+                         for s in leaf.addressable_shards
+                         if s.device == jax.devices()[0])
+        total = sum(leaf.size for leaf in opt)
+        if name == "sharded":
+            assert per_device * world == total, (per_device, total)
+        else:
+            assert per_device == total, (per_device, total)
+        # The step holds the collectives this mode is made of: as traced
+        # (StableHLO) and as the chip's compiler left them. XLA may rewrite
+        # a reduce-scatter as an all-reduce and a slice, so the compiled
+        # text is held to "a gradient reduction and a gather", and what
+        # the compiler made of them is printed.
+        lowered = tr.train_step.lower(tr.state, first)
+        traced = lowered.as_text()
+        counts = count_collectives(lowered.compile().as_text())
+        if name == "sharded":
+            assert "reduce_scatter" in traced and "all_gather" in traced
+            assert counts.get("all-gather") and (
+                counts.get("reduce-scatter") or counts.get("all-reduce")
+            ), counts
+        else:
+            assert "reduce_scatter" not in traced
+            assert counts.get("all-reduce") and not counts.get(
+                "reduce-scatter"), counts
+        print(f"{name}: {n} param leaves and the batch on {world} devices "
+              f"({batch // world} rows each), optimizer state "
+              f"{per_device}/{total} elements per device, "
+              f"collectives {counts}")
+    ref = losses["one device"]
+    for name in ("replicated", "sharded"):
+        assert digests[name] == digests["one device"], (
+            f"{name} saw a different batch stream")
+        diffs = [abs(a - b) for a, b in zip(losses[name], ref)]
+        print(f"{name} vs one device: |loss difference| per step "
+              f"{[float(f'{d:.1e}') for d in diffs]}, "
+              f"max {max(diffs):.3e} (allowed {atol:.0e})")
+        assert max(diffs) <= atol, (
+            f"{name} departs from one device by {max(diffs):.3e} > "
+            f"{atol:.0e}")
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    args = ap.parse_args(argv)
+
+    import jax
+
+    from tpu_dp.obs.chips import chip_spec
+    from tpu_dp.utils import place_compile_cache
+
+    devs = jax.devices()
+    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+              "count": len(devs)}
+    spec = chip_spec(device["kind"])
+    print(f"device: platform={device['platform']} kind={device['kind']!r} "
+          f"count={device['count']} -> "
+          + (f"{spec.name}: {spec.peak_flops / 1e12:.0f} TFLOP/s bf16, "
+             f"{spec.hbm_gbs:.0f} GB/s HBM" if spec else "no such chip in "
+             "tpu_dp.obs.chips"))
+    if device["platform"] != "tpu" or spec is None:
+        print("chip_smoke: needs a TPU that tpu_dp.obs.chips knows",
+              file=sys.stderr)
+        return 1
+    if device["count"] != args.chips:
+        print(f"chip_smoke: --chips {args.chips} but JAX sees "
+              f"{device['count']} device(s)", file=sys.stderr)
+        return 1
+    print(f"compile cache: {place_compile_cache()}")
+
+    shutil.rmtree(WORK, ignore_errors=True)
+    WORK.mkdir(parents=True)
+    try:
+        if args.chips == 4:
+            compare_data_parallel()
+        else:
+            clock = _CompileClock()
+            phase_trainer(clock)
+            phase_kernels()
+            phase_trainer_pallas_xent(clock)
+    finally:
+        shutil.rmtree(WORK, ignore_errors=True)
+    print(json.dumps({"ok": True, "device": device}))
+    return 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())

@@ -156,6 +156,10 @@ def test_base_op_name():
     assert base_op_name("all-gather-start.1") == "all-gather"
     assert base_op_name("all-gather-done.1") == "all-gather-done"
     assert base_op_name("loop_fusion.2") == "loop_fusion"
+    # Instruction names derived from JAX primitives (JAX 0.9).
+    assert base_op_name("all_gather_invariant.9") == "all-gather"
+    assert base_op_name("reduce_scatter.14") == "reduce-scatter"
+    assert base_op_name("psum_invariant.3") == "all-reduce"
 
 
 # --------------------------------------------------------------------------

@@ -26,7 +26,6 @@ def _write(monkeypatch, tmp_path, rows):
     p = tmp_path / "results.jsonl"
     p.write_text("".join(json.dumps(r) + "\n" for r in rows))
     monkeypatch.setattr(fused_verdict, "RESULTS", p)
-    monkeypatch.setattr(fused_verdict, "CAPTURE", tmp_path / "nocap")
 
 
 def _verdict_line(capsys):

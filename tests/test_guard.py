@@ -298,6 +298,18 @@ def _guard_cfg(tmp_path, **over):
     return cfg
 
 
+def _assert_same_trajectory(params, oracle_params):
+    """Sentinel run vs plain-factory oracle: the same updates on the same
+    batches. They are two compiled programs, and XLA may contract their
+    multiply-adds differently (a few f32 ulp after a dozen steps on JAX
+    0.9.0), so the comparison allows rounding and nothing else — one
+    update applied or withheld differently is orders of magnitude more."""
+    for a, b in zip(jax.tree_util.tree_leaves(params),
+                    jax.tree_util.tree_leaves(oracle_params)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-8)
+
+
 def _oracle_params_skipping(cfg, skip_batches=(), extra_epochs=None):
     """Final params of a run over the same deterministic batch stream that
     never saw the batches in ``skip_batches`` (global batch indices).
@@ -358,9 +370,7 @@ def test_nan_skip_matches_never_saw_batch_oracle(tmp_path):
     assert int(np.asarray(tr.state.step)) == 11  # 12 batches, 1 skipped
 
     oracle = _oracle_params_skipping(cfg, skip_batches={3})
-    for a, b in zip(jax.tree_util.tree_leaves(tr.state.params),
-                    jax.tree_util.tree_leaves(oracle.params)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    _assert_same_trajectory(tr.state.params, oracle.params)
 
     recs = [json.loads(line)
             for line in (tmp_path / "ck" / "quarantine.jsonl").read_text()
@@ -403,9 +413,7 @@ def test_sentinel_on_clean_run_bitwise_equals_plain(tmp_path):
     tr = Trainer(_guard_cfg(tmp_path))
     tr.fit()
     oracle = _oracle_params_skipping(tr.cfg)
-    for a, b in zip(jax.tree_util.tree_leaves(tr.state.params),
-                    jax.tree_util.tree_leaves(oracle.params)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    _assert_same_trajectory(tr.state.params, oracle.params)
 
 
 @pytest.mark.resilience

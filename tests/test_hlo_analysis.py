@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -30,6 +31,7 @@ import pytest
 
 from tpu_dp.analysis import hlo, recompile
 from tpu_dp.analysis.recompile import RecompileError, RecompileGuard
+from tpu_dp.parallel.collectives import all_gather_invariant
 
 pytestmark = pytest.mark.analysis
 
@@ -100,8 +102,10 @@ def test_serve_programs_in_artifact(repo_hlo):
     assert set(serve) == {"serve_step@b16", "serve_step@b2"}
     big, small = serve["serve_step@b16"], serve["serve_step@b2"]
     # Fan-out bucket: batch sharded over data; only the stats reduce.
-    assert big["counts"] == {"all-reduce": 2}, big["counts"]
-    assert big["grad_reduce_ops"] == 1 and big["metric_allreduce_ops"] == 1
+    # (XLA's combiner may merge the two into one tuple-shaped all-reduce.)
+    assert set(big["counts"]) == {"all-reduce"}, big["counts"]
+    assert big["metric_allreduce_ops"] == 1
+    assert sum("f32[10]" in op["shape"] for op in big["collectives"]) == 1
     groups = {op["replica_groups"] for op in big["collectives"]}
     reductions = {op["reduction"] for op in big["collectives"]}
     assert len(groups) == 1 and reductions == {"add"}, (groups, reductions)
@@ -145,8 +149,11 @@ def test_shipped_sharded_steps_have_scatter_update_gather_schedule(repo_hlo):
         # psum is the one collective guardrails add — see
         # `test_sentinel_programs_in_artifact`).
         declared = 3 if "sentinel" in name else 2
-        assert len(by_kind["all-reduce"]) == rec["metric_allreduce_ops"] \
-            == declared, (name, rec["metric_allreduce_ops"])
+        assert not any(re.search(r"\[\d", op["shape"])
+                       for op in by_kind["all-reduce"]), (
+            name, by_kind["all-reduce"])
+        assert rec["metric_allreduce_ops"] == declared, (
+            name, rec["metric_allreduce_ops"])
         assert rec["grad_reduce_ops"] == len(by_kind["reduce-scatter"]) >= 1
         # Donation survives the sharded layout: opt-state shards alias too.
         assert rec["aliased_inputs"] == rec["donated_inputs"] > 0, name
@@ -277,8 +284,8 @@ def test_dp301_fires_on_int8_leak_and_missing_payload():
         flat = jnp.pad(g.reshape(-1), (0, (-g.size) % 8))
         shard = jax.lax.psum_scatter(flat, dist.DATA_AXIS,
                                      scatter_dimension=0, tiled=True)
-        return jax.lax.all_gather(shard, dist.DATA_AXIS, axis=0,
-                                  tiled=True)[: g.size]
+        return all_gather_invariant(shard, dist.DATA_AXIS, axis=0,
+                                    tiled=True)[: g.size]
 
     fn2 = jax.jit(_shard_map(plain, mesh, (P(),), P()))
     text2, _, _ = hlo.lower_and_compile(fn2, (jnp.zeros((64,), jnp.float32),))
@@ -357,7 +364,11 @@ def test_dp301_fires_on_mismatched_scatter_gather_axes():
         return jax.lax.all_gather(shard - 0.1 * shard, "data", axis=0,
                                   tiled=True)[: g.size]
 
-    fn = jax.jit(_shard_map(bad_axes, mesh2d, (P(),), P()))
+    # The result still varies over `model`, so replication checking would
+    # refuse this program at trace time; it is switched off here so that the
+    # compiled-artifact rule is what catches it.
+    fn = jax.jit(jax.shard_map(bad_axes, mesh=mesh2d, in_specs=(P(),),
+                               out_specs=P(), check_vma=False))
     text, _, _ = hlo.lower_and_compile(fn, (jnp.zeros((32,), jnp.float32),))
     findings, _ = hlo.analyze_module(
         text, label="bad", where=("x.py", 1), world=8,
@@ -404,7 +415,7 @@ def test_dp301_accepts_legal_sharded_schedule_unit():
         shard = jax.lax.psum_scatter(flat, dist.DATA_AXIS,
                                      scatter_dimension=0, tiled=True) / 8.0
         new = shard - 0.1 * shard
-        full = jax.lax.all_gather(new, dist.DATA_AXIS, axis=0, tiled=True)
+        full = all_gather_invariant(new, dist.DATA_AXIS, axis=0, tiled=True)
         return full[: g.size].reshape(g.shape)
 
     fn = jax.jit(_shard_map(good, mesh, (P(),), P()))
@@ -460,6 +471,32 @@ def test_schedule_digest_is_deterministic():
         hlo.collect_ops(_compile_text(lambda x: x * 2, jnp.zeros((4,))))
     )
     assert d3 != d1
+
+
+def test_collect_ops_reads_tpu_tiled_layouts():
+    """Text compiled for a TPU carries tiled layouts with parentheses of
+    their own; a tuple-shaped collective must still be seen, with its
+    members, groups and reduction (lines from a v5e:2x2 compile)."""
+    text = """
+%region_1.2 (a: f32[], b: f32[]) -> f32[] {
+  %a = f32[]{:T(128)} parameter(0)
+  %b = f32[]{:T(128)} parameter(1)
+  ROOT %add.1 = f32[]{:T(128)} add(%a, %b)
+}
+  %all-reduce.134 = (f32[64]{0:T(128)S(1)}, f32[36864]{0:T(1024)S(1)}, /*index=2*/f32[]{:T(128)}) all-reduce(%x, %y, %z), channel_id=5, replica_groups={{0,1,2,3}}, use_global_device_ids=true, to_apply=%region_1.2
+  %psum_invariant.434 = f32[2,64]{1,0:T(2,128)S(1)} all-reduce(%f), channel_id=1, replica_groups={{0,1,2,3}}, to_apply=%region_1.2
+  %all-gather.91 = f32[5120]{0:T(1024)} all-gather(%g), channel_id=2, replica_groups={{0,1,2,3}}, dimensions={0}
+"""
+    ops = hlo.collect_ops(text)
+    assert [op.kind for op in ops] == ["all-reduce", "all-reduce",
+                                       "all-gather"]
+    combined = ops[0]
+    assert combined.shape == "(f32[64],f32[36864],/*index=2*/f32[])"
+    assert combined.replica_groups == "{{0,1,2,3}}"
+    assert combined.reduction == "add"
+    assert combined.scalar_results == 1 and not combined.is_scalar
+    assert ops[1].shape == "f32[2,64]"
+    assert hlo.count_collectives(text) == {"all-reduce": 2, "all-gather": 1}
 
 
 def test_count_collectives_sees_the_allreduce():
@@ -532,8 +569,9 @@ def test_dp303_fires_on_dropped_donation():
     )
     assert [f.rule for f in findings] == ["DP303"]
     assert record["aliased_inputs"] == 0
-    # The XLA lowering warning is surfaced in the finding, not swallowed.
-    assert "donated buffers were not usable" in findings[0].message
+    # Where lowering warns about the unusable donation, the finding
+    # surfaces the warning instead of swallowing it.
+    assert all(w in findings[0].message for w in warns)
 
 
 def test_dp303_clean_on_real_donation():

@@ -111,3 +111,28 @@ def test_shard_map_step_with_pallas_xent(mesh8):
                                use_pallas_xent=True)(s_g, dict(batch))
     assert float(met_sm["loss"]) == pytest.approx(float(met_g["loss"]),
                                                   rel=2e-4)
+
+
+def test_kernel_off_the_tpu_without_the_request_raises(monkeypatch):
+    """No silent interpret mode: on a backend that is not a TPU the kernel
+    runs only inside `interpret_kernels()` (conftest's fixture, suspended
+    here), and the refusal names the backend."""
+    from tpu_dp.ops import _partition
+
+    monkeypatch.setattr(_partition, "_interpret_requests", 0)
+    logits = jnp.zeros((8, 10), jnp.float32)
+    labels = jnp.zeros((8,), jnp.int32)
+    with pytest.raises(RuntimeError, match=r"backend 'cpu'.*interpret_kernels"):
+        softmax_xent(logits, labels)
+
+
+def test_kernel_off_the_tpu_with_the_request_runs(monkeypatch):
+    from tpu_dp.ops import _partition, interpret_kernels
+
+    monkeypatch.setattr(_partition, "_interpret_requests", 0)
+    logits = jnp.zeros((8, 10), jnp.float32)
+    labels = jnp.zeros((8,), jnp.int32)
+    with interpret_kernels():
+        loss = softmax_xent(logits, labels)
+    np.testing.assert_allclose(np.asarray(loss), np.log(10.0), rtol=1e-6)
+    assert _partition._interpret_requests == 0  # the request ended with it

@@ -1,0 +1,126 @@
+"""The Pallas kernels compile for a described (not attached) v5e chip.
+
+Interpret mode — what every other test of `tpu_dp.ops` runs — cannot see a
+misaligned slice, an over-budget VMEM tile or an op Mosaic refuses; the
+TPU's compiler can, and it is installed here. Each case lowers one kernel
+at a shape `chip_smoke.py` runs on the chip, compiles it for one device of
+a described ``v5e:2x2`` topology, and asserts the compiled program holds a
+``tpu_custom_call``. Nothing runs, so nothing here is a result or a time.
+
+One file and in-process on purpose: only one process at a time may load
+the TPU's library, so the topology is described inside a module-scoped
+fixture (never at import, in a `skipif` or in `parametrize` arguments) and
+no test starts a child.
+"""
+
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from tpu_dp.ops import _partition, conv_block, xent
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # whatever keeps the compiler from loading
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def compiled_kernels(monkeypatch):
+    """The kernels as the chip gets them: no interpret request (conftest's
+    is suspended), the backend check answered as on the chip, and the
+    persistent compile cache off (an entry compiled for a described device
+    cannot be read back without one)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(_partition, "_interpret_requests", 0)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    return compiled
+
+
+def _xent_fwd(logits, labels):
+    return xent.softmax_xent(logits, labels)
+
+
+def _xent_bwd(logits, labels):
+    return jax.grad(lambda lg: jnp.sum(xent.softmax_xent(lg, labels)))(logits)
+
+
+@pytest.mark.parametrize("fn", [_xent_fwd, _xent_bwd],
+                         ids=["fwd", "bwd"])
+@pytest.mark.parametrize("batch,classes", [(2048, 10), (1024, 100)])
+def test_xent_compiles_for_v5e(one_chip, compiled_kernels, fn, batch,
+                               classes):
+    _compile(fn, one_chip,
+             ((batch, classes), jnp.bfloat16), ((batch,), jnp.int32))
+
+
+_X = ((256, 32, 32, 64), jnp.bfloat16)
+_W = ((3, 3, 64, 64), jnp.bfloat16)
+_C = ((64,), jnp.float32)
+
+
+def _conv_plain(x, w, scale, shift):
+    return conv_block.fused_affine_relu_conv(x, w, scale, shift, None)
+
+
+def _conv_full(x, w, scale, shift, res):
+    return conv_block.fused_conv_bn(x, w, scale, shift, res, emit_z=True)
+
+
+def _conv_plain_bwd(x, w, scale, shift):
+    def loss(x, w):
+        y = conv_block.fused_affine_relu_conv(x, w, scale, shift, None,
+                                              pallas_bwd=True)
+        return jnp.sum(y.astype(jnp.float32))
+    return jax.grad(loss, argnums=(0, 1))(x, w)
+
+
+def _conv_full_bwd(x, w, scale, shift, res):
+    def loss(x, w):
+        y, z, stats = conv_block.fused_conv_bn(x, w, scale, shift, res,
+                                               emit_z=True)
+        return (jnp.sum(y.astype(jnp.float32))
+                + jnp.sum(z.astype(jnp.float32)) + jnp.sum(stats))
+    return jax.grad(loss, argnums=(0, 1))(x, w)
+
+
+@pytest.mark.parametrize("fn,shapes", [
+    (_conv_plain, (_X, _W, _C, _C)),
+    (_conv_full, (_X, _W, _C, _C, _X)),
+    (_conv_plain_bwd, (_X, _W, _C, _C)),
+    (_conv_full_bwd, (_X, _W, _C, _C, _X)),
+], ids=["plain", "residual+z+stats", "plain-bwd", "residual+z+stats-bwd"])
+def test_conv_block_compiles_for_v5e(one_chip, compiled_kernels, fn, shapes):
+    compiled = _compile(fn, one_chip, *shapes)
+    # One (256,32,32,64) bf16 image block is 32 MiB; the whole call must
+    # sit far inside the chip's 16 GB.
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        + mem.output_size_in_bytes < 2 * 2**30

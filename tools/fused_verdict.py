@@ -2,13 +2,11 @@
 """Render the fused-conv verdict from captured TPU measurements.
 
 Reads the e2e sweep rows in `benchmarks/results.jsonl` (non-smoke,
-accelerator-backend) and the kernel microbench JSON lines under
-`benchmarks/r4_capture/fusedk_*.out`, and prints:
+accelerator-backend) and prints:
 
   1. a per-(batch, window) e2e table: unfused vs each fused variant,
-  2. a per-stage-shape kernel table: XLA vs Pallas per block_b,
-  3. the verdict line VERDICT r3 item 1 asks for — which variant (if any)
-     beats unfused at the headline operating point, with the margin.
+  2. the verdict line — which variant (if any) beats unfused at the
+     headline operating point, with the margin.
 
 Pure file parsing (no device); run any time:
     python tools/fused_verdict.py
@@ -18,14 +16,11 @@ Pure file parsing (no device); run any time:
 from __future__ import annotations
 
 import argparse
-import glob
 import json
-from collections import defaultdict
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parent.parent
 RESULTS = ROOT / "benchmarks" / "results.jsonl"
-CAPTURE = ROOT / "benchmarks" / "r4_capture"
 
 
 def load_results(metric_substr: str):
@@ -89,48 +84,6 @@ def e2e_table(rows):
     return "\n".join(lines), variants, cells
 
 
-def kernel_table():
-    recs = []
-    # captured/ holds the watcher-preserved (committed) copies; the top
-    # level holds this session's live outputs — read both, dedup by path
-    # basename preferring the live copy.
-    paths = {Path(p).name: p
-             for p in sorted(glob.glob(str(CAPTURE / "captured"
-                                           / "fusedk_*.out")))}
-    paths.update({Path(p).name: p
-                  for p in sorted(glob.glob(str(CAPTURE / "fusedk_*.out")))})
-    for path in sorted(paths.values()):
-        for line in Path(path).read_text().splitlines():
-            try:
-                r = json.loads(line)
-            except json.JSONDecodeError:
-                continue
-            if r.get("device") and r.get("device") != "cpu" and r.get("ms"):
-                recs.append(r)
-    if not recs:
-        return None
-    by_point = defaultdict(list)
-    for r in recs:
-        by_point[(tuple(r["shape"]), bool(r.get("grad")),
-                  bool(r.get("residual")))].append(r)
-    lines = ["| shape | mode | xla ms (%pk) | best pallas ms (%pk) | "
-             "block_b | speedup |", "|---|---|---|---|---|---|"]
-    for (shape, grad, res), rs in sorted(by_point.items()):
-        xla = [r for r in rs if r["impl"] == "xla"]
-        pal = [r for r in rs if r["impl"].startswith("pallas")]
-        if not xla or not pal:
-            continue
-        x = min(xla, key=lambda r: r["ms"])
-        p = min(pal, key=lambda r: r["ms"])
-        mode = ("fwd+bwd" if grad else "fwd") + ("+res" if res else "")
-        lines.append(
-            f"| {'x'.join(map(str, shape))} | {mode} "
-            f"| {x['ms']} ({x.get('pct_peak')}) "
-            f"| {p['ms']} ({p.get('pct_peak')}) [{p['impl']}] "
-            f"| {p['block_b']} | {x['ms'] / p['ms']:.2f}x |")
-    return "\n".join(lines) if len(lines) > 2 else None
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="resnet18")
@@ -143,18 +96,10 @@ def main() -> None:
     print(f"# Fused-conv verdict ({args.model})\n")
     if table is None:
         print("No accelerator e2e rows yet — run `python bench.py "
-              "--sweep-fused` on the chip (or wait for the r4 watcher).")
+              "--sweep-fused` on the chip.")
     else:
         print("## End-to-end (images/sec/chip, (MFU), % vs unfused)\n")
         print(table)
-
-    kt = kernel_table()
-    if kt:
-        print("\n## Kernel microbench (best per shape)\n")
-        print(kt)
-    else:
-        print("\n(no TPU kernel microbench captures under "
-              "benchmarks/r4_capture/ yet)")
 
     # The verdict line.
     hb, hw = args.headline_batch, args.headline_window

@@ -8,7 +8,7 @@ without installing the package:
     tools/obsctl.py timeline <run_dir> --steps    # + per-step coverage
     tools/obsctl.py stragglers <run_dir>          # leave-one-out attribution
     tools/obsctl.py merge-trace <run_dir> -o t.json
-    tools/obsctl.py diff <run_dir> --baseline BENCH_r08.json
+    tools/obsctl.py diff <run_dir> --baseline BENCH_r02.json
     tools/obsctl.py diff <run_dir> --write-baseline base.json
 
 Equivalent to ``python -m tpu_dp.obs``. Exit 0 clean / 1 regression

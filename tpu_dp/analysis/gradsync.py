@@ -39,7 +39,7 @@ from tpu_dp.analysis.report import Finding
 # receives the data-axis sum of its shard — reduced exactly once, like
 # psum, just not everywhere. The params all-gather that follows the
 # sharded update is NOT a reduction and is deliberately absent here.
-_REDUCTION_PRIMS = {"psum", "pmin", "pmax", "psum2", "reduce_scatter"}
+_REDUCTION_PRIMS = {"psum", "pmin", "pmax", "psum_invariant", "reduce_scatter"}
 
 # The int8 wire codec (`train.collective_dtype=int8`,
 # `parallel/collectives.py psum_scatter_quant`) carries the gradient
@@ -86,7 +86,7 @@ def _sub_jaxprs(eqn) -> list[tuple[Any, int | None]]:
     plain call-like primitives, and None for loops with unknown trip count
     (a reduction there runs "at least twice" for counting purposes).
     """
-    import jax.core as core
+    import jax.extend.core as core
 
     out: list[tuple[Any, int | None]] = []
     name = eqn.primitive.name
@@ -115,7 +115,7 @@ def _count_reductions(jaxpr, target_outvars, axis: str) -> int:
     a per-microbatch psum under gradient accumulation counts accum_steps
     times, which is exactly the DP202 failure mode.
     """
-    import jax.core as core
+    import jax.extend.core as core
 
     producer: dict[Any, Any] = {}
     for eqn in jaxpr.eqns:

@@ -65,8 +65,11 @@ _COLLECTIVE_KINDS = (
 )
 _HOST_KINDS = ("infeed", "outfeed", "send", "recv")
 
+# A result shape is one array or a tuple of them; on the TPU each carries a
+# tiled layout with parentheses of its own (``f32[64]{0:T(128)S(1)}``).
+_SHAPE = r"(\((?:[^(){}]|\{[^{}]*\})*\)|\S+)"
 _OP_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%[\w.~-]+\s*=\s*(\([^)]*\)|\S+)\s+([a-z-]+)\("
+    r"^\s*(?:ROOT\s+)?%[\w.~-]+\s*=\s*" + _SHAPE + r"\s+([a-z-]+)\("
 )
 _REPLICA_GROUPS_RE = re.compile(
     r"replica_groups=(\[[^\]]*\]<=\[[^\]]*\](?:T\([\d,]+\))?"
@@ -76,7 +79,7 @@ _TO_APPLY_RE = re.compile(r"to_apply=%([\w.~-]+)")
 _TARGET_RE = re.compile(r'custom_call_target="([^"]+)"')
 _ALIAS_RE = re.compile(r"input_output_alias=\{(.*?)\}(?:,\s*[a-z_]+=|\s*$)")
 _ALIAS_ENTRY_RE = re.compile(r"\{[\d,\s]*\}:\s*\((\d+),")
-_LAYOUT_RE = re.compile(r"\{[\d,*]*\}")
+_LAYOUT_RE = re.compile(r"\{[^{}]*\}")
 
 # custom_call_target substrings that mean "the compiled program calls back
 # into the host" (CPU/TPU python callbacks, explicit host transfers).
@@ -99,6 +102,14 @@ class HloOp:
         return "[" in self.shape and "[]" in self.shape and not re.search(
             r"\[\d", self.shape
         )
+
+    @property
+    def scalar_results(self) -> int:
+        """Rank-0 members of the result. XLA's all-reduce combiner merges
+        same-dtype reductions into one tuple-shaped op — the metric scalars
+        with each other, the f32 loss with the gradient group — so the
+        declared metric reductions are counted by member, not by op."""
+        return len(re.findall(r"[a-z]+\d+\[\]", self.shape))
 
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -396,7 +407,7 @@ def analyze_module(
     scatters = [op for op in collectives if op.kind == "reduce-scatter"]
     gathers = [op for op in collectives if op.kind == "all-gather"]
     a2as = [op for op in collectives if op.kind == "all-to-all"]
-    metric_ars = [op for op in allreduces if op.is_scalar]
+    metric_scalars = sum(op.scalar_results for op in allreduces)
     if int8_wire:
         payload_a2as = [op for op in a2as if "s8[" in op.shape]
         scale_a2as = [op for op in a2as if "f32[" in op.shape]
@@ -495,9 +506,9 @@ def analyze_module(
                  "gradient all-reduce the DDP contract requires was never "
                  "materialized by the partitioner (replicas would silently "
                  "diverge)")
-    if len(metric_ars) > metric_reductions:
+    if metric_scalars > metric_reductions:
         emit("DP301",
-             f"{len(metric_ars)} scalar all-reduce(s) compiled, "
+             f"{metric_scalars} scalar all-reduce(s) compiled, "
              f"{metric_reductions} metric reduction(s) declared — an "
              f"undeclared scalar sync per step serializes the schedule")
 
@@ -570,7 +581,7 @@ def analyze_module(
         # Mode-neutral name: in sharded mode the gradient-reduction ops are
         # the reduce-scatter group, not non-scalar all-reduces.
         "grad_reduce_ops": len(grad_ars),
-        "metric_allreduce_ops": len(metric_ars),
+        "metric_allreduce_ops": metric_scalars,
         "donated_inputs": donated_leaves,
         "aliased_inputs": len(aliased),
     }

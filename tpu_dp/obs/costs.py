@@ -97,7 +97,7 @@ def resolve_flops_per_step(program_flops, step_flops, window, per_chip_batch,
     Round 2 published mfu=0.0165 instead of the true ~0.49 because
     `compiled.cost_analysis()["flops"]` on a `lax.scan` program reports the
     loop *body's* FLOPs once on this jaxlib/TPU, and the old code divided by
-    the trip count again (VERDICT.md round 2, "What's weak" #1). Resolution
+    the trip count again (the round-2 review, weak point 1). Resolution
     order:
 
     1. `step_flops` — cost analysis of the w1-compiled production step

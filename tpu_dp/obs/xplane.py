@@ -55,8 +55,22 @@ COLLECTIVE_KINDS = (
     "all-to-all",
 )
 
-#: Host-plane event names that are executor scaffolding, not ops.
-_INFRA_MARKERS = ("::", "D2D Dispatch", "ThunkExecutor")
+#: HLO instruction names that JAX 0.9 derives from its own primitives
+#: instead of the HLO opcode (``%reduce_scatter.14 = ... reduce-scatter(``):
+#: trace events carry the instruction name, so they map back to the kind.
+_PRIMITIVE_KINDS = {
+    "psum": "all-reduce", "psum_invariant": "all-reduce",
+    "pmax": "all-reduce", "pmin": "all-reduce",
+    "all_gather": "all-gather", "all_gather_invariant": "all-gather",
+    "reduce_scatter": "reduce-scatter", "all_to_all": "all-to-all",
+    "ppermute": "collective-permute",
+}
+
+#: Host-plane event names that are executor scaffolding, not ops (the
+#: ``end: <op>`` twin of every thunk event and the collective rendezvous
+#: waits among them).
+_INFRA_MARKERS = ("::", "D2D Dispatch", "ThunkExecutor", "end: ",
+                  "Rendezvous", "Wait: ", "Wait for ", "Handle inputs")
 
 _SUFFIX_RE = re.compile(r"\.\d+$")
 
@@ -133,7 +147,7 @@ def base_op_name(name: str) -> str:
     base = _SUFFIX_RE.sub("", base)
     if base.endswith("-start"):
         base = base[:-6]
-    return base
+    return _PRIMITIVE_KINDS.get(base, base)
 
 
 def _merge(intervals: list[tuple[float, float]]) -> list[tuple[float, float]]:

@@ -10,6 +10,7 @@ ring allreduce fallback for host coordination off-TPU — are native C++
 """
 
 from tpu_dp.ops import native
+from tpu_dp.ops._partition import interpret_kernels
 from tpu_dp.ops.conv_block import (
     fused_affine_relu_conv,
     fused_affine_relu_conv_emit,
@@ -22,6 +23,7 @@ __all__ = [
     "fused_affine_relu_conv",
     "fused_affine_relu_conv_emit",
     "fused_conv_bn",
+    "interpret_kernels",
     "mean_softmax_xent",
     "softmax_xent",
 ]

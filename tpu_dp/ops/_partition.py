@@ -1,81 +1,68 @@
 """Shared GSPMD/shard_map plumbing for the Pallas op modules.
 
 One copy of the custom-partitioning support code used by both
-`tpu_dp.ops.conv_block` and `tpu_dp.ops.xent`: backend detection, the
-batch-axis extraction from operand shardings, batch padding, the
+`tpu_dp.ops.conv_block` and `tpu_dp.ops.xent`: the interpret-mode request,
+the batch-axis extraction from operand shardings, batch padding, the
 varying-mesh-axes (vma) union for `shard_map`'s check_vma, and the guard
-for the interpret-mode fallback (Pallas interpret lowers to a grid scan
+for per-shard interpret-mode code (Pallas interpret lowers to a grid scan
 whose index scalars are vma-unvarying, which check_vma rejects — per-shard
-code falls back to the op's identical XLA statement there).
+code runs the op's identical XLA statement there).
 """
 
 from __future__ import annotations
 
-import inspect
+import contextlib
 import logging
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.custom_partitioning import (
-    custom_partitioning as _custom_partitioning,
-)
 from jax.sharding import NamedSharding
 
 logger = logging.getLogger(__name__)
 
-# --- JAX version adaptation -------------------------------------------------
-# The vma (varying-mesh-axes) machinery — `jax.typeof`, avals carrying `vma`,
-# `ShapeDtypeStruct(..., vma=...)` — and `def_partition(sharding_rule=...)`
-# only exist in newer JAX. Detect each capability once; older installs get
-# the no-vma behavior (their shard_map has no check_vma to satisfy).
-
-_HAS_TYPEOF = hasattr(jax, "typeof")
-try:
-    jax.ShapeDtypeStruct((1,), jnp.float32, vma=frozenset())
-    _HAS_VMA_STRUCT = True
-except TypeError:
-    _HAS_VMA_STRUCT = False
-_HAS_SHARDING_RULE = "sharding_rule" in inspect.signature(
-    _custom_partitioning.def_partition
-).parameters
+# Open `interpret_kernels()` requests (nesting depth). The kernels are
+# compiled for the TPU unless a caller asked, on purpose, for the Pallas
+# interpreter: tests, the CPU-mesh dry run, `--platform cpu` tool runs.
+_interpret_requests = 0
 
 
-def def_partition(cp, *, partition, infer_sharding_from_operands,
-                  sharding_rule=None):
-    """`cp.def_partition` across JAX versions.
+@contextlib.contextmanager
+def interpret_kernels():
+    """Run the Pallas kernels traced inside this block in interpret mode.
 
-    Newer JAX (Shardy) wants the `sharding_rule` mini-language string;
-    older `def_partition` signatures reject the kwarg outright — pass it
-    only where it exists (the GSPMD callbacks carry the same information).
+    The request is read when a kernel is traced, so the block must cover
+    the first call (the compile) of every jitted function that holds one.
     """
-    kwargs = dict(partition=partition,
-                  infer_sharding_from_operands=infer_sharding_from_operands)
-    if sharding_rule is not None and _HAS_SHARDING_RULE:
-        kwargs["sharding_rule"] = sharding_rule
-    cp.def_partition(**kwargs)
-    return cp
-
-
-def shape_struct(shape, dtype, *operands):
-    """`ShapeDtypeStruct` declaring the operands' vma union where supported.
-
-    On JAX without vma-typed avals this is a plain ShapeDtypeStruct — there
-    is no check_vma to satisfy there."""
-    if _HAS_VMA_STRUCT:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma_of(*operands))
-    return jax.ShapeDtypeStruct(shape, dtype)
+    global _interpret_requests
+    _interpret_requests += 1
+    try:
+        yield
+    finally:
+        _interpret_requests -= 1
 
 
 def interpret() -> bool:
-    """True off-TPU: run kernels in Pallas interpret mode."""
-    return jax.default_backend() != "tpu"
+    """True inside `interpret_kernels()`; otherwise the kernel is compiled,
+    which needs a TPU — any other backend is an error, not a fallback."""
+    if _interpret_requests:
+        return True
+    backend = jax.default_backend()
+    if backend != "tpu":
+        raise RuntimeError(
+            f"Pallas kernel called on backend {backend!r}: the kernels "
+            "compile for the TPU only. To run one in the Pallas interpreter "
+            "on purpose, call it inside tpu_dp.ops.interpret_kernels().")
+    return False
 
 
 def shard_map_interp(x) -> bool:
-    """True when per-shard interpret-mode code must take the XLA fallback."""
-    if not _HAS_TYPEOF:
-        return False
-    return interpret() and bool(getattr(jax.typeof(x), "vma", None))
+    """True when per-shard interpret-mode code must run the XLA statement."""
+    return bool(_interpret_requests) and bool(jax.typeof(x).vma)
+
+
+def shape_struct(shape, dtype, *operands):
+    """`ShapeDtypeStruct` declaring the operands' vma union."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma_of(*operands))
 
 
 def batch_axis(arg_infos):
@@ -109,8 +96,5 @@ def pad_batch(x, block):
 
 def vma_of(*arrays):
     """Union of the mesh axes the arrays vary over (empty outside
-    shard_map, and always empty on JAX without vma-typed avals)."""
-    if not _HAS_TYPEOF:
-        return frozenset()
-    return frozenset().union(*(getattr(jax.typeof(a), "vma", frozenset())
-                               for a in arrays))
+    shard_map)."""
+    return frozenset().union(*(jax.typeof(a).vma for a in arrays))

@@ -45,8 +45,9 @@ weight-grad contraction is XLA's; the input-grad conv is XLA's
 conv-transpose by default, or — with ``pallas_bwd`` — this same kernel
 with spatially-flipped, io-swapped weights (the input-grad of a stride-1
 SAME 3x3 conv is another stride-1 SAME 3x3 conv); the affine/ReLU
-backward is explicit elementwise math. Off-TPU the kernel runs in Pallas
-interpret mode so CPU tests exercise identical code.
+backward is explicit elementwise math. The kernel compiles for the TPU;
+inside `tpu_dp.ops.interpret_kernels()` it runs in the Pallas interpreter,
+which is how CPU tests exercise identical code.
 """
 
 from __future__ import annotations
@@ -62,7 +63,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from tpu_dp.ops._partition import (
     batch_axis as _batch_axis,
-    def_partition as _def_partition,
     interpret as _interpret,
     pad_batch as _pad_batch,
     shape_struct as _shape_struct,
@@ -339,8 +339,8 @@ def _make_cp(with_res, emit_z=False, emit_stats=False):
     if emit_stats:
         outs.append("u v")  # fresh factors: stats are replicated, never
         # tied to the channel factor (the partition rule psums partials)
-    _def_partition(cp, partition=part, infer_sharding_from_operands=infer,
-                   sharding_rule=f"{ins} -> {', '.join(outs)}")
+    cp.def_partition(partition=part, infer_sharding_from_operands=infer,
+                     sharding_rule=f"{ins} -> {', '.join(outs)}")
     return cp
 
 

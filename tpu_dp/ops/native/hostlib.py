@@ -12,7 +12,6 @@ from pathlib import Path
 import numpy as np
 
 _SRC = Path(__file__).with_name("hostring.cpp")
-_LIB = Path(__file__).with_name("libtpudp_host.so")
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _build_failed = False
@@ -39,9 +38,7 @@ def _cached_lib_path() -> Path:
     """Content-addressed build location outside the source tree.
 
     Keyed on the source hash: editing hostring.cpp gets a fresh build
-    without mtime games, and a stale/incompatible prebuilt .so in the repo
-    (different glibc, different arch) never blocks a local rebuild — the
-    checkout may be read-only.
+    without mtime games, and the checkout may be read-only.
     """
     import hashlib
 
@@ -68,26 +65,22 @@ def _get() -> ctypes.CDLL | None:
             return _lib
         if _build_failed:
             return None
-        # Prebuilt .so next to the source: use it when fresh AND loadable.
-        lib = None
-        if _LIB.exists() and _LIB.stat().st_mtime >= _SRC.stat().st_mtime:
-            lib = _try_load(_LIB)
+        # Built from hostring.cpp into the digest-named cache path, and
+        # from nothing else: a library lying in the source tree is not
+        # among the files git commits.
+        cached = _cached_lib_path()
+        lib = _try_load(cached) if cached.exists() else None
         if lib is None:
-            # Compile-on-demand into the cache dir (rebuilds when the
-            # prebuilt is stale, fails to load, or doesn't exist).
-            cached = _cached_lib_path()
-            lib = _try_load(cached) if cached.exists() else None
-            if lib is None:
-                # Cache missing OR unloadable (e.g. built on another host of
-                # an NFS home, glibc upgraded since): rebuild in place.
-                # Holding the module lock across the one-time compile is
-                # the point: a second caller must wait for THIS build, not
-                # race a duplicate compiler into the same cache path.
-                # dplint: allow(DP505) one-time build serializes callers
-                if not _build(cached):
-                    _build_failed = True  # no compiler: available() -> False
-                    return None
-                lib = _try_load(cached)
+            # Cache missing OR unloadable (e.g. built on another host of
+            # an NFS home, glibc upgraded since): rebuild in place.
+            # Holding the module lock across the one-time compile is
+            # the point: a second caller must wait for THIS build, not
+            # race a duplicate compiler into the same cache path.
+            # dplint: allow(DP505) one-time build serializes callers
+            if not _build(cached):
+                _build_failed = True  # no compiler: available() -> False
+                return None
+            lib = _try_load(cached)
         if lib is None:
             _build_failed = True
             return None

@@ -11,8 +11,9 @@ tile per grid step. For CIFAR head sizes (C = 10/100, padded to the
 for none.
 
 API: `softmax_xent(logits, labels) -> per-example loss (B,)`, differentiable
-wrt logits via `jax.custom_vjp`. Off-TPU the same kernels run in Pallas
-interpret mode, so tests exercise identical code on CPU. `tpu_dp.train.step`
+wrt logits via `jax.custom_vjp`. The kernels compile for the TPU; inside
+`tpu_dp.ops.interpret_kernels()` the same code runs in the Pallas
+interpreter, which is how the tests exercise it on CPU. `tpu_dp.train.step`
 uses the jnp path by default; the kernel is opt-in (`use_pallas=True` /
 bench) and numerically validated against the jnp path in tests.
 """
@@ -30,7 +31,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from tpu_dp.ops._partition import (
     batch_axis as _batch_axis_shared,
-    def_partition as _def_partition,
     interpret as _interpret,
     pad_batch as _pad_batch,
     shape_struct as _shape_struct,
@@ -158,8 +158,8 @@ def _make_cp(fn, n_args, out_spec_fn, rule):
         arg_shardings = (row, vec, vec)[:n_args]
         return mesh, fn, out_spec_fn(mesh, batch), arg_shardings
 
-    _def_partition(cp, partition=part, infer_sharding_from_operands=infer,
-                   sharding_rule=rule)
+    cp.def_partition(partition=part, infer_sharding_from_operands=infer,
+                     sharding_rule=rule)
     return cp
 
 

@@ -39,6 +39,13 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax import lax
+# The Varying -> Invariant all-gather. `lax.all_gather` types its result
+# device-varying, which cannot leave a replication-checked `shard_map` under
+# `out_specs=P()` nor ride a `lax.scan` carry that entered invariant; this
+# primitive lowers to the same HLO all-gather and is typed replicated by
+# construction. JAX 0.9.0 ships it beside the public one but does not
+# re-export it under `jax.lax`.
+from jax._src.lax.parallel import all_gather_invariant
 
 from tpu_dp.parallel.dist import DATA_AXIS
 
@@ -244,9 +251,7 @@ def _issue_barrier(payload, token):
     so XLA's latency-hiding scheduler stays free to keep several exchanges
     in flight while it interleaves the remaining backward compute.
     """
-    if hasattr(lax, "optimization_barrier"):
-        return lax.optimization_barrier((payload, token))
-    return payload, token  # ancient JAX: hint unavailable, semantics equal
+    return lax.optimization_barrier((payload, token))
 
 
 def _bucket_rows(leaves, world: int, wire_dtype) -> jnp.ndarray:
@@ -464,7 +469,7 @@ def all_gather(shards: Any, like: Any, axis_name: str = DATA_AXIS,
     from tpu_dp.parallel import quant
 
     def gather(shard, ref):
-        full = lax.all_gather(shard, axis_name, axis=0, tiled=True)
+        full = all_gather_invariant(shard, axis_name, axis=0, tiled=True)
         return full[: ref.size].reshape(ref.shape).astype(ref.dtype)
 
     if codec is None:
@@ -472,7 +477,7 @@ def all_gather(shards: Any, like: Any, axis_name: str = DATA_AXIS,
 
     if isinstance(codec, quant.CastCodec):
         def gather_cast(shard, ref):
-            full = lax.all_gather(
+            full = all_gather_invariant(
                 shard.astype(codec.dtype), axis_name, axis=0, tiled=True
             )
             return full[: ref.size].reshape(ref.shape).astype(ref.dtype)
@@ -487,8 +492,8 @@ def all_gather(shards: Any, like: Any, axis_name: str = DATA_AXIS,
             pad = (-flat.size) % block
             padded = jnp.pad(flat, (0, pad))
             q, scales = quant.quantize_blocks(padded, block)
-            qx = lax.all_gather(q, axis_name, axis=0, tiled=True)
-            sx = lax.all_gather(scales, axis_name, axis=0, tiled=True)
+            qx = all_gather_invariant(q, axis_name, axis=0, tiled=True)
+            sx = all_gather_invariant(scales, axis_name, axis=0, tiled=True)
             full = quant.dequantize_blocks(qx, sx, block)
             # Drop each replica's block padding, then the shard padding.
             full = full.reshape(-1, flat.size + pad)[:, : flat.size]

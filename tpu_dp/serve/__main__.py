@@ -150,8 +150,9 @@ def main(argv=None) -> int:
 
     import jax
 
-    if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
+    from tpu_dp.utils import place_compile_cache
+
+    place_compile_cache()
 
     if profile is not None:
         # The ladder was tuned for a (workload, mesh, backend); serving a

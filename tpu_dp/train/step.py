@@ -19,6 +19,7 @@ churn). Single-chip is the same program on a mesh of one.
 
 from __future__ import annotations
 
+import functools
 from typing import Callable
 
 import jax
@@ -59,37 +60,13 @@ def cross_entropy_loss(
 
 
 def _to_varying(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
-    """Cast a replication-invariant value to device-varying under shard_map.
-
-    `jax.lax.pvary` is deprecated in jax 0.9 in favour of
-    `jax.lax.pcast(..., to='varying')`; keep one call site so the next
-    rename is a one-line change.
-    """
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axis_name, to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axis_name)
-    # Pre-vma JAX: no replication typing, nothing to cast (the shard_map
-    # below runs with check_rep=False, so AD never inserts implicit psums).
-    return x
+    """Cast a replication-invariant value to device-varying under shard_map."""
+    return jax.lax.pcast(x, axis_name, to="varying")
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    """`jax.shard_map` across JAX versions.
-
-    Older JAX only has `jax.experimental.shard_map.shard_map`; its
-    replication-checking rewrite would insert the implicit grad psums the
-    varying-params cast in `_to_varying` exists to avoid, so it runs
-    unchecked there — the explicit collectives make every output replicated
-    before it crosses the shard_map boundary either way.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """`jax.shard_map` with replication checking on (its default)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
 
 
 def _maybe_normalize(images: jnp.ndarray) -> jnp.ndarray:
@@ -312,8 +289,8 @@ def _sentinel_tail(optimizer, schedule, state, grads, new_batch_stats,
         # ``opt_pred_cast`` (sharded update only): the opt-state leaves
         # are device-varying 1/world shards under shard_map's replication
         # typing, so the invariant skip predicate is cast varying for that
-        # subtree (`_to_varying`; a no-op on pre-vma JAX and everywhere
-        # else the whole state is replicated). The int8 codec's residuals
+        # subtree (`_to_varying`; everywhere else the whole state is
+        # replicated and no cast is passed). The int8 codec's residuals
         # share the varying predicate: a quarantined batch's quantization
         # error must be forgotten WITH the batch, or the next step's error
         # feedback would re-inject a slice of the poisoned gradient.
@@ -475,6 +452,16 @@ def _make_accum_body(
             jnp.zeros((), jnp.float32),
             jnp.zeros((), jnp.int32),
         )
+        if cast_params is not None:
+            # Under shard_map a scan carry must enter with the type it
+            # leaves with: per-shard grads, loss and correct come out
+            # device-varying, and so do the running stats of a model whose
+            # BatchNorm does not sync over the axis in its forward.
+            vary = functools.partial(jax.tree_util.tree_map, cast_params)
+            synced_bn = getattr(model, "axis_name", None) is not None
+            init = (vary(init[0]),
+                    init[1] if synced_bn else vary(init[1]),
+                    vary(init[2]), vary(init[3]))
         (grads, new_batch_stats, loss_sum, correct), _ = jax.lax.scan(
             micro, init, {"image": images, "label": labels}
         )
@@ -621,7 +608,7 @@ def make_multi_step(
     ``lax.scan`` over the same step body `make_train_step` compiles, fed by a
     device-resident pool of batches with a leading (num_steps,) axis. One
     dispatch executes the whole window, so host→device round-trips (launch
-    latency, relay RTT in tunneled setups) amortize across the window — the
+    latency) amortize across the window — the
     reference's eager loop pays them every step
     (`/root/reference/cifar_example_ddp.py:94-107`). Semantically identical
     to calling the single step ``num_steps`` times (equivalence-tested);
@@ -1000,9 +987,8 @@ def make_local_step(
     IS the decomposed exchange); DP301 verifies the K-bucket schedule
     covers the union of gradient leaves exactly once.
 
-    ``cast_params=False`` skips the varying-cast of the params (a no-op on
-    pre-vma JAX anyway); the analyzer uses it to trace outside a real
-    `shard_map` scope.
+    ``cast_params=False`` skips the varying-cast of the params; the
+    analyzer uses it to trace outside a real `shard_map` scope.
     """
     from tpu_dp.parallel import bucketing, collectives, quant
     from tpu_dp.parallel.dist import DATA_AXIS

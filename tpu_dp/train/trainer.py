@@ -237,8 +237,6 @@ class Trainer:
         # re-check against the LIVE topology: parse_cli validated the file
         # but could not see the mesh. Typed refusal, never silent drift.
         if cfg.train.profile:
-            import jax
-
             from tpu_dp.tune.profile import check_key, load_profile
 
             check_key(load_profile(cfg.train.profile),
@@ -501,12 +499,8 @@ class Trainer:
             from tpu_dp.obs.costs import EfficiencyMeter
             from tpu_dp.obs.costs import peak_flops as _peak_flops
 
-            peak = cfg.obs.peak_flops_override or None
-            if peak is None:
-                try:
-                    peak = _peak_flops(jax.devices()[0].device_kind)
-                except Exception:
-                    peak = None
+            peak = (cfg.obs.peak_flops_override
+                    or _peak_flops(jax.devices()[0].device_kind))
             self._eff = EfficiencyMeter(peak=peak,
                                         capacity=cfg.obs.span_capacity)
         # Flight recorder (tpu_dp/obs/flightrec.py): the always-on black
@@ -2411,7 +2405,7 @@ class Trainer:
         self._build_training()
 
         # Reload through the resharding path: the target carries the NEW
-        # world's optimizer layout; `load_checkpoint` relays the saved
+        # world's optimizer layout; `load_checkpoint` re-lays out the saved
         # opt state onto it value-preserving (docs/PERF.md). A corrupt
         # agreed snapshot (checksum refusal) self-heals onto the
         # next-older complete candidate — every survivor reads the same

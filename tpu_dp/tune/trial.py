@@ -12,9 +12,8 @@ trial — the space grammar already refuses to sweep them.
 Every completed trial is archived to `benchmarks/results.jsonl` tagged
 ``tune_trial: true`` (and, like every archived row since this PR,
 stamped with ``schema`` + ``config_hash``), so trials, BENCH emissions
-and `obsctl diff` baselines join on one key. The tag keeps trial rows —
-deliberately tiny, short-fence measurements — out of
-`last_good_archived`'s stale-headline pool.
+and `obsctl diff` baselines join on one key. The tag tells trial rows —
+deliberately tiny, short-fence measurements — from headline rows.
 """
 
 from __future__ import annotations

@@ -1,5 +1,6 @@
 """Utilities: rank-0 logging, throughput metering, profiling, determinism."""
 
+from tpu_dp.utils.compile_cache import place_compile_cache
 from tpu_dp.utils.determinism import (
     check_cross_process_consistency,
     check_replica_consistency,
@@ -22,6 +23,7 @@ __all__ = [
     "local_digest",
     "log0",
     "parse_profile_steps",
+    "place_compile_cache",
     "print0",
     "profile_trace",
 ]

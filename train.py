@@ -29,11 +29,12 @@ import sys
 from tpu_dp.config import parse_cli
 from tpu_dp.resilience import DivergedError, PreemptedError
 from tpu_dp.train.trainer import Trainer, run_elastic
-from tpu_dp.utils import print0
+from tpu_dp.utils import place_compile_cache, print0
 
 
 def main(argv=None) -> int:
     cfg = parse_cli(sys.argv[1:] if argv is None else argv)
+    place_compile_cache()
     try:
         if cfg.resilience.elastic:
             # The relaunch-aware driver: identical to Trainer(cfg).fit()
