@@ -17,11 +17,14 @@ Default run (one chip):
    shapes the trainer gives it;
 3. the trainer once more with ``--train.pallas_xent=true``.
 
-``--chips 4`` runs only the data-parallel comparison: ten steps of the
-same seed and batch stream on one device, on four with the replicated
-(GSPMD) update, and on four with the sharded update; per-step losses must
-agree, state and batch must really be spread over the four devices, and
-the compiled steps must hold the collectives each mode is made of.
+``--chips 4`` runs only the data-parallel comparison, each run through
+`train.main` from the same seed and batches: on one device, on four with
+the replicated (GSPMD) update, and on four with the sharded update — ten
+steps, a checkpoint, and an eleventh step after ``--resume=auto``.
+Per-step and eval losses must agree with one device; the restored state
+and the batch must really be spread over the four devices; and the
+compiled steps must reduce the gradients (and, sharded, gather the
+parameters) across them, whatever ops the compiler chose for it.
 
 The times printed are smoke readings of one short run, not a benchmark.
 The last line of stdout is the result:
@@ -48,6 +51,11 @@ WORK = Path(__file__).resolve().parent / ".chip_smoke"
 #: atol 2e-5) and somewhat more through the MXU's bf16 passes. A gradient
 #: scaled or reduced wrongly moves the loss by >1e-1 within three steps.
 DP_LOSS_ATOL = 2e-3
+#: The eval loss is allowed this many times more: it reads the final
+#: parameters, which carry every step's difference, through BatchNorm's
+#: running statistics on images the model was not trained on. State restored
+#: wrongly or onto the wrong device moves it by >1e-1 as well.
+EVAL_SLACK = 5
 
 
 class _Tee(io.TextIOBase):
@@ -132,14 +140,19 @@ def phase_trainer(clock, *, model="resnet18", batch=2048, steps=8,
     argv = [*_trainer_argv(model, batch, steps), *extra]
     clock.lap()
     summary, epochs = run_trainer([*argv, "--train.epochs=2"], ckpt)
-    cold_s, _, _ = clock.lap()
+    first_s, first_hits, first_requests = clock.lap()
+    # The cache directory may outlive the machine (the variable can point
+    # at a persistent one): the first run is cold only if nothing hit.
+    first = ("cold" if first_hits == 0 else
+             "warm" if first_hits == first_requests else "partly warm")
     step_ms = _check_run(summary, epochs, batch)
     assert [e["epoch"] for e in epochs] == [1, 2], epochs
     assert epochs[-1]["loss"] < epochs[0]["loss"], (
         f"loss did not fall: {[e['loss'] for e in epochs]}")
     saved = sorted(p.name for p in ckpt.glob("step_*"))
     assert saved, f"no checkpoint under {ckpt}"
-    print(f"smoke reading, first run: compile {cold_s:.1f} s (cold cache), "
+    print(f"smoke reading, first run: compile {first_s:.1f} s ({first}: "
+          f"{first_hits}/{first_requests} programs from the compile cache), "
           f"steady {step_ms:.2f} ms/step, "
           f"{summary['images_per_sec']:.0f} images/s, "
           f"epoch losses {[round(e['loss'], 4) for e in epochs]}, "
@@ -156,7 +169,8 @@ def phase_trainer(clock, *, model="resnet18", batch=2048, steps=8,
     print(f"smoke reading, resumed run: epoch 3 loss "
           f"{epochs[-1]['loss']:.4f}; compile {warm_s:.1f} s with "
           f"{hits}/{requests} programs served from the compile cache "
-          f"(cold {cold_s:.1f} s | warm {warm_s:.1f} s)")
+          f"(first run, {first}: {first_s:.1f} s | resumed run, warm: "
+          f"{warm_s:.1f} s)")
 
 
 def _assert_kernel(fn, args, name):
@@ -264,28 +278,6 @@ def phase_trainer_pallas_xent(clock, *, model="resnet18", batch=2048,
           f"{step_ms:.2f} ms/step, epoch loss {epochs[-1]['loss']:.4f}")
 
 
-def _ten_steps(argv, steps, ckpt_dir):
-    """Build the Trainer a user's flags would build and take ``steps``
-    steps of its own pipeline through its own compiled step. Returns
-    (trainer, per-step losses, first placed batch, label digests)."""
-    import numpy as np
-
-    from tpu_dp.config import parse_cli
-    from tpu_dp.train.trainer import Trainer
-
-    tr = Trainer(parse_cli([*argv, f"--train.ckpt_dir={ckpt_dir}"]))
-    tr.train_pipe.set_epoch(0)
-    losses, digests, first = [], [], None
-    for _, batch in tr.train_pipe.windows(1):
-        if first is None:
-            first = batch
-        digests.append(int(np.asarray(batch["label"], np.int64).sum()))
-        tr.state, metrics = tr.train_step(tr.state, batch)
-        losses.append(float(metrics["loss"]))
-    assert len(losses) == steps, (len(losses), steps)
-    return tr, losses, first, digests
-
-
 def _on_all(tree, devices, name):
     """Every leaf's sharding spans exactly ``devices``."""
     import jax
@@ -299,21 +291,103 @@ def _on_all(tree, devices, name):
     return len(leaves)
 
 
-def compare_data_parallel(*, model="resnet18", batch=512, steps=10,
-                          world=4, atol=DP_LOSS_ATOL):
-    """One device vs ``world`` devices, replicated and sharded update."""
+def _resume_and_inspect(name, argv, ckpt, *, devices, batch, epochs):
+    """The last epoch as `train.main` runs it after ``--resume=auto`` —
+    `Trainer(cfg).fit()` — keeping the Trainer, to see where the state it
+    restored and trained and a batch of its pipeline live, and what
+    collectives its compiled step holds. Returns (train loss, eval loss)."""
     import jax
 
-    from tpu_dp.analysis.hlo import count_collectives
+    from tpu_dp.analysis.hlo import (
+        _shape_elements,
+        collect_ops,
+        count_collectives,
+    )
+    from tpu_dp.config import parse_cli
+    from tpu_dp.train.trainer import Trainer
+
+    world = len(devices)
+    tr = Trainer(parse_cli([*argv, f"--train.epochs={epochs}",
+                            "--resume=auto", f"--train.ckpt_dir={ckpt}"]))
+    assert tr.num_devices == world
+    assert (tr.start_epoch, int(tr.state.step)) == (epochs - 1, epochs - 1), (
+        f"{name}: resumed at epoch {tr.start_epoch}, step "
+        f"{int(tr.state.step)}, not {epochs - 1}")
+    result = tr.fit()
+    assert len(result["history"]) == 1 and int(tr.state.step) == epochs
+    n = _on_all(tr.state.params, devices, f"{name} params")
+    _on_all(tr.state.batch_stats or tr.state.step, devices,
+            f"{name} batch_stats")
+    tr.train_pipe.set_epoch(0)
+    _, first = next(iter(tr.train_pipe.windows(1)))
+    _on_all(first, devices, f"{name} batch")
+    for key, leaf in first.items():
+        rows = {s.data.shape[0] for s in leaf.addressable_shards}
+        assert rows == {batch // world}, (name, key, rows)
+    opt = jax.tree_util.tree_leaves(tr.state.opt_state)
+    _on_all(opt, devices, f"{name} opt_state")
+    per_device = sum(s.data.size for leaf in opt
+                     for s in leaf.addressable_shards
+                     if s.device == jax.devices()[0])
+    total = sum(leaf.size for leaf in opt)
+    if name == "sharded":
+        assert per_device * world == total, (per_device, total)
+    else:
+        assert per_device == total, (per_device, total)
+    # The step holds the collectives this mode is made of: as traced
+    # (StableHLO) and as the compiler left them. XLA may rewrite them — on
+    # a 2x2 host the v5e compiler turns the reduce-scatters into one
+    # combined all-reduce and slices, and the all-gathers of leaves under
+    # 512 elements into an all-reduce — so the compiled text is held to
+    # what no rewrite takes away: nearly every gradient element is reduced
+    # across devices (the metric scalars' all-reduces do not count) and,
+    # sharded, nearly every parameter element is gathered back.
+    lowered = tr.train_step.lower(tr.state, first)
+    traced = lowered.as_text()
+    compiled = lowered.compile().as_text()
+    ops, counts = collect_ops(compiled), count_collectives(compiled)
+    n_params = sum(x.size for x in jax.tree_util.tree_leaves(tr.state.params))
+    reduced = sum(_shape_elements(op.shape) for op in ops
+                  if op.kind == "all-reduce" and not op.is_scalar)
+    reduced += world * sum(_shape_elements(op.shape) for op in ops
+                           if op.kind == "reduce-scatter")
+    gathered = sum(_shape_elements(op.shape) for op in ops
+                   if op.kind == "all-gather")
+    assert reduced >= 0.99 * n_params, (name, reduced, n_params, counts)
+    if name == "sharded":
+        assert "reduce_scatter" in traced and "all_gather" in traced
+        assert gathered >= 0.99 * n_params, (name, gathered, n_params, counts)
+    else:
+        assert "reduce_scatter" not in traced
+        assert not counts.get("reduce-scatter"), counts
+    print(f"{name}: resumed at step {epochs - 1}; {n} param leaves and the "
+          f"batch on {world} devices ({batch // world} rows each), "
+          f"optimizer state {per_device}/{total} elements per device; "
+          f"compiled step reduces {reduced} and gathers {gathered} "
+          f"elements for {n_params} parameters, collectives {counts}")
+    return result["history"][0]["loss"], result["eval"]["loss"]
+
+
+def compare_data_parallel(*, model="resnet18", batch=512, steps=10,
+                          world=4, atol=DP_LOSS_ATOL):
+    """One device vs ``world`` devices, replicated and sharded update. The
+    train set is one global batch, so an epoch is a step and every
+    per-epoch loss a per-step loss. One device trains ``steps + 1`` epochs
+    in one go through `train.main`; the others train ``steps`` through
+    `train.main`, checkpoint, and take the last one after ``--resume=auto``
+    (`_resume_and_inspect`) — so the last loss also says the optimizer
+    state came back from the checkpoint onto the devices it was saved
+    from."""
+    import jax
 
     devices = set(jax.devices()[:world])
     assert len(devices) == world, f"{len(jax.devices())} device(s) < {world}"
     base = [
         f"--model.name={model}", "--data.dataset=synthetic",
-        f"--data.batch_size={batch}",
-        f"--data.synthetic_train_size={batch * steps}",
+        f"--data.batch_size={batch}", f"--data.synthetic_train_size={batch}",
         f"--data.synthetic_test_size={batch}", "--data.device_resident=off",
-        "--optim.lr=0.05", "--train.seed=0",
+        "--optim.lr=0.05", "--optim.schedule=constant", "--train.seed=0",
+        "--train.log_every=1",
     ]
     runs = {
         "one device": [*base, "--parallel.num_devices=1"],
@@ -321,66 +395,40 @@ def compare_data_parallel(*, model="resnet18", batch=512, steps=10,
         "sharded": [*base, f"--parallel.num_devices={world}",
                     "--train.update_sharding=sharded"],
     }
-    losses, digests = {}, {}
+    losses, evals = {}, {}
     for name, argv in runs.items():
-        tr, losses[name], first, digests[name] = _ten_steps(
-            argv, steps, WORK / f"dp_{name.replace(' ', '_')}")
-        assert all(math.isfinite(x) for x in losses[name]), losses[name]
-        print(f"{name}: losses {[round(x, 5) for x in losses[name]]}")
+        ckpt = WORK / f"dp_{name.replace(' ', '_')}"
+        n_epochs = steps + 1 if name == "one device" else steps
+        summary, epochs = run_trainer(
+            [*argv, f"--train.epochs={n_epochs}"], ckpt)
+        assert [e["epoch"] for e in epochs] == list(range(1, n_epochs + 1))
+        losses[name] = [e["loss"] for e in epochs]
+        evals[name] = summary["eval"]["loss"]
         if name == "one device":
-            assert tr.num_devices == 1
-            continue
-        # Placement: state and batch really spread over all the devices.
-        assert tr.num_devices == world
-        n = _on_all(tr.state.params, devices, f"{name} params")
-        _on_all(tr.state.batch_stats or tr.state.step, devices,
-                f"{name} batch_stats")
-        _on_all(first, devices, f"{name} batch")
-        for key, leaf in first.items():
-            rows = {s.data.shape[0] for s in leaf.addressable_shards}
-            assert rows == {batch // world}, (name, key, rows)
-        opt = jax.tree_util.tree_leaves(tr.state.opt_state)
-        _on_all(opt, devices, f"{name} opt_state")
-        per_device = sum(s.data.size for leaf in opt
-                         for s in leaf.addressable_shards
-                         if s.device == jax.devices()[0])
-        total = sum(leaf.size for leaf in opt)
-        if name == "sharded":
-            assert per_device * world == total, (per_device, total)
+            assert summary["devices"] == 1, summary
         else:
-            assert per_device == total, (per_device, total)
-        # The step holds the collectives this mode is made of: as traced
-        # (StableHLO) and as the chip's compiler left them. XLA may rewrite
-        # a reduce-scatter as an all-reduce and a slice, so the compiled
-        # text is held to "a gradient reduction and a gather", and what
-        # the compiler made of them is printed.
-        lowered = tr.train_step.lower(tr.state, first)
-        traced = lowered.as_text()
-        counts = count_collectives(lowered.compile().as_text())
-        if name == "sharded":
-            assert "reduce_scatter" in traced and "all_gather" in traced
-            assert counts.get("all-gather") and (
-                counts.get("reduce-scatter") or counts.get("all-reduce")
-            ), counts
-        else:
-            assert "reduce_scatter" not in traced
-            assert counts.get("all-reduce") and not counts.get(
-                "reduce-scatter"), counts
-        print(f"{name}: {n} param leaves and the batch on {world} devices "
-              f"({batch // world} rows each), optimizer state "
-              f"{per_device}/{total} elements per device, "
-              f"collectives {counts}")
-    ref = losses["one device"]
+            assert summary["devices"] == world, summary
+            last, evals[name] = _resume_and_inspect(
+                name, argv, ckpt, devices=devices, batch=batch,
+                epochs=steps + 1)
+            losses[name].append(last)
+        assert all(math.isfinite(x) for x in [*losses[name], evals[name]])
+        print(f"{name}: losses {[round(x, 5) for x in losses[name]]}, "
+              f"eval loss {evals[name]:.5f}")
     for name in ("replicated", "sharded"):
-        assert digests[name] == digests["one device"], (
-            f"{name} saw a different batch stream")
-        diffs = [abs(a - b) for a, b in zip(losses[name], ref)]
+        diffs = [abs(a - b) for a, b in
+                 zip(losses[name], losses["one device"])]
+        eval_diff = abs(evals[name] - evals["one device"])
         print(f"{name} vs one device: |loss difference| per step "
-              f"{[float(f'{d:.1e}') for d in diffs]}, "
-              f"max {max(diffs):.3e} (allowed {atol:.0e})")
+              f"{[float(f'{d:.1e}') for d in diffs]} (the last after "
+              f"--resume=auto), max {max(diffs):.3e} (allowed {atol:.0e}); "
+              f"eval {eval_diff:.3e} (allowed {EVAL_SLACK * atol:.0e})")
         assert max(diffs) <= atol, (
             f"{name} departs from one device by {max(diffs):.3e} > "
             f"{atol:.0e}")
+        assert eval_diff <= EVAL_SLACK * atol, (
+            f"{name}: eval loss departs from one device by "
+            f"{eval_diff:.3e} > {EVAL_SLACK * atol:.0e}")
 
 
 def main(argv=None) -> int:

@@ -46,13 +46,24 @@ def test_alone_in_a_directory_it_exits_nonzero(tmp_path):
     _assert_refused(_run(tmp_path, tmp_path / "chip_smoke.py"))
 
 
+@pytest.fixture()
+def scratch_run(tmp_path, monkeypatch):
+    """The script's working directory moved under the test's, and
+    `train.main` kept from placing the compile cache: that setting is the
+    process's, and the tests run without a persistent cache."""
+    import train
+
+    monkeypatch.setattr(chip_smoke, "WORK", tmp_path)
+    monkeypatch.setattr(train, "place_compile_cache", lambda: None)
+
+
 @pytest.mark.parametrize("model,batch", [("net", 32), ("resnet18", 16)])
 def test_three_way_comparison_on_four_virtual_devices(
-        tmp_path, monkeypatch, capsys, model, batch):
-    """One device vs four (replicated) vs four (sharded): same losses,
+        scratch_run, capsys, model, batch):
+    """One device vs four (replicated) vs four (sharded), through
+    `train.main` and a ``--resume=auto``: same per-step and eval losses,
     state and batch on all four devices, a quarter of the optimizer state
     each when sharded, and each mode's collectives in its compiled step."""
-    monkeypatch.setattr(chip_smoke, "WORK", tmp_path)
     # True f32 on the CPU: a few ulp of reduction order per step.
     chip_smoke.compare_data_parallel(model=model, batch=batch, steps=3,
                                      world=4, atol=1e-4)
@@ -61,10 +72,9 @@ def test_three_way_comparison_on_four_virtual_devices(
     assert "'reduce-scatter'" in out and "'all-gather'" in out
 
 
-def test_three_way_comparison_catches_a_departure(tmp_path, monkeypatch):
+def test_three_way_comparison_catches_a_departure(scratch_run):
     """The loss tolerance is what fails when a run departs from one
     device: an allowance of zero rounding cannot be met."""
-    monkeypatch.setattr(chip_smoke, "WORK", tmp_path)
     with pytest.raises(AssertionError, match="departs from one device"):
         chip_smoke.compare_data_parallel(model="resnet18", batch=16,
                                          steps=3, world=4, atol=0.0)

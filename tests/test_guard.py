@@ -315,8 +315,9 @@ def _oracle_params_skipping(cfg, skip_batches=(), extra_epochs=None):
     never saw the batches in ``skip_batches`` (global batch indices).
 
     Drives the plain (non-sentinel) `make_train_step` directly: the
-    sentinel's disarmed seam and lr_scale=1.0 are multiply-by-1.0 bitwise
-    identities, so the two programs must agree bit-for-bit.
+    sentinel's disarmed seam and lr_scale=1.0 are multiply-by-1.0
+    identities, so the two programs compute the same updates and agree to
+    the rounding between two compilations (`_assert_same_trajectory`).
     """
     from tpu_dp.config import Config
     from tpu_dp.data.cifar import load_dataset
@@ -358,9 +359,9 @@ def _oracle_params_skipping(cfg, skip_batches=(), extra_epochs=None):
 @pytest.mark.resilience
 def test_nan_skip_matches_never_saw_batch_oracle(tmp_path):
     """ISSUE 8 acceptance: nan:step=3 + action=skip completes with final
-    params bitwise-identical to an oracle that never trained on batch 3 —
-    the quarantined update was withheld on-device (step counter frozen),
-    so every later update replays the oracle's trajectory exactly."""
+    params matching, to rounding, an oracle that never trained on batch 3
+    — the quarantined update was withheld on-device (step counter frozen),
+    so every later update replays the oracle's trajectory."""
     from tpu_dp.train.trainer import Trainer
 
     cfg = _guard_cfg(tmp_path, **{"resilience.fault": "nan:step=3",
@@ -404,10 +405,11 @@ def test_guard_off_run_unaffected_by_guard_code(tmp_path):
 
 
 @pytest.mark.resilience
-def test_sentinel_on_clean_run_bitwise_equals_plain(tmp_path):
-    """The sentinel itself is a bitwise no-op on a healthy run: guard on,
-    nothing triggering — final params equal the plain factory's (the
-    disarmed seam and neutral guard_in are exact identities)."""
+def test_sentinel_on_clean_run_matches_plain_within_rounding(tmp_path):
+    """The sentinel changes no update on a healthy run: guard on, nothing
+    triggering — final params match the plain factory's to rounding (the
+    disarmed seam and neutral guard_in are identities in the arithmetic;
+    the two are still two compiled programs, `_assert_same_trajectory`)."""
     from tpu_dp.train.trainer import Trainer
 
     tr = Trainer(_guard_cfg(tmp_path))

@@ -563,15 +563,18 @@ def test_dp303_fires_on_dropped_donation():
     text, _, warns = hlo.lower_and_compile(
         jitted, (jnp.zeros((32, 32), jnp.float32),)
     )
+    # JAX 0.9.0 no longer warns about the unusable donation under AOT
+    # lower+compile (``warns`` is empty here), so the message a warning
+    # must surface in is fed one by hand.
+    warned = "Some donated buffers were not usable: f32[32,32]"
     findings, record = hlo.analyze_module(
         text, label="drop", where=("x.py", 1), world=8,
-        donated_leaves=1, donation_warnings=warns,
+        donated_leaves=1, donation_warnings=[*warns, warned],
     )
     assert [f.rule for f in findings] == ["DP303"]
     assert record["aliased_inputs"] == 0
-    # Where lowering warns about the unusable donation, the finding
-    # surfaces the warning instead of swallowing it.
-    assert all(w in findings[0].message for w in warns)
+    # The lowering warning is surfaced in the finding, not swallowed.
+    assert warned in findings[0].message
 
 
 def test_dp303_clean_on_real_donation():

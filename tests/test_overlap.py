@@ -12,13 +12,14 @@ The correctness story, proven on the 8-device CPU mesh:
    monolithic path bitwise on this backend (the documented contract is
    reduction-order tolerance, docs/PERF.md); bf16/int8 wires within their
    codec bounds; per-bucket error-feedback residuals; sub-threshold
-   buckets ride the f32 fallback; the compiled schedule issues buckets in
-   reverse production order (the overlap property's precondition).
+   buckets ride the f32 fallback; the compiled schedule keeps the K
+   bucket exchanges separate (the overlap property's precondition).
 3. **Step level** — bucketed training parity vs the replicated f32
    reference across all three wire dtypes lives in the ONE wire-dtype
-   parity harness (tests/test_quant.py, bucketed × {f32, bf16, int8});
-   here: the error-feedback telescoping property survives bucketing
-   (no-EF ablation ≥ 2x worse) and the windowed multi-step composition.
+   parity harness (tests/test_quant.py, bucketed × {f32, bf16, int8}),
+   and the error-feedback telescoping property survives bucketing in the
+   ONE telescoping test there (per leaf × per bucket, 24 steps); here:
+   the windowed multi-step composition.
 4. **Analyzer** — DP301 accepts the K-bucket schedule and rejects a
    dropped or duplicated bucket; DP304's fingerprint artifact round-trips
    the bucket layout; Level 2 still proves exactly-one-reduction-per-leaf
@@ -346,39 +347,6 @@ def _states(bucket_mb=0.05):
         state_s.params, WORLD, 256,
         bucket_bytes=bucketing.parse_bucket_mb(bucket_mb)))
     return model, opt, sopt, state_r, state_s, state_q
-
-
-def test_bucketed_error_feedback_ablation_is_measurably_worse(mesh8):
-    """The telescoping property survives bucketing: over a 5-step
-    fixed-seed run the no-EF ablation drifts more than 1.5x farther from
-    the f32 trajectory than the per-bucket-EF run (same contract, and the
-    same short horizon, as the per-leaf harness in tests/test_quant.py),
-    at 0.01 MB buckets; at 0.05 MB × block 256 the margin compresses —
-    cross-leaf blocks share one absmax scale, the documented
-    bucket-size/block-size coupling of docs/PERF.md."""
-    model, opt, sopt, state_r, _, state_q = _states(bucket_mb=0.01)
-    lr = constant_lr(0.01)
-    step_r = make_train_step_shard_map(model, opt, mesh8, lr)
-    step_ef = make_train_step_shard_map(
-        model, sopt, mesh8, lr, update_sharding="sharded",
-        collective_dtype="int8", bucket_mb=0.01)
-    step_no = make_train_step_shard_map(
-        model, sopt, mesh8, lr, update_sharding="sharded",
-        collective_dtype="int8", quant_error_feedback=False,
-        bucket_mb=0.01)
-    sr, se, sn = _copy(state_r), _copy(state_q), _copy(state_q)
-    for i in range(5):
-        batch = _make_batch(i)
-        sr, _ = step_r(sr, batch)
-        se, _ = step_ef(se, batch)
-        sn, _ = step_no(sn, batch)
-    d_ef = _l2(se.params, sr.params)
-    d_no = _l2(sn.params, sr.params)
-    assert d_ef * 1.5 < d_no, (d_ef, d_no)
-    for leaf in jax.tree_util.tree_leaves(sn.residuals):
-        np.testing.assert_array_equal(np.asarray(leaf), 0.0)
-    for leaf in jax.tree_util.tree_leaves(se.residuals):
-        assert np.abs(np.asarray(leaf)).max() > 0
 
 
 def test_bucketed_multi_step_window_tracks_f32(mesh8):

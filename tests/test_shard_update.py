@@ -113,6 +113,44 @@ def test_psum_scatter_all_gather_is_bitwise_pmean(mesh8):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def test_invariant_all_gather_is_there_and_typed_replicated(mesh8):
+    """`collectives.all_gather` rests on a JAX-internal primitive: 0.9.0
+    ships `all_gather_invariant` in `jax._src.lax.parallel` with no public
+    spelling (`lax.all_gather` is typed varying). A JAX bump that moves it
+    breaks the import in `collectives.py` for every mode; one that gives
+    `lax.all_gather` an invariant result makes the private import
+    needless. Either way this test says so by name."""
+    from jax import lax
+    from jax._src.lax import parallel
+    from jax.sharding import PartitionSpec as P
+
+    from tpu_dp.parallel.dist import DATA_AXIS
+
+    assert collectives.all_gather_invariant is getattr(
+        parallel, "all_gather_invariant", None), (
+        "jax._src.lax.parallel.all_gather_invariant moved: "
+        "tpu_dp/parallel/collectives.py imports it at module load")
+    seen = {}
+
+    def gather(x):
+        ours = collectives.all_gather_invariant(x, DATA_AXIS, axis=0,
+                                                tiled=True)
+        public = lax.all_gather(x, DATA_AXIS, axis=0, tiled=True)
+        seen["ours"], seen["public"] = (jax.typeof(ours).vma,
+                                        jax.typeof(public).vma)
+        return ours
+
+    # Replication checking on (the default): out_specs=P() is only
+    # accepted because the gather itself types its result invariant.
+    out = jax.jit(jax.shard_map(gather, mesh=mesh8, in_specs=P(DATA_AXIS),
+                                out_specs=P()))(jnp.arange(16.0))
+    np.testing.assert_array_equal(np.asarray(out), np.arange(16.0))
+    assert seen["ours"] == frozenset(), seen
+    assert seen["public"] == {DATA_AXIS}, (
+        f"lax.all_gather is no longer typed varying ({seen}): use it and "
+        f"drop the private import")
+
+
 def test_shard_slice_matches_scatter_layout(mesh8):
     """shard_slice hands replica i exactly the slice psum_scatter would:
     gathering the slices reconstructs the original leaf."""

@@ -313,7 +313,7 @@ def verify_repo_step(
     parameter output must still contain exactly ONE data-axis reduction —
     a leaf reduced in two buckets (or bucketed AND monolithically) is the
     same DP202 double-averaging bug, just better hidden. The
-    `optimization_barrier` token chain that pins issue order deliberately
+    `optimization_barrier` token chain that keeps buckets apart deliberately
     couples buckets through their *inputs* only, so it never drags a
     neighbouring bucket's collective onto a foreign leaf's slice.
 

@@ -12,8 +12,8 @@ path: this module is the ONE source of truth for how gradient leaves map to
 buckets. The same plan drives
 
 - the wire schedule (`collectives.psum_scatter_bucketed` /
-  `psum_scatter_quant_bucketed` — one collective per bucket, issue order
-  pinned by `jax.lax.optimization_barrier` token chaining),
+  `psum_scatter_quant_bucketed` — one collective per bucket, kept separate
+  by `jax.lax.optimization_barrier` token chaining),
 - the error-feedback residual layout (`quant.init_residuals` — one residual
   per *quantizing bucket*, keyed by the bucket's self-describing
   composition key),

@@ -237,19 +237,22 @@ def psum_scatter_quant(
 
 
 def _issue_barrier(payload, token):
-    """Pin a bucket's issue order with `jax.lax.optimization_barrier`.
+    """Keep a bucket's exchange separate with `jax.lax.optimization_barrier`.
 
     The scheduling hint of the bucketed schedule (docs/PERF.md "Overlapped
     collectives"): each bucket's wire payload is coupled to a scalar token
-    carried from the PREVIOUS bucket's barrier, so (a) the buckets'
-    collectives keep their reverse-production issue order — the first
-    bucket's reduction can start while backward still computes the earlier
-    layers — and (b) the optimizer passes (CSE, fusion, collective
-    combining) cannot glob the per-bucket payloads back into one monolithic
-    exchange across the barrier. The token rides the barrier's *input*
-    side only: bucket i+1's issue never waits on bucket i's *completion*,
-    so XLA's latency-hiding scheduler stays free to keep several exchanges
-    in flight while it interleaves the remaining backward compute.
+    carried from the PREVIOUS bucket's barrier, so the optimizer passes
+    (CSE, fusion, collective combining) cannot glob the per-bucket payloads
+    back into one monolithic exchange across the barrier. The chain
+    sequences the *barriers* in plan order (reverse production: bucket 0's
+    payload passes first, while backward still computes the earlier
+    layers); it does NOT order the collectives behind them. The token rides
+    the barrier's *input* side only — bucket i+1's issue never waits on
+    bucket i's *completion* — so XLA's scheduler stays free to keep several
+    exchanges in flight under the remaining backward compute, and equally
+    free to place two independent exchanges either way round: the compiled
+    order usually follows the plan and is not guaranteed to (XLA of JAX
+    0.9.0 on the CPU mesh swaps Net's last two small buckets at 0.01 MB).
     """
     return lax.optimization_barrier((payload, token))
 
@@ -301,8 +304,8 @@ def psum_scatter_bucketed(
     with the analyzer and the wire report), each bucket's leaves are
     concatenated in the world-chunked layout (`_bucket_rows`) and reduced
     by ONE tiled reduce-scatter, with `optimization_barrier` token
-    chaining pinning the issue order so XLA can hide each bucket's wire
-    time under the remaining backward compute. Per-leaf output layout is
+    chaining keeping the K exchanges separate so XLA can hide each
+    bucket's wire time under the remaining backward compute. Per-leaf output layout is
     identical to `psum_scatter`'s (same flat shards, same padding), and
     the per-element reduction arithmetic is unchanged — on the same
     backend the bucketed f32 result is bitwise the unbucketed one

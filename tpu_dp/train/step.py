@@ -981,7 +981,7 @@ def make_local_step(
     reductions in reverse production order instead of one monolithic
     reduce-scatter — `collectives.psum_scatter_bucketed` (f32/bf16 wire)
     or `psum_scatter_quant_bucketed` (int8, per-bucket error-feedback
-    residuals) — with `optimization_barrier` issue-order hints so XLA's
+    residuals) — with `optimization_barrier` anti-combining hints so XLA's
     latency-hiding scheduler can overlap each bucket's wire time with the
     remaining backward compute. Sharded mode only (the overlap schedule
     IS the decomposed exchange); DP301 verifies the K-bucket schedule
