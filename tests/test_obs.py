@@ -18,6 +18,7 @@ from datetime import datetime
 import pytest
 
 from tpu_dp.obs import (
+    STEP_SPANS,
     Counters,
     HealthError,
     HealthMonitor,
@@ -576,12 +577,15 @@ def test_trainer_obs_full_end_to_end(tmp_path):
     per_step = [r for r in records if "spans" in r and "epoch" not in r]
     assert [r["step"] for r in per_step] == [1, 2, 3, 4]
     for r in per_step:
-        assert set(r["spans"]) == {"data_wait", "h2d", "dispatch", "device"}
+        # The line is written inside `telemetry`: it holds the spans that
+        # had ended by then; the ring (the rollup below) holds them all.
+        assert set(r["spans"]) == {"data_wait", "pre_dispatch", "h2d",
+                                   "dispatch", "device"}
         assert r["spans"]["device"] > 0.0  # full mode fences per window
         assert isinstance(r["counters"], dict)
     epoch_rec = next(r for r in records if "epoch" in r)
-    assert set(epoch_rec["spans"]) == {"data_wait", "h2d", "dispatch",
-                                       "device"}
+    assert set(epoch_rec["spans"]) == set(STEP_SPANS) | {"epoch_fence"}
+    assert epoch_rec["spans"]["epoch_fence"]["n"] == 1
     assert {"p50", "p95", "p99", "mean", "max", "n"} <= set(
         epoch_rec["spans"]["dispatch"])
 
@@ -635,7 +639,8 @@ def test_trainer_obs_basic_spans_without_sync(tmp_path):
     # never a fake zero) while data_wait/dispatch are real.
     assert [r for r in records if "spans" in r and "epoch" not in r] == []
     epoch_rec = next(r for r in records if "epoch" in r)
-    assert set(epoch_rec["spans"]) == {"data_wait", "dispatch"}
+    assert set(epoch_rec["spans"]) == (
+        set(STEP_SPANS) - {"h2d", "device"}) | {"epoch_fence"}
     assert epoch_rec["spans"]["dispatch"]["max"] > 0.0
     # Heartbeats + export still on.
     assert (tmp_path / "ck" / "obs" / "trace.perfetto.json").exists()

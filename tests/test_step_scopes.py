@@ -1,0 +1,108 @@
+"""The step's device phases are names in HLO metadata, and nothing else.
+
+`tpu_dp.input`, `.augment`, `.gather`, `.fwd_bwd`, `.grad_reduce` and
+`.update` (`train/step.py`) are `jax.named_scope`s: a device trace can be
+grouped by them only if every costly op carries exactly one, and they may
+not move a collective.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import re
+
+import jax
+import pytest
+
+from tpu_dp.analysis import hlo
+
+PHASES = {"tpu_dp.input", "tpu_dp.augment", "tpu_dp.gather",
+          "tpu_dp.fwd_bwd", "tpu_dp.grad_reduce", "tpu_dp.update"}
+#: What a step spends its time in, and what a trace would group by phase.
+COSTLY = ("convolution", "dynamic-slice", "dynamic-update-slice", "while",
+          "all-reduce", "gather")
+_OP = re.compile(r" = .*?[\])}] ([a-z\-]+)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def _step_program(tmp_path, model: str, resident: str):
+    """The program a benchmark cell runs, and its arguments, at batch 16 on
+    four devices: the resident window of one step, or the streamed step."""
+    from tpu_dp.config import Config
+    from tpu_dp.train.trainer import Trainer
+
+    c = Config()
+    c.data.dataset = "synthetic"
+    c.data.synthetic_train_size = 64
+    c.data.synthetic_test_size = 16
+    c.data.batch_size = 16
+    c.data.augment = True
+    c.data.device_resident = resident
+    c.model.name = model
+    c.parallel.num_devices = 4
+    c.train.ckpt_dir = str(tmp_path / f"ck_{model}_{resident}")
+    tr = Trainer(c)
+    if tr.resident_train is not None:
+        _, idx = next(iter(tr.train_pipe.index_windows(1)))
+        return tr._resident_loop(1), (tr.state, tr.resident_train, idx)
+    _, batch = next(iter(tr.train_pipe.windows(1)))
+    return tr.train_step, (tr.state, batch)
+
+
+def _phases_by_op(text: str) -> list[tuple[str, str | None, set]]:
+    """(op kind, op_name, phases in it) of every costly op of an HLO text."""
+    out = []
+    for line in text.splitlines():
+        m = _OP.search(line)
+        if m is None or m.group(1) not in COSTLY:
+            continue
+        name = _OP_NAME.search(line)
+        name = name.group(1) if name else None
+        out.append((m.group(1), name,
+                    set(re.findall(r"tpu_dp\.\w+", name or ""))))
+    return out
+
+
+@pytest.mark.parametrize("resident", ["on", "off"])
+def test_every_costly_op_carries_one_phase(tmp_path, resident):
+    fn, args = _step_program(tmp_path, "resnet18", resident)
+    text, _, _ = hlo.lower_and_compile(fn, args)
+    ops = _phases_by_op(text)
+    kinds = {k for k, _, _ in ops}
+    assert {"convolution", "all-reduce", "while", "gather"} <= kinds
+    for kind, name, phases in ops:
+        assert len(phases) <= 1 and phases <= PHASES, (kind, name)
+    named = [(k, n) for k, n, p in ops if len(p) == 1]
+    bare = [(k, n) for k, n, p in ops if not p]
+    if resident == "on":
+        assert bare == []
+    else:
+        # The CPU compiler rewrites the streamed step's weight-gradient
+        # convolutions and gives the new ops no metadata at all: no name
+        # to lose a phase from. Every op that has a name has its phase.
+        assert all(k == "convolution" and n is None for k, n in bare), bare
+        assert len(named) > 2 * len(bare)
+    seen = {k: {next(iter(p)) for kk, _, p in ops if kk == k and p}
+            for k in kinds}
+    assert seen["convolution"] == {"tpu_dp.fwd_bwd"}
+    assert seen["all-reduce"] == {"tpu_dp.fwd_bwd"}
+    # The crop is the augmentation's loop, apart from the input's
+    # normalisation; the resident feed's gather has a phase of its own.
+    assert seen["while"] == {"tpu_dp.augment"}
+    assert "tpu_dp.augment" in seen["gather"]
+    assert ("tpu_dp.gather" in seen["gather"]) == (resident == "on")
+
+
+@pytest.mark.parametrize("resident", ["on", "off"])
+def test_scopes_leave_the_collective_fingerprint_alone(tmp_path, monkeypatch,
+                                                       resident):
+    """Metadata only: the program traced with every `named_scope` taken
+    out has the same collective schedule, op for op."""
+    with_scopes = hlo.program_fingerprint(
+        *_step_program(tmp_path / "with", "net", resident))
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    fn, args = _step_program(tmp_path / "without", "net", resident)
+    text, _, _ = hlo.lower_and_compile(fn, args)
+    assert not re.search(r"tpu_dp\.\w+", text)
+    assert hlo.schedule_digest(hlo.collect_ops(text)) == with_scopes

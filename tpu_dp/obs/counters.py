@@ -147,6 +147,11 @@ METRICS: dict[str, str] = {
     "obs.step_time_ms": "smoothed step time (gauge, ms)",
     "obs.goodput": "examples/s across the slice (gauge)",
     "obs.mfu": "model FLOPs utilization (gauge)",
+    # the trainer's dispatch boundary (obs/spans.py InflightSteps)
+    "loop.dispatches": "dispatches counted (an epoch's first left out)",
+    "loop.inflight_sum": "steps enqueued and unfinished, summed at dispatch",
+    "loop.inflight_steps": "steps enqueued and unfinished (gauge)",
+    "loop.dispatch_onto_idle": "dispatches that found the device drained",
     # fleet aggregation (obs/fleet.py — derived cross-rank signals)
     "fleet.step_skew_ms": "max-min step-boundary arrival skew (gauge, ms)",
     "fleet.skew_ratio": "slowest rank vs leave-one-out median (gauge)",

@@ -9,11 +9,13 @@ device-internal traces; this export answers the *host loop* questions:
 where did step 4017's 80 ms go, and on which rank).
 
 Layout: one trace *process* per rank (``pid`` = rank), one *thread* per
-span name (``tid`` — data_wait/h2d/dispatch/device stack as parallel
+span name (``tid`` — data_wait/pre_dispatch/…/hooks stack as parallel
 tracks), metadata events naming both, and counter snapshots as "C" events
 on a counters track. Span slices within a step are laid out back-to-back
 from the step's wall-clock start — exactly the order the trainer measures
-them in its loop, so the picture is honest, not reconstructed.
+them in its loop, so the picture is honest, not reconstructed; the one
+span that overlays others, ``epoch_gap``, ends where the first step's
+``dispatch`` ends.
 
 The format contract is pinned by `validate_trace` (used by the tests and
 the `--obs` CI lane): a file this module writes that Perfetto would
@@ -80,15 +82,25 @@ def to_trace_events(
         # Slices go out in the recorder's span order, laid back-to-back —
         # the loop measures them sequentially, so the timeline is honest.
         ordered = [n for n in STEP_SPANS if n in spans] + [
-            n for n in spans if n not in STEP_SPANS
+            n for n in spans if n not in STEP_SPANS and n != "epoch_gap"
         ]
+
+        slices = []  # (name, start µs, duration µs)
         for name in ordered:
             dur_us = max(0.0, float(spans[name]) * 1e3)  # ms → µs
+            slices.append((name, t_us, dur_us))
+            t_us += dur_us
+            if name == "dispatch" and "epoch_gap" in spans:
+                # Began at the last epoch's fence and ends here, with the
+                # epoch's first dispatch; it takes no place in the row.
+                gap_us = max(0.0, float(spans["epoch_gap"]) * 1e3)
+                slices.append(("epoch_gap", max(0.0, t_us - gap_us), gap_us))
+        for name, start_us, dur_us in slices:
             ev = {
                 "name": name,
                 "cat": "step",
                 "ph": "X",
-                "ts": round(t_us, 3),
+                "ts": round(start_us, 3),
                 "dur": round(dur_us, 3),
                 "pid": rank,
                 "tid": _span_tid(name, gen, tid_order),
@@ -97,7 +109,6 @@ def to_trace_events(
             if gen:
                 ev["args"]["gen"] = gen
             events.append(ev)
-            t_us += dur_us
     for (gen, name), tid in sorted(tid_order.items(), key=lambda kv: kv[1]):
         events.append({
             "name": "thread_name", "ph": "M", "pid": rank, "tid": tid,

@@ -72,7 +72,7 @@ from tpu_dp.obs.fleet import (
     summarize as fleet_summarize,
 )
 from tpu_dp.obs.health import HealthMonitor
-from tpu_dp.obs.spans import percentile
+from tpu_dp.obs.spans import percentile, tile_ms
 from tpu_dp.obs.tail import JsonlTail, StreamTailer, read_jsonl
 
 #: quarantine-log kinds → the metrics-stream event names, so the same
@@ -680,7 +680,7 @@ def run_efficiency(art: RunArtifacts) -> dict:
     totals, waits, mfus, goodputs = [], [], [], []
     for r in per_step:
         spans = r["spans"]
-        totals.append(sum(spans.values()))
+        totals.append(tile_ms(spans))
         waits.append(spans.get("data_wait", 0.0))
         if r.get("mfu") is not None:
             mfus.append(float(r["mfu"]))

@@ -1,25 +1,44 @@
 """Per-step span recording: where a training step's wall time actually goes.
 
-The trainer's host loop has four distinct places a step can lose time, and
-a single throughput number cannot tell them apart ("Scalable Training of
-Language Models using JAX pjit and TPUv4", arXiv:2204.06514 — step-time
-*breakdowns* are how pod-scale runs stay debuggable):
+A single throughput number cannot tell apart the places a step can lose
+time ("Scalable Training of Language Models using JAX pjit and TPUv4",
+arXiv:2204.06514 — step-time *breakdowns* are how pod-scale runs stay
+debuggable). The trainer's loop therefore names everything the host does
+in one iteration. The spans tile it: contiguous, non-overlapping, in loop
+order, so their sum is the iteration's wall time (`STEP_SPANS`):
 
-- ``data_wait`` — blocked in the pipeline's ``next()``: host gather +
-  a prefetch that fell behind;
-- ``h2d``      — waiting for the batch's host→device transfer to land
-  (zero when prefetch overlapped it);
-- ``dispatch`` — the host's own cost of launching the compiled step;
-- ``device``   — fence-to-fence device execution: from dispatch return to
-  a device→host scalar fetch, the same fence discipline as
-  `ThroughputMeter.mark()` (`tpu_dp/utils/meter.py`).
+- ``data_wait``    — blocked in the pipeline's ``next()``, nothing else:
+  host gather, a prefetch that fell behind, or back-pressure from a device
+  that is behind (the ``loop.*`` counters tell which);
+- ``pre_dispatch`` — the ``on_window_start`` hooks and the guard's input;
+- ``h2d``          — (``full`` only) waiting for the batch's host→device
+  transfer to land (zero when prefetch overlapped it);
+- ``dispatch``     — the host's own cost of launching the compiled step;
+- ``device``       — (``full`` only) from dispatch return to a
+  device→host scalar fetch, the same fence discipline as
+  `ThroughputMeter.mark()` (`tpu_dp/utils/meter.py`);
+- ``telemetry``    — what the recorder, the efficiency meter and the
+  gauges cost themselves;
+- ``accumulate``   — unstacking the window's metrics, the on-device
+  running sums, the meter, the log line;
+- ``hooks``        — the ``on_step_end`` sweep.
+
+Two more are per epoch, each on one record (`EPOCH_SPANS`):
+``epoch_fence`` on the epoch's last step (from the end of its hooks to the
+return of the stats fetch that drains the device) and ``epoch_gap`` on the
+epoch's first step (from that return to the return of this epoch's first
+dispatch: device idle time measured on the host). ``epoch_gap`` overlays
+the first step's tiles up to ``dispatch``; `tile_ms` leaves it out.
 
 `SpanRecorder` is the low-overhead sink: a ring buffer (`deque(maxlen=)`)
 of per-step records, each ``{"step", "ts", "spans": {name: ms}}``, with
 percentile rollups computed only when asked (log boundaries, epoch ends,
-export) — the hot-loop cost is one dict construction and one append per
-step. Windowed dispatch (`train.steps_per_call > 1`) measures per *window*
-and attributes the totals evenly across the window's steps (documented in
+export). `SpanRecorder.begin` is the one span primitive: it ends the open
+span, adds its milliseconds to the current step's record and opens the
+next, under a ``jax.profiler.TraceAnnotation("tpu_dp.<name>", step=N)``
+that puts the span on the profiler's clock beside the device planes.
+Windowed dispatch (`train.steps_per_call > 1`) measures per *window* and
+attributes the totals evenly across the window's steps (documented in
 docs/OBSERVABILITY.md — per-step attribution inside one device-side scan
 is not observable from the host).
 """
@@ -28,10 +47,21 @@ from __future__ import annotations
 
 import time
 from collections import deque
-from typing import Iterable, Mapping
+from typing import Callable, Iterable, Mapping
 
-#: The trainer's canonical span set, in loop order.
-STEP_SPANS = ("data_wait", "h2d", "dispatch", "device")
+from tpu_dp.obs.counters import counters as _registry
+
+#: The spans that tile one iteration of the trainer's loop, in loop order.
+STEP_SPANS = ("data_wait", "pre_dispatch", "h2d", "dispatch", "device",
+              "telemetry", "accumulate", "hooks")
+#: Per-epoch spans, each on one record: the last step's, the first step's.
+EPOCH_SPANS = ("epoch_fence", "epoch_gap")
+
+
+def tile_ms(spans: Mapping[str, float]) -> float:
+    """A record's wall time: every span but ``epoch_gap``, which overlays
+    the first step's ``data_wait`` … ``dispatch``."""
+    return sum(v for k, v in spans.items() if k != "epoch_gap")
 
 
 def percentile(sorted_values: list[float], q: float) -> float:
@@ -60,12 +90,94 @@ class SpanRecorder:
     a "why is it slow *now*" investigation needs.
     """
 
-    def __init__(self, capacity: int = 4096):
+    def __init__(self, capacity: int = 4096,
+                 clock: Callable[[], float] = time.perf_counter,
+                 annotate: Callable | None = None):
         if capacity <= 0:
             raise ValueError(f"capacity must be positive, got {capacity}")
         self.capacity = int(capacity)
         self._records: deque[dict] = deque(maxlen=self.capacity)
         self.total_recorded = 0  # lifetime count, beyond the ring
+        self._clock = clock
+        if annotate is None:
+            from jax.profiler import TraceAnnotation as annotate  # lazy
+        self._annotate = annotate
+        self._open: tuple | None = None  # (name, start, annotation, rec)
+        self._step = 0
+        self._ts = 0.0
+        self._held: dict[str, float] = {}  # closed, not yet on a record
+        self._window: list[dict] | None = None  # the iteration's records
+
+    # ------------------------------------------------- the span primitive
+    def begin(self, name: str, step: int | None = None,
+              rec: dict | None = None) -> float:
+        """End the open span and open ``name`` on the same clock reading,
+        so consecutive spans tile the time between them. Returns it.
+
+        ``step`` starts a new iteration at that global step: spans ended
+        from here on are held until `open_window` makes the iteration's
+        records and add to those afterwards; what an abandoned iteration
+        held (the ``next()`` that found the epoch exhausted) is dropped.
+        ``rec`` names the one record this span is added to instead, after
+        the iteration is over; what was held goes with it (the time since
+        that record's last span ended).
+        """
+        now = self._clock()
+        self._close(now)
+        if step is not None:
+            self._step, self._ts = int(step), time.time()
+            self._held, self._window = {}, None
+        carried = 0.0
+        if rec is not None:
+            carried, self._held = sum(self._held.values()), {}
+        ann = self._annotate(
+            "tpu_dp." + name,
+            step=self._step if rec is None else rec["step"])
+        ann.__enter__()
+        self._open = (name, now - carried / 1e3, ann, rec)
+        return now
+
+    def end(self) -> float:
+        """End the open span; returns the clock reading it ended on."""
+        now = self._clock()
+        self._close(now)
+        return now
+
+    def abandon(self) -> None:
+        """Drop the open span unrecorded (a hook raised out of the loop)."""
+        if self._open is not None:
+            self._open[2].__exit__(None, None, None)
+            self._open = None
+
+    def _close(self, now: float) -> None:
+        if self._open is None:
+            return
+        name, start, ann, rec = self._open
+        self._open = None
+        ann.__exit__(None, None, None)
+        ms = (now - start) * 1e3
+        records = self._window if rec is None else [rec]
+        if records is None:
+            self._held[name] = self._held.get(name, 0.0) + ms
+            return
+        share = ms / len(records)
+        for j, r in enumerate(records):
+            r["spans"][name] = r["spans"].get(name, 0.0) + share
+            r["ts"] += j * share / 1e3  # keep a window's steps back to back
+
+    @property
+    def held(self) -> Mapping[str, float]:
+        """The iteration's ended spans (ms) that are on no record yet."""
+        return self._held
+
+    def open_window(self, n_steps: int, gen: int = 0) -> list[dict]:
+        """Make the iteration's records, ``n_steps`` from the step it began
+        at, from what is held (an even split, as `record_window`); spans
+        ended later add to them the same way."""
+        self._window = self.record_window(self._step, n_steps, self._held,
+                                          ts=self._ts, gen=gen)
+        self._held = {}
+        return self._window
 
     def record(self, step: int, spans: Mapping[str, float],
                ts: float | None = None, gen: int = 0) -> dict:
@@ -104,7 +216,7 @@ class SpanRecorder:
         n = max(1, int(n_steps))
         ts0 = time.time() if ts is None else float(ts)
         per = {k: float(v) / n for k, v in spans.items()}
-        stride_s = sum(per.values()) / 1e3
+        stride_s = tile_ms(per) / 1e3
         return [
             self.record(first_step + j, per, ts=ts0 + j * stride_s, gen=gen)
             for j in range(n)
@@ -146,3 +258,41 @@ class SpanRecorder:
 
     def reset(self) -> None:
         self._records.clear()
+
+
+class InflightSteps:
+    """Did the device wait for the host? Counted where the loop dispatches.
+
+    Holds the loss arrays of the windows dispatched and not yet seen
+    finished. Just before a dispatch, `before_dispatch` drops those whose
+    ``is_ready()`` is true (non-blocking: no fence) and publishes
+    ``loop.dispatches``, ``loop.inflight_sum`` and the gauge
+    ``loop.inflight_steps`` (steps enqueued and not finished), and
+    ``loop.dispatch_onto_idle`` (+1 when nothing was left: the previous
+    step had finished, the device had nothing queued). An epoch's first
+    dispatch follows a fence that drained the device: ``epoch_gap`` counts
+    it, and it is left out here.
+    """
+
+    def __init__(self, registry=_registry):
+        self._registry = registry
+        self._queue: deque[tuple] = deque()  # (loss array, steps)
+
+    def before_dispatch(self, first_of_epoch: bool = False) -> None:
+        q = self._queue
+        if first_of_epoch:
+            q.clear()
+            return
+        while q and q[0][0].is_ready():
+            q.popleft()
+        steps = sum(n for _, n in q)
+        reg = self._registry
+        reg.inc("loop.dispatches")
+        reg.inc("loop.inflight_sum", steps)
+        reg.gauge("loop.inflight_steps", steps)
+        # inc(0) creates the counter: "never onto an idle device" is a
+        # statement, absence is not.
+        reg.inc("loop.dispatch_onto_idle", 0.0 if q else 1.0)
+
+    def dispatched(self, loss, n_steps: int) -> None:
+        self._queue.append((loss, int(n_steps)))

@@ -357,9 +357,12 @@ def _make_step_body(model, optimizer, schedule, loss_impl, augment_fn,
         # unchanged.
         with jax.named_scope("tpu_dp.input"):
             images, labels = _maybe_normalize(batch["image"]), batch["label"]
-            if augment_fn is not None:
-                # Keyed by the global step: compiled into the program,
-                # deterministic, identical on every replica.
+        if augment_fn is not None:
+            # A phase of its own, beside and not inside the input's: an
+            # op's name carries one phase. Keyed by the global step:
+            # compiled into the program, deterministic, identical on
+            # every replica.
+            with jax.named_scope("tpu_dp.augment"):
                 images = augment_fn(state.step, images)
         with jax.named_scope("tpu_dp.fwd_bwd"):
             loss, grads, new_batch_stats, correct = _forward_backward(
@@ -424,10 +427,11 @@ def _make_accum_body(
         # for device-side trace attribution; schedule-neutral).
         with jax.named_scope("tpu_dp.input"):
             images, labels = _maybe_normalize(batch["image"]), batch["label"]
-            if augment_fn is not None:
-                # On-device augmentation keyed by the global step and the
-                # microbatch index: compiled into the step, deterministic,
-                # identical on every replica.
+        if augment_fn is not None:
+            # On-device augmentation keyed by the global step and the
+            # microbatch index: compiled into the step, deterministic,
+            # identical on every replica.
+            with jax.named_scope("tpu_dp.augment"):
                 images = jax.vmap(
                     lambda i, im: augment_fn(state.step * accum_steps + i, im)
                 )(jnp.arange(accum_steps), images)
@@ -785,7 +789,8 @@ def make_multi_step_resident(
         )
 
         def indexed_body(st, idx_step):
-            mb = jax.tree_util.tree_map(lambda x: x[idx_step], data)
+            with jax.named_scope("tpu_dp.gather"):
+                mb = jax.tree_util.tree_map(lambda x: x[idx_step], data)
             return step_body(st, mb)
 
         # length pins the window size: a mis-shaped idx errors at trace

@@ -49,6 +49,11 @@ from tpu_dp.utils import (
 )
 
 
+def _unstack(stacked, n):
+    """Lazy per-step views over a window's stacked metrics — no host sync."""
+    return tuple({k: v[j] for k, v in stacked.items()} for j in range(n))
+
+
 def _iso_ts(epoch_seconds: float) -> str:
     """ISO-8601 UTC stamp for metrics records (millisecond resolution)."""
     from datetime import datetime, timezone
@@ -107,7 +112,9 @@ def _elastic_fatal_errors() -> tuple[type[BaseException], ...]:
 
 
 class Trainer:
-    def __init__(self, cfg: Config, mesh=None):
+    def __init__(self, cfg: Config, mesh=None, datasets=None):
+        """``datasets`` hands the trainer its ``(train, test)`` data sets
+        (`ArrayDataset`s) in place of those ``cfg.data`` would load."""
         self.cfg = cfg
         # Elastic grow (docs/RESILIENCE.md "Grow"): before any classic
         # bootstrap, a starting process may instead JOIN a live run it
@@ -161,7 +168,10 @@ class Trainer:
         )
         log0("topology: %s", json.dumps(dist.describe(self.mesh)))
 
-        self._load_data(cfg)
+        if datasets is not None:
+            self.train_ds, self.test_ds = datasets
+        else:
+            self._load_data(cfg)
 
         # The dataset determines the number of classes; an explicit config
         # value must agree (a silently mis-sized head clamps labels inside
@@ -463,12 +473,15 @@ class Trainer:
             cfg.obs.run_dir or Path(cfg.train.ckpt_dir) / "obs"
         )
         self.spans = None
+        self._fence_t = None  # when the last epoch's fence returned
         self.heartbeat = None
         self.health = None
         if self.obs_mode != "off":
             from tpu_dp.obs import HealthMonitor, HeartbeatWriter, SpanRecorder
+            from tpu_dp.obs.spans import InflightSteps
 
             self.spans = SpanRecorder(capacity=cfg.obs.span_capacity)
+            self._inflight = InflightSteps()
             if cfg.obs.heartbeat_every_steps > 0 and self._join is None:
                 # Every rank appends to its own heartbeat file — per-rank
                 # host IO is the protocol, not a rank gate. A JOINER never
@@ -909,6 +922,12 @@ class Trainer:
                   ProfilerHook(self), CommProfilerHook(self),
                   BoundaryHook(self)]
         self._hooks = hooks
+
+    def add_hook(self, hook) -> None:
+        """Register an outside `StepHook` after the trainer's own: it runs
+        last in every sweep, and not at a boundary an earlier hook raises
+        out of (a rollback, a regroup, a preemption)."""
+        self._hooks.append(hook)
 
     @property
     def quarantine_path(self) -> Path:
@@ -1600,20 +1619,20 @@ class Trainer:
         else:
             items = pipe.windows(
                 self.steps_per_call, skip_steps=start_step)
-        def _unstack(stacked, n):
-            # Lazy per-step views over the window's stacked metrics — still
-            # no host sync outside log boundaries.
-            return tuple(
-                {k: v[j] for k, v in stacked.items()} for j in range(n)
-            )
-
-        # Telemetry (train.obs != off): span timestamps bracket the loop's
-        # phases — t0→t1 data_wait, t1→t2 h2d (full only: block on the
-        # placed batch), t2→t3 dispatch, t3→t4 device (full only: a scalar
-        # fetch, the `ThroughputMeter.mark()` fence discipline — the only
-        # obs mode that adds a host sync, which is why it is opt-in).
+        # Telemetry (train.obs != off): `spans.begin` ends one span and
+        # opens the next, so the spans tile the iteration (obs/spans.py);
+        # h2d (block on the placed batch) and device (a scalar fetch, the
+        # `ThroughputMeter.mark()` fence discipline) are full only — the
+        # only obs mode that adds a host sync, which is why it is opt-in.
         spans = self.spans
         obs_full = self.obs_mode == "full"
+        resident = self.resident_train is not None
+        # The moment the last epoch's fence returned, once: an epoch a
+        # hook raised out of leaves none behind for its re-entry.
+        fence_t, self._fence_t = self._fence_t, None
+        first_of_epoch, last_rec = True, None
+        if spans is not None:
+            spans.abandon()
         from tpu_dp.train.hooks import StepEvent
 
         for hook in self._hooks:
@@ -1621,16 +1640,13 @@ class Trainer:
         it = iter(items)
         while True:
             if spans is not None:
-                # ts_wall is the step's wall-clock START — stamped before
-                # next(), so the data_wait slice occupies its real place
-                # on the exported timeline instead of shifting every
-                # step's slices right by its own data_wait.
-                ts_wall = time.time()
-                t0 = time.perf_counter()
+                spans.begin("data_wait", step=self._host_step + 1)
             try:
                 n, item = next(it)
             except StopIteration:
                 break
+            if spans is not None:
+                spans.begin("pre_dispatch")
             for hook in self._hooks:
                 hook.on_window_start(self._host_step + 1, n)
             # The sentinel's replicated input (guard on only): armed loss
@@ -1641,101 +1657,31 @@ class Trainer:
                     self._guard_hook.guard_in(self._host_step + 1, n),
                 )
             if spans is not None:
-                t1 = time.perf_counter()
-                t2 = t1
                 if obs_full:
+                    spans.begin("h2d")
                     jax.block_until_ready(item)
-                    t2 = time.perf_counter()
-            if self.resident_train is not None:
+                self._inflight.before_dispatch(first_of_epoch)
+                spans.begin("dispatch")
+            if resident:
                 # Indices in, stacked metrics out — the dataset never
                 # re-crosses the host→device link.
-                self.state, stacked = self._resident_loop(n)(
+                self.state, out = self._resident_loop(n)(
                     self.state, self.resident_train, item, *guard_args
                 )
-                window = _unstack(stacked, n)
             elif n == 1:
-                self.state, m = self.train_step(self.state, item,
-                                                *guard_args)
-                window = (m,)
+                self.state, out = self.train_step(self.state, item,
+                                                  *guard_args)
             else:
                 # One dispatch, n optimizer steps (device-side scanned loop).
-                self.state, stacked = self.multi_step(self.state, item,
-                                                      *guard_args)
-                window = _unstack(stacked, n)
+                self.state, out = self.multi_step(self.state, item,
+                                                  *guard_args)
+            stacked = resident or n > 1
             if spans is not None:
-                t3 = time.perf_counter()
-                t4 = t3
-                if obs_full:
-                    float(window[-1]["loss"])  # scalar fetch: honest fence
-                    t4 = self.meter.mark()     # one fence, two consumers
-                    _obs_counters.gauge(
-                        "throughput.images_per_sec",
-                        round(self.meter.images_per_sec, 1),
-                    )
-                    from tpu_dp.obs import update_device_memory_gauges
-
-                    update_device_memory_gauges()
-                # Basic mode OMITS h2d/device rather than recording 0.0:
-                # absence means "not measured" — a fake zero would render
-                # as "device took 0 ms" in rollups and the Perfetto trace
-                # (same principle as the absent memory gauges).
-                window_spans = {
-                    "data_wait": (t1 - t0) * 1e3,
-                    "dispatch": (t3 - t2) * 1e3,
-                }
-                if obs_full:
-                    window_spans["h2d"] = (t2 - t1) * 1e3
-                    window_spans["device"] = (t4 - t3) * 1e3
-                new_recs = spans.record_window(
-                    self._host_step + 1, n, window_spans, ts=ts_wall,
-                    gen=self._rollback_gen,
-                )
-                eff = None
-                if self._eff is not None:
-                    # Live efficiency gauges, per dispatched window: MFU
-                    # from the cost registry (absent when the program's
-                    # cost or the chip's peak is unknown — never a wrong
-                    # number), goodput = 1 − data_wait/window. Window wall
-                    # time is boundary-to-boundary: at obs=full it ends on
-                    # the device fence (honest device time); at basic it
-                    # is a dispatch rate (documented in OBSERVABILITY.md).
-                    if self.resident_train is not None:
-                        tag = f"resident_loop[w{n}]"
-                    else:
-                        tag = "train_step" if n == 1 else "multi_step"
-                    wall_ms = ((t4 if obs_full else t3) - t0) * 1e3
-                    eff = self._eff.observe(
-                        tag, n, wall_ms, window_spans["data_wait"]
-                    )
-                    self._last_efficiency = eff
-                    _obs_counters.gauge("obs.step_time_ms",
-                                        eff["step_time_ms"])
-                    _obs_counters.gauge("obs.goodput", eff["goodput"])
-                    if "mfu" in eff:
-                        _obs_counters.gauge("obs.mfu", eff["mfu"])
-                if obs_full:
-                    # Per-step metrics.jsonl records (schema 3): spans,
-                    # the window's efficiency gauges, and a counter
-                    # snapshot — one line per optimizer step. The int8
-                    # codec's overflow/clip counts publish first (riding
-                    # this block's existing fence) so the same window's
-                    # records carry them.
-                    self._publish_quant_counters(window,
-                                                 self._host_step + 1)
-                    snap = _obs_counters.snapshot()
-                    for r in new_recs:
-                        rec = {
-                            "step": r["step"],
-                            "ts": _iso_ts(r["ts"]),
-                            "spans": {k: round(v, 3)
-                                      for k, v in r["spans"].items()},
-                            "counters": snap,
-                        }
-                        if eff is not None:
-                            rec["goodput"] = eff["goodput"]
-                            if "mfu" in eff:
-                                rec["mfu"] = eff["mfu"]
-                        self._log_metrics(rec)
+                last_rec = self._window_telemetry(
+                    n, out, stacked, fence_t if first_of_epoch else None)
+                first_of_epoch = False
+                spans.begin("accumulate")
+            window = _unstack(out, n) if stacked else (out,)
             for m in window:
                 i += 1
                 # On-device async adds; no host sync inside the loop.
@@ -1786,9 +1732,13 @@ class Trainer:
             done += n
             self._host_step += n
             self._epoch_done = done  # regroup attribution (fit's handler)
+            if spans is not None:
+                spans.begin("hooks")
             ev = StepEvent(epoch=epoch, done=done, n=n, window=window)
             for hook in self._hooks:
                 hook.on_step_end(ev)
+        if last_rec is not None:
+            spans.begin("epoch_fence", rec=last_rec)
         stats = {
             "loss": float(ep_loss) / max(1, ep_steps) if ep_steps else 0.0,
             "accuracy": float(ep_correct) / ep_count if ep_count else 0.0,
@@ -1799,7 +1749,89 @@ class Trainer:
             # their own discontinuity instead of faking full-epoch coverage.
             stats["resumed_at_step"] = base + start_step
         self.meter.mark()  # fence: epoch stats fetched, device drained
+        if last_rec is not None:
+            self._fence_t = spans.end()
         return stats
+
+    def _window_telemetry(self, n: int, out, stacked: bool,
+                          fence_t: float | None) -> dict:
+        """The ``device`` (full only) and ``telemetry`` spans of one
+        dispatched window: the fence, the window's records, the efficiency
+        gauges and, at full, the per-step `metrics.jsonl` lines. ``out`` is
+        the step's metrics (``stacked`` over the window's steps);
+        ``fence_t`` is when the last epoch's fence returned, given with an
+        epoch's first window. Returns the window's last record."""
+        spans = self.spans
+        obs_full = self.obs_mode == "full"
+        dispatched = spans.begin("device" if obs_full else "telemetry")
+        self._inflight.dispatched(out["loss"], n)
+        if obs_full:
+            # scalar fetch: honest fence
+            float(out["loss"][-1] if stacked else out["loss"])
+            self.meter.mark()  # the same fence feeds the meter
+            spans.begin("telemetry")
+            _obs_counters.gauge(
+                "throughput.images_per_sec",
+                round(self.meter.images_per_sec, 1),
+            )
+            from tpu_dp.obs import update_device_memory_gauges
+
+            update_device_memory_gauges()
+        # Basic mode OMITS h2d/device rather than recording 0.0: absence
+        # means "not measured" — a fake zero would render as "device took
+        # 0 ms" in rollups and the Perfetto trace (same principle as the
+        # absent memory gauges).
+        held = spans.held
+        wall_ms, data_wait_ms = sum(held.values()), held["data_wait"]
+        new_recs = spans.open_window(n, gen=self._rollback_gen)
+        if fence_t is not None:
+            # On the first step's record alone, never spread over a
+            # window: the device sat drained for all of it.
+            new_recs[0]["spans"]["epoch_gap"] = (dispatched - fence_t) * 1e3
+        eff = None
+        if self._eff is not None:
+            # Live efficiency gauges, per dispatched window: MFU from the
+            # cost registry (absent when the program's cost or the chip's
+            # peak is unknown — never a wrong number), goodput = 1 −
+            # data_wait/window. Window wall time runs from the start of
+            # data_wait: at obs=full it ends on the device fence (honest
+            # device time); at basic on the dispatch's return, a dispatch
+            # rate (documented in OBSERVABILITY.md).
+            if self.resident_train is not None:
+                tag = f"resident_loop[w{n}]"
+            else:
+                tag = "train_step" if n == 1 else "multi_step"
+            eff = self._eff.observe(tag, n, wall_ms, data_wait_ms)
+            self._last_efficiency = eff
+            _obs_counters.gauge("obs.step_time_ms", eff["step_time_ms"])
+            _obs_counters.gauge("obs.goodput", eff["goodput"])
+            if "mfu" in eff:
+                _obs_counters.gauge("obs.mfu", eff["mfu"])
+        if obs_full:
+            # Per-step metrics.jsonl records (schema 3): the spans ended
+            # so far, the window's efficiency gauges, and a counter
+            # snapshot — one line per optimizer step. The int8 codec's
+            # overflow/clip counts publish first (riding this block's
+            # existing fence) so the same window's records carry them.
+            if self._quant_enabled:
+                self._publish_quant_counters(
+                    _unstack(out, n) if stacked else (out,),
+                    self._host_step + 1)
+            snap = _obs_counters.snapshot()
+            for r in new_recs:
+                rec = {
+                    "step": r["step"],
+                    "ts": _iso_ts(r["ts"]),
+                    "spans": {k: round(v, 3)
+                              for k, v in r["spans"].items()},
+                    "counters": snap,
+                }
+                if eff is not None:
+                    rec["goodput"] = eff["goodput"]
+                    if "mfu" in eff:
+                        rec["mfu"] = eff["mfu"]
+                self._log_metrics(rec)
+        return new_recs[-1]
 
     def _snapshot_meta(self, epoch: int, steps_done: int) -> dict[str, Any]:
         """Snapshot metadata: the mid-epoch resume position + provenance.
