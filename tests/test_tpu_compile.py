@@ -5,7 +5,10 @@ misaligned slice, an over-budget VMEM tile or an op Mosaic refuses; the
 TPU's compiler can, and it is installed here. Each case lowers one kernel
 at a shape `chip_smoke.py` runs on the chip, compiles it for one device of
 a described ``v5e:2x2`` topology, and asserts the compiled program holds a
-``tpu_custom_call``. Nothing runs, so nothing here is a result or a time.
+``tpu_custom_call``. The last test compiles the step's random crop
+(`tpu_dp.data.augment`) the same way and asserts the opposite kind of
+thing: that the TPU's compiler makes no loop of it, which only that
+compiler can say. Nothing runs, so nothing here is a result or a time.
 
 One file and in-process on purpose: only one process at a time may load
 the TPU's library, so the topology is described inside a module-scoped
@@ -22,6 +25,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from tpu_dp.data.augment import make_augment_fn
 from tpu_dp.ops import _partition, conv_block, xent
 
 
@@ -124,3 +128,27 @@ def test_conv_block_compiles_for_v5e(one_chip, compiled_kernels, fn, shapes):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
         + mem.output_size_in_bytes < 2 * 2**30
+
+
+def _crop_accumulated(step, images):
+    aug, k = make_augment_fn(1), images.shape[0]
+    return jax.vmap(lambda i, im: aug(step * k + i, im))(jnp.arange(k),
+                                                         images)
+
+
+@pytest.mark.parametrize("fn,shape", [
+    (make_augment_fn(1), (4096, 32, 32, 3)),
+    (_crop_accumulated, (2, 2048, 32, 32, 3)),
+], ids=["batch", "microbatches"])
+def test_random_crop_is_no_loop_for_v5e(one_chip, compiled_kernels, fn,
+                                        shape):
+    """A per-image `dynamic_slice` is a `gather` that the v5e compiler runs
+    as a `while` of one trip an image (74 ms of a 204 ms step, PERF.md §6,
+    PR 26). The crop as shipped selects among static shifts of the whole
+    batch: no loop, no gather, no dynamic slice in the TPU's program."""
+    args = [jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert " select(" in text
+    for kind in ("while", "gather", "dynamic-slice", "dynamic-update-slice"):
+        assert f" {kind}(" not in text, kind

@@ -10,6 +10,21 @@ step* — keyed by the global step counter, so it is deterministic, replayable
 from a checkpoint, and bitwise-identical on every replica (each device
 augments only its own shard; the vmapped per-example keys are derived from
 the global step, not from device identity).
+
+How the crop moves its pixels is decided by the TPU, not by taste. The
+plain form — `vmap` of a `lax.dynamic_slice` whose start is the image's own
+offset — is one HLO `gather` whose slice is a whole image, and the TPU
+compiler runs such a gather as a `while` of one trip an image (slice one
+image, copy it, `dynamic-update-slice` it into the result): 4,096 trips
+and 74 ms of a 204 ms ResNet-18 step on the v5e (PERF.md §6, PR 25/26). So
+`random_crop_flip` holds no per-image slice at all: every one of the
+`2*pad + 1` row shifts and column shifts is a *static* slice of the whole
+batch, and an image's drawn offset only selects among them. That is
+elementwise, fuses into one pass, splits along the batch with no
+collective, and returns the same array bit for bit. Do not "simplify" it
+back to a `dynamic_slice` or a `gather`: `tests/test_augment.py` holds the
+old form for comparison and `tests/test_step_scopes.py` refuses a loop or
+a gather under `tpu_dp.augment`.
 """
 
 from __future__ import annotations
@@ -35,13 +50,18 @@ def random_crop_flip(
     offsets = jax.random.randint(k_off, (n, 2), 0, 2 * pad + 1)
     flips = jax.random.bernoulli(k_flip, 0.5, (n,))
 
-    def one(img, off, flip):
-        crop = jax.lax.dynamic_slice(
-            img, (off[0], off[1], 0), (h, w, img.shape[-1])
-        )
-        return jnp.where(flip, crop[:, ::-1, :], crop)
-
-    return jax.vmap(one)(padded, offsets, flips)
+    # Rows, then columns: the shift by `d` is a static slice of the whole
+    # batch, kept for the images whose drawn offset is `d` (module
+    # docstring: a per-image `dynamic_slice` is a loop on the TPU).
+    off_y = offsets[:, 0].reshape(n, 1, 1, 1)
+    off_x = offsets[:, 1].reshape(n, 1, 1, 1)
+    rows = padded[:, 0:h]
+    for d in range(1, 2 * pad + 1):
+        rows = jnp.where(off_y == d, padded[:, d:d + h], rows)
+    out = rows[:, :, 0:w]
+    for d in range(1, 2 * pad + 1):
+        out = jnp.where(off_x == d, rows[:, :, d:d + w], out)
+    return jnp.where(flips.reshape(n, 1, 1, 1), out[:, :, ::-1, :], out)
 
 
 def make_augment_fn(seed: int, fill: float = -1.0):
