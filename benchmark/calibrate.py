@@ -2,17 +2,18 @@
 """Read a cell's control and faults on the chip, at the cell's own size.
 
     python3 benchmark/calibrate.py --workload <cell> --seeds 11 12 13 \
-        [--variants program float8 float8_operands bfloat16 half_batch no_exchange]
+        [--variants program <a precision or a fault of the family's> ...]
 
 Not part of a benchmark run. For each seed it follows the first steps with
-the plain reference (float32, highest), then with the reference put in the
-program's place (or, for ``program``, with the program itself, driven
-through its first epoch as a run's set-up drives it: a dozen seeds' lower
-readings in one process) and either computed in a lower precision (``float8``, the
-control for a configuration that states bfloat16; ``bfloat16``, a second
-witness of what the stated precision costs) or with a fault planted
-(``half_batch``, ``no_exchange``), and prints the numbers `compare.py`
-would compare, one JSON line a seed and variant. The limits in
+the configuration's plain reference as the family computes it, then with
+the reference put in the program's place (or, for ``program``, with the
+program itself, driven through its first epoch as a run's set-up drives
+it: a dozen seeds' lower readings in one process) and either computed in
+one of the lower precisions the family's ``variants`` lists (the first is
+the control, the nearest below the stated one; the others are further
+witnesses) or with one of its faults planted, and prints the numbers
+`compare.py` would compare, one JSON line a seed and variant. Without
+``--variants``: the control and the faults the cell can have. The limits in
 ``cells/<cell>.json`` were set between the program's readings (every run of
 `run.py` prints them) and these.
 """
@@ -29,6 +30,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent))
 
 import run  # noqa: E402
 from compare import compare, observed  # noqa: E402
+from loop_hook import FOLLOWED_STEPS  # noqa: E402
 
 NO_LIMITS = {"loss": math.inf, "grad1": math.inf, "delta": math.inf,
              "grad1_median": math.inf}
@@ -51,8 +53,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
-    ap.add_argument("--variants", nargs="+",
-                    default=["float8", "bfloat16", "half_batch"])
+    ap.add_argument("--variants", nargs="+", default=None)
     ap.add_argument("--batch-per-chip", type=int, default=None,
                     help="cut the batch (CPU tests only)")
     ap.add_argument("--steps-per-epoch", type=int, default=None)
@@ -65,29 +66,34 @@ def main(argv=None) -> int:
 
     place_compile_cache()
     cell = run.load_cell(args.workload)
-    chips = int(cell["chips"])
-    traffic = cell["traffic_file"]
-    batch = (args.batch_per_chip or int(traffic["batch_per_chip"])) * chips
-    train_size = int(traffic["train_size"])
-    if args.steps_per_epoch:
-        train_size = batch * args.steps_per_epoch
-    spe = train_size // batch
-    augmented = "augment" in cell["config_file"]["input"]
+    family = cell["family"]
     rehearsal = None
     if args.batch_per_chip or args.steps_per_epoch:
-        rehearsal = {"batch_per_chip": batch // chips,
-                     "train_size": train_size}
+        batch = args.batch_per_chip or int(
+            cell["traffic_file"]["batch_per_chip"])
+        rehearsal = {"batch_per_chip": batch}
+        if args.steps_per_epoch:
+            rehearsal["train_size"] = (batch * int(cell["chips"])
+                                       * args.steps_per_epoch)
+    known = family.variants(run.job_of(cell, args.seeds[0], rehearsal))
+    variants = args.variants or [known["precisions"][0],
+                                 *known["faults"]]
     for seed in args.seeds:
-        read = lambda **kw: run.reference_readings(  # noqa: E731
-            cell, seed, chips, batch, train_size, spe, augmented, **kw)
-        ref = read()
-        for variant in args.variants:
+        job = run.job_of(cell, seed, rehearsal)
+        ref = family.reference_readings(job, FOLLOWED_STEPS)
+        for variant in variants:
             if variant == "program":
                 got = program_readings(cell, seed, rehearsal)
-            elif variant in ("float8", "float8_operands", "bfloat16"):
-                got = read(precision=variant)
+            elif variant in known["precisions"]:
+                got = family.reference_readings(job, FOLLOWED_STEPS,
+                                                precision=variant)
+            elif variant in known["faults"]:
+                got = family.reference_readings(job, FOLLOWED_STEPS,
+                                                fault=variant)
             else:
-                got = read(fault=variant)
+                raise SystemExit(
+                    f"unknown variant {variant!r}: the family knows "
+                    f"{list(known['precisions']) + list(known['faults'])}")
             _, rows = compare(got, ref, NO_LIMITS)
             print(json.dumps({
                 "workload": args.workload, "seed": seed, "variant": variant,

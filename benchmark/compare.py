@@ -32,6 +32,19 @@ import math
 import statistics
 
 
+def leaf_norms(tree) -> dict:
+    """L2 norm of every leaf of a tree of device arrays, keyed by its path
+    joined with '/': the form in which the program's hook and every
+    family's reference hand their gradients and changes to `compare`."""
+    import jax
+    import jax.numpy as jnp
+
+    flat = jax.tree_util.tree_flatten_with_path(tree)[0]
+    return {"/".join(str(getattr(k, "key", k)) for k in path):
+            jnp.sqrt(jnp.sum(jnp.square(leaf.astype(jnp.float32))))
+            for path, leaf in flat}
+
+
 def leaf_gaps(prog: dict, ref: dict) -> dict[str, float]:
     """Every leaf's gap; infinite where the trees differ, the reference
     norms are all zero or a norm is not finite."""

@@ -7,9 +7,9 @@ Appended last to the trainer's hook list, it
   stamps the completion time, so the loop itself is never fenced;
 - in the first epoch, which is the warm-up, reads what the comparison needs
   from the timed object's own state: the parameters before step 1, the
-  optimizer's state after step 1 (the first gradient as the optimizer got
-  it is ``buf - wd * p0``) and the parameters after step 3, each reduced to
-  norms by leaf on the device;
+  optimizer's state after step 1 (from which the configuration's family
+  reads back the first gradient as the optimizer got it) and the parameters
+  after step 3, each reduced to norms by leaf on the device;
 - in a traced run, starts and stops `jax.profiler` around a few steady
   steps inside one epoch and writes host annotations (``bench.data_wait``,
   ``bench.dispatch``, ``bench.epoch_boundary``) on the trace's clock.
@@ -25,7 +25,7 @@ import time
 import jax
 import jax.numpy as jnp
 
-from reference import leaf_norms as _leaf_norms
+from compare import leaf_norms as _leaf_norms
 from tpu_dp.train.hooks import StepEvent, StepHook
 
 FOLLOWED_STEPS = 3
@@ -37,22 +37,20 @@ def _copy_tree(tree):
 
 
 @jax.jit
-def _first_grad_norms(buf, p0, wd):
-    return _leaf_norms(jax.tree_util.tree_map(
-        lambda b, p: b - wd * p, buf, p0))
-
-
-@jax.jit
 def _delta_norms(params, p0):
     return _leaf_norms(jax.tree_util.tree_map(
         lambda a, b: a - b, params, p0))
 
 
 class LoopHook(StepHook):
-    def __init__(self, trainer, weight_decay: float, steps_per_epoch: int,
+    def __init__(self, trainer, first_gradient, steps_per_epoch: int,
                  trace_dir: str | None = None):
+        """``first_gradient(opt_state, params0)`` is the family's pure
+        function from the optimizer's state after step 1 and the parameters
+        before it to the first gradient, a tree of the parameters' shape."""
         super().__init__(trainer)
-        self.wd = jnp.float32(weight_decay)
+        self._first_grad_norms = jax.jit(
+            lambda opt_state, p0: _leaf_norms(first_gradient(opt_state, p0)))
         self.spe = int(steps_per_epoch)
         self.done: list[tuple[float, float]] = []  # (completion time, loss)
         self._q: queue.Queue = queue.Queue()
@@ -134,8 +132,8 @@ class LoopHook(StepHook):
         self._epoch_step += ev.n
         if self._p0 is not None:
             if self.steps_seen == 1:
-                self._grad1 = _first_grad_norms(
-                    self.tr.state.opt_state, self._p0, self.wd)
+                self._grad1 = self._first_grad_norms(
+                    self.tr.state.opt_state, self._p0)
             elif self.steps_seen == FOLLOWED_STEPS:
                 self._delta = _delta_norms(self.tr.state.params, self._p0)
                 self._p0 = None

@@ -45,6 +45,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from compare import leaf_norms
+
 PRECISIONS = ("float32", "bfloat16", "float8", "float8_operands")
 
 
@@ -298,14 +300,6 @@ def make_step(model: dict, opt: dict, steps_per_epoch: int,
         return params, buf, loss, leaf_norms(grads)
 
     return step
-
-
-def leaf_norms(tree) -> dict:
-    """L2 norm of every leaf, keyed by its path joined with '/'."""
-    flat = jax.tree_util.tree_flatten_with_path(tree)[0]
-    return {"/".join(str(getattr(k, "key", k)) for k in path):
-            jnp.sqrt(jnp.sum(jnp.square(leaf.astype(jnp.float32))))
-            for path, leaf in flat}
 
 
 def follow(model: dict, opt: dict, seed: int, program_seed: int,

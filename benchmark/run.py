@@ -5,13 +5,16 @@
 
 One process, which holds the cell's chips. It reads the cell from
 ``BENCHMARK.json`` (configuration, traffic, chips), the configuration's and
-the traffic's files by their names, builds the program's `Config` from the
-argv those files hold, constructs the program's `Trainer` on data made from
-``--seed`` and drives `Trainer.train_epoch`, whole epochs one after another
-as `Trainer.fit` does between its checkpoints: a warm-up epoch outside the
-window (it compiles or loads the step, and its first steps are what
-``correct`` compares), a fence, epochs until the clock has passed
-``--seconds``, a fence. The window is what actually ran, overshoot included.
+the traffic's files by their names and the configuration's family
+(``families/<family>.py``, which knows what the data, the weights, the
+reference and the required work of such a model are), builds the program's
+`Config` from the argv those files hold, constructs the program's `Trainer`
+on the family's data from ``--seed`` and drives `Trainer.train_epoch`,
+whole epochs one after another as `Trainer.fit` does between its
+checkpoints: a warm-up epoch outside the window (it compiles or loads the
+step, and its first steps are what ``correct`` compares), a fence, epochs
+until the clock has passed ``--seconds``, a fence. The window is what
+actually ran, overshoot included.
 
 ``--trace 0`` prints the cell's end-to-end metrics, ``--trace 1`` its
 per-layer metrics (the same loop with ``train.obs=basic`` and a profiler
@@ -26,6 +29,8 @@ import time
 T_START = time.perf_counter()  # set-up counts from here
 
 import argparse  # noqa: E402
+import dataclasses  # noqa: E402
+import functools  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -64,6 +69,7 @@ def load_cell(name: str) -> dict:
     cell = dict(cells[name])
     config = {c["name"]: c for c in bench["configs"]}[cell["config"]]
     cell["config_file"] = json.loads((REPO / config["file"]).read_text())
+    cell["family"] = load_family(cell["config_file"], config["file"])
     cell["traffic_file"] = json.loads(
         (HERE / "traffic" / f"{cell['traffic']}.json").read_text())
     cell["limits"] = json.loads(
@@ -77,15 +83,48 @@ def load_cell(name: str) -> dict:
     return cell
 
 
+def load_module(name: str, path: Path):
+    """A module of the benchmark's by its file, under no fixed name."""
+    mod_spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(module)
+    return module
+
+
+# What a family's file answers (`README.md`, "What a family file answers").
+FAMILY_API = ("datasets", "init_params", "first_gradient", "variants",
+              "reference_readings", "train_flops_per_item",
+              "least_step_seconds", "items_per_row")
+
+
+def load_family(config: dict, where: str):
+    """The module ``families/<family>.py`` that the configuration's file
+    names under ``family``."""
+    name = config.get("family")
+    if not name:
+        raise Refused(f"{where} has no key 'family': it names the file "
+                      f"under benchmark/families/ that knows such a model")
+    path = HERE / "families" / f"{name}.py"
+    if not path.is_file():
+        raise Refused(f"{where} names the family {name!r}, and there is "
+                      f"no file {path}")
+    return check_family(load_module(f"bench_family_{name}", path), path)
+
+
+def check_family(module, path):
+    missing = [f for f in FAMILY_API if not callable(getattr(module, f, None))]
+    if missing:
+        raise Refused(f"{path} does not answer {missing}")
+    return module
+
+
 def read_metric(name: str, ctx: dict):
     """A per-layer metric by its own file and reader; None where the reader
     finds nothing to read."""
     spec = json.loads((HERE / "metrics" / f"{name}.json").read_text())
-    path = HERE / "metrics" / "readers" / f"{spec['reader']}.py"
-    mod_spec = importlib.util.spec_from_file_location(
-        f"bench_reader_{spec['reader']}", path)
-    module = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(module)
+    module = load_module(
+        f"bench_reader_{spec['reader']}",
+        HERE / "metrics" / "readers" / f"{spec['reader']}.py")
     value = module.read(ctx, **spec.get("args", {}))
     return None if value is None else float(value)
 
@@ -104,8 +143,38 @@ def percentile(values, q: float) -> float:
 # key is a constant of the program), so a run with a new `train.seed`
 # compiles for a minute. The program's own seed therefore stays fixed, which
 # fixes the order of the rows and the crops; what `--seed` draws is the data
-# and the weights, both made here.
+# and the weights, both made by the configuration's family.
 PROGRAM_SEED = 0
+
+
+@dataclasses.dataclass(frozen=True)
+class Job:
+    """What a family's functions are told of a run: the configuration's and
+    the traffic's files, the seeds, and the sizes as this run has them (a
+    rehearsal cuts the batch and the data set)."""
+
+    config: dict
+    traffic: dict
+    seed: int
+    chips: int
+    batch_per_chip: int
+    train_size: int
+    program_seed: int = PROGRAM_SEED
+
+    @property
+    def global_batch(self) -> int:
+        return self.batch_per_chip * self.chips
+
+    @property
+    def steps_per_epoch(self) -> int:
+        return self.train_size // self.global_batch
+
+
+def job_of(cell: dict, seed: int, rehearsal: dict | None = None) -> Job:
+    sizes = {**cell["traffic_file"], **(rehearsal or {})}
+    return Job(cell["config_file"], cell["traffic_file"], int(seed),
+               int(cell["chips"]), int(sizes["batch_per_chip"]),
+               int(sizes["train_size"]))
 
 
 class Session:
@@ -121,24 +190,11 @@ class Session:
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
-        import reference
-        import work
-        from datagen import make_dataset
+        from work import load_peaks
 
-        self.cell, self.seed = cell, int(seed)
-        self.chips = int(cell["chips"])
-        self.config, traffic = cell["config_file"], cell["traffic_file"]
-        self.model = model = self.config["model"]
-        self.batch_per_chip = int(traffic["batch_per_chip"])
-        self.train_size = int(traffic["train_size"])
-        if rehearsal:
-            self.batch_per_chip = int(
-                rehearsal.get("batch_per_chip", self.batch_per_chip))
-            self.train_size = int(
-                rehearsal.get("train_size", self.train_size))
-        self.global_batch = self.batch_per_chip * self.chips
-        self.steps_per_epoch = self.train_size // self.global_batch
-        if self.steps_per_epoch < 3:
+        self.family = family = cell["family"]
+        self.job = job = job_of(cell, seed, rehearsal)
+        if job.steps_per_epoch < 3:
             raise Refused("an epoch has fewer steps than are followed")
 
         self.devices = devices = jax.devices()
@@ -146,17 +202,15 @@ class Session:
         self.kind = devices[0].device_kind
         if not rehearsal and self.platform != "tpu":
             raise Refused(f"needs a TPU, JAX found {self.platform!r}")
-        if len(devices) < self.chips:
-            raise Refused(f"needs {self.chips} chips, JAX found "
+        if len(devices) < job.chips:
+            raise Refused(f"needs {job.chips} chips, JAX found "
                           f"{len(devices)}")
         # A rehearsal does the readers' arithmetic with the v5e's peaks.
-        self.peaks = work.load_peaks("TPU v5 lite" if rehearsal
-                                     else self.kind)
+        self.peaks = load_peaks("TPU v5 lite" if rehearsal else self.kind)
         log(f"devices: {len(devices)} x {self.kind} ({self.platform}), "
-            f"using {self.chips}")
+            f"using {job.chips}")
 
         from tpu_dp.config import parse_cli
-        from tpu_dp.data.cifar import ArrayDataset
         from tpu_dp.parallel.sharding import replicated_sharding
         from tpu_dp.train.trainer import Trainer
         from tpu_dp.utils import place_compile_cache
@@ -165,43 +219,27 @@ class Session:
 
         self.cache_dir = place_compile_cache()
         self.workdir = tempfile.mkdtemp(prefix="tpu_dp_bench_")
-        argv = list(self.config["argv"]) + list(traffic.get("argv", [])) + [
-            f"--data.batch_size={self.global_batch}",
+        argv = list(job.config["argv"]) + list(job.traffic.get("argv", [])) + [
+            f"--data.batch_size={job.global_batch}",
             f"--train.seed={PROGRAM_SEED}",
-            f"--parallel.num_devices={self.chips}",
+            f"--parallel.num_devices={job.chips}",
             f"--train.ckpt_dir={self.workdir}/ckpt",
             "--resilience.handle_signals=false",
         ]
         if trace:
             argv.append("--train.obs=basic")
         self.cfg = cfg = parse_cli(argv)
-        self.augmented = bool(cfg.data.augment)
 
-        classes = int(model["num_classes"])
-        images, labels = make_dataset(
-            seed, self.train_size, classes, int(model["image_size"]),
-            int(model["image_channels"]))
-        name = cfg.data.dataset
-        train_ds = ArrayDataset(images, labels, name, classes, synthetic=True)
-        test_ds = ArrayDataset(images[:self.global_batch],
-                               labels[:self.global_batch], name, classes,
-                               synthetic=True)
-        log(f"data: {self.train_size} items made from seed {seed}")
-
-        class BenchTrainer(Trainer):
-            """The program's trainer on the benchmark's inputs."""
-
-            def _load_data(self, cfg):
-                self.train_ds, self.test_ds = train_ds, test_ds
-
-        self.trainer = trainer = BenchTrainer(cfg)
-        if len(trainer.train_pipe) != self.steps_per_epoch:
+        datasets = family.datasets(job)
+        log(f"data: {job.train_size} rows made from seed {seed}")
+        self.trainer = trainer = Trainer(cfg, datasets=datasets)
+        if len(trainer.train_pipe) != job.steps_per_epoch:
             raise RuntimeError(
                 f"the trainer plans {len(trainer.train_pipe)} steps an "
-                f"epoch, the traffic file gives {self.steps_per_epoch}")
+                f"epoch, the traffic file gives {job.steps_per_epoch}")
         # Weights from the seed, made on the device in one call, in the
         # place of those the program drew from its own seed.
-        params = jax.device_put(reference.init_params(model, seed),
+        params = jax.device_put(family.init_params(job),
                                 replicated_sharding(trainer.mesh))
         ours = jax.tree_util.tree_map(
             lambda x: (x.shape, x.dtype), params)
@@ -213,10 +251,11 @@ class Session:
         trainer.state = trainer.state.replace(params=params)
         self.trace_dir = os.path.join(self.workdir, "trace") if trace else None
         self.hook = LoopHook(
-            trainer, float(self.config["optimizer"]["weight_decay"]),
-            self.steps_per_epoch, self.trace_dir)
-        # The program offers no public seam for an outside hook.
-        trainer._hooks.append(self.hook)
+            trainer,
+            functools.partial(family.first_gradient,
+                              optimizer=job.config["optimizer"]),
+            job.steps_per_epoch, self.trace_dir)
+        trainer.add_hook(self.hook)
         log("trainer built")
 
     def warm_up(self) -> None:
@@ -234,12 +273,51 @@ class Session:
         trainer, self.trainer, self.hook = self.trainer, None, None
         del trainer.state
         trainer._resident_train = None
-        trainer._hooks.clear()
         del trainer
         gc.collect()
 
     def close(self) -> None:
         shutil.rmtree(self.workdir, ignore_errors=True)
+
+
+def window_metrics(cell: dict, family, job: Job, peaks: dict, window: dict,
+                   trace: bool, reduced=None, spans=(),
+                   memory_peak: int = 0) -> tuple[int, dict]:
+    """``(items a step, metrics)`` of a window of ``steps`` steps that took
+    ``seconds``, with ``gaps_ms`` between completions and ``setup_s``
+    before it: the cell's end-to-end metrics, or (``trace``) its per-layer
+    metrics as their readers find them in the reduced trace and the spans.
+    """
+    # What the window counts: a batch row holds as many items as the
+    # family says (an image is one; a row of tokens its counted tokens).
+    items_per_step = job.global_batch * int(family.items_per_row(job))
+    metrics = {}
+    if not trace:
+        values = {
+            "throughput_per_chip": (window["steps"] * items_per_step
+                                    / window["seconds"] / job.chips),
+            "step_ms_p95": percentile(window["gaps_ms"], 95.0),
+            "setup_s": window["setup_s"],
+        }
+        for m in cell["end_to_end"]:
+            metrics[m["name"]] = {"value": values[m["name"]],
+                                  "unit": m["unit"]}
+        return items_per_step, metrics
+    ctx = {
+        "trace": reduced, "spans": spans, "chips": job.chips,
+        "global_batch": job.global_batch,
+        "batch_per_chip": job.batch_per_chip,
+        "items_per_step": items_per_step,
+        "flops_per_item": family.train_flops_per_item(job),
+        "least_step_s": family.least_step_seconds(job, peaks)["seconds"],
+        "peaks": peaks, "memory_peak_bytes": memory_peak,
+        "config": job.config,
+    }
+    for m in cell["per_layer"]:
+        value = read_metric(m["name"], ctx)
+        if value is not None:
+            metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    return items_per_step, metrics
 
 
 def run(cell: dict, seed: int, seconds: float, trace: bool,
@@ -251,13 +329,14 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
     """
     import jax
 
-    import work
+    from loop_hook import FOLLOWED_STEPS
 
     ses = Session(cell, seed, trace, rehearsal)
     try:
         trainer, hook = ses.trainer, ses.hook
-        chips, steps_per_epoch = ses.chips, ses.steps_per_epoch
-        global_batch = ses.global_batch
+        family, job = ses.family, ses.job
+        chips, steps_per_epoch = job.chips, job.steps_per_epoch
+        global_batch = job.global_batch
         ses.warm_up()
         t0 = time.perf_counter()
         setup_s = t0 - T_START
@@ -307,44 +386,19 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
 
         del trainer, hook, stamps
         ses.release()
-        ref = reference_readings(cell, seed, chips, global_batch,
-                                 ses.train_size, steps_per_epoch,
-                                 ses.augmented)
+        ref = family.reference_readings(job, FOLLOWED_STEPS)
         log("reference followed")
     finally:
         ses.close()
 
     from compare import compare, observed, render
 
-    model, peaks = ses.model, ses.peaks
     correct, rows = compare(prog, ref, cell["limits"])
-    least = work.least_step_seconds(
-        model, ses.batch_per_chip, ses.config["precision"]["compute"],
-        peaks["bf16_flops_per_s"], peaks["hbm_bytes_per_s"])
-    metrics = {}
-    if not trace:
-        values = {
-            "throughput_per_chip": steps * global_batch / window_s / chips,
-            "step_ms_p95": percentile(gaps_ms, 95.0),
-            "setup_s": setup_s,
-        }
-        for m in cell["end_to_end"]:
-            metrics[m["name"]] = {"value": values[m["name"]],
-                                  "unit": m["unit"]}
-    else:
-        ctx = {
-            "trace": reduced, "spans": spans, "chips": chips,
-            "global_batch": global_batch,
-            "batch_per_chip": ses.batch_per_chip,
-            "flops_per_item": work.train_flops_per_item(model),
-            "least_step_s": least["seconds"],
-            "peaks": peaks, "memory_peak_bytes": memory_peak,
-            "model": model,
-        }
-        for m in cell["per_layer"]:
-            value = read_metric(m["name"], ctx)
-            if value is not None:
-                metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    window = {"seconds": window_s, "steps": steps, "gaps_ms": gaps_ms,
+              "setup_s": setup_s}
+    items_per_step, metrics = window_metrics(
+        cell, family, job, ses.peaks, window, trace, reduced, spans,
+        memory_peak)
 
     device = {"platform": ses.platform, "kind": ses.kind, "count": chips,
               "memory_peak_bytes": int(memory_peak)}
@@ -364,7 +418,9 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
         }
     result["window"] = {"seconds": window_s, "steps": steps,
                         "steps_per_epoch": steps_per_epoch,
-                        "global_batch": global_batch, "setup_s": setup_s}
+                        "global_batch": global_batch,
+                        "items_per_step": items_per_step,
+                        "setup_s": setup_s}
     result["compared"] = [
         {k: (None if isinstance(v, float) and not math.isfinite(v) else v)
          for k, v in row.items()} for row in rows]
@@ -372,40 +428,6 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
         print(f"observed {name} value={value:.6g}", file=sys.stderr)
     print(render(rows), file=sys.stderr, flush=True)
     return result
-
-
-def reference_readings(cell, seed, chips, global_batch, train_size,
-                       steps_per_epoch, augmented, precision="float32",
-                       fault=None) -> dict:
-    """The plain reference's reading of the first steps, on the cell's chips."""
-    import jax
-    import numpy as np
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-    import reference
-    from datagen import device_dataset, step_rows
-    from loop_hook import FOLLOWED_STEPS
-
-    model = cell["config_file"]["model"]
-    sharding = None
-    if chips > 1:
-        mesh = Mesh(np.asarray(jax.devices()[:chips]), ("data",))
-        sharding = NamedSharding(mesh, P("data"))
-    images, labels = device_dataset(
-        seed, train_size, int(model["num_classes"]),
-        int(model["image_size"]), int(model["image_channels"]))
-    batches = []
-    for k in range(FOLLOWED_STEPS):
-        rows = step_rows(PROGRAM_SEED, 0, train_size, global_batch, k)
-        x, y = images[rows], labels[rows]
-        if sharding is not None:
-            x, y = jax.device_put((x, y), sharding)
-        batches.append((x, y))
-    del images, labels
-    return reference.follow(
-        model, cell["config_file"]["optimizer"], seed, PROGRAM_SEED,
-        steps_per_epoch, augmented, batches, precision=precision,
-        fault=fault, chips=chips, batch_sharding=sharding)
 
 
 def main(argv=None, rehearsal: dict | None = None) -> int:

@@ -127,8 +127,10 @@ def test_sound_run_on_four_devices_meets_every_limit_on_the_norms():
 def test_float8_control_is_not_correct():
     """The reference computed in float8, put in the program's place."""
     cell = run.load_cell("r18-b4096-resident")
-    args = (cell, SEED, 1, 16, 64, 4, True)
-    ref = run.reference_readings(*args)
-    control = run.reference_readings(*args, precision="float8")
-    correct, rows = compare(control, ref, cell["limits"])
+    family, job = cell["family"], run.job_of(cell, SEED, REHEARSAL)
+    control = family.variants(job)["precisions"][0]
+    assert control == "float8"
+    ref = family.reference_readings(job, 3)
+    got = family.reference_readings(job, 3, precision=control)
+    correct, rows = compare(got, ref, cell["limits"])
     assert correct is False, rows
