@@ -78,8 +78,11 @@ def test_config_overrides_and_presets():
     assert c.train.epochs == 3 and c.model.bf16 and c.optim.lr == 0.5
     assert set(PRESETS) == {
         "reference", "resnet18_cifar10", "resnet50_cifar100",
-        "resnet18_8chip_gb1024", "bf16_cosine_gb4096",
+        "resnet18_8chip_gb1024", "bf16_cosine_gb4096", "sdar_30b_a3b_ep8",
     }
+    token = parse_cli(["--preset=sdar_30b_a3b_ep8", "--model.num_layers=2"])
+    assert (token.model.name, token.optim.name) == ("sdar_moe", "adamw")
+    assert (token.model.num_layers, token.data.seq_len) == (2, 4096)
     with pytest.raises(ValueError):
         Config().override("optim.nonexistent", "1")
 

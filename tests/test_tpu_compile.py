@@ -152,3 +152,52 @@ def test_random_crop_is_no_loop_for_v5e(one_chip, compiled_kernels, fn,
     assert " select(" in text
     for kind in ("while", "gather", "dynamic-slice", "dynamic-update-slice"):
         assert f" {kind}(" not in text, kind
+
+
+def _flash_attention_fwd_bwd(q, k, v):
+    from tpu_dp.models.sdar import flash_block_diffusion_attention
+
+    def loss(q, k, v):
+        out = flash_block_diffusion_attention(q, k, v, 4,
+                                              _partition.interpret())
+        return jnp.sum(out.astype(jnp.float32))
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+def _expert_products_fwd_bwd(h, gate, up, down):
+    from tpu_dp.models.sdar import experts_share, route
+
+    def loss(h, gate, up, down):
+        weights, experts = route(h, jnp.ones((h.shape[1], 128), h.dtype), 8)
+        out, _ = experts_share(h, weights, experts, gate, up, down, 0, 8)
+        return jnp.sum(out)
+    return jax.grad(loss, argnums=(0, 1, 2, 3))(h, gate, up, down)
+
+
+def test_block_diffusion_attention_kernel_compiles_for_v5e(one_chip,
+                                                           compiled_kernels):
+    """The shipped flash kernel with the block-diffusion mask computed from
+    the indices inside it, forward and backward, at the published widths
+    (32 heads over 4 key/value heads of 128) on one row of 2 x 4,096
+    positions. The score array of a row would be 8.6 GB in float32; the
+    whole call stays under 2 GiB."""
+    n2 = 2 * 4096
+    compiled = _compile(
+        _flash_attention_fwd_bwd, one_chip,
+        ((1, n2, 32, 128), jnp.bfloat16), ((1, n2, 4, 128), jnp.bfloat16),
+        ((1, n2, 4, 128), jnp.bfloat16))
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        + mem.output_size_in_bytes < 2 * 2**30
+
+
+def test_grouped_expert_products_are_a_kernel_of_the_compilers(
+        one_chip, compiled_kernels):
+    """`lax.ragged_dot` over the held experts is a tiled grouped product of
+    the TPU compiler's own (a ``tpu_custom_call``), forward and backward,
+    and not sixteen dense products masked afterwards: 8,192 positions, 16
+    experts of 2048 x 768, as a chunk of the cell's layer has them."""
+    _compile(
+        _expert_products_fwd_bwd, one_chip,
+        ((8192, 2048), jnp.bfloat16), ((16, 2048, 768), jnp.float32),
+        ((16, 2048, 768), jnp.float32), ((16, 768, 2048), jnp.float32))

@@ -31,11 +31,29 @@ class ModelConfig:
     fused_stages: str = ""
     fused_block_b: int = 0  # images per Pallas grid step; 0 = auto from VMEM budget
     fused_bwd: bool = False  # route the backward input-grad conv through it too
+    # Shapes of the block-diffusion mixture-of-experts decoder
+    # (model.name=sdar_moe; tpu_dp/models/sdar.py). The defaults are the
+    # published widths of SDAR-30B-A3B-Chat and one chip's share of an
+    # eight-chip expert group; num_classes is the vocabulary held here.
+    # The image classifiers read none of them.
+    hidden_size: int = 2048
+    num_layers: int = 4
+    num_heads: int = 32
+    num_kv_heads: int = 4
+    head_dim: int = 128
+    expert_width: int = 768
+    num_experts: int = 128  # the router's width
+    experts_per_token: int = 8
+    experts_held: int = 16  # this chip's experts of every layer ...
+    share_index: int = 0  # ... from share_index * experts_held
+    block_length: int = 4  # tokens a diffusion block
+    rope_theta: float = 1e6
 
 
 @dataclass
 class DataConfig:
-    dataset: str = "cifar10"  # cifar10 | cifar100 | synthetic
+    dataset: str = "cifar10"  # cifar10 | cifar100 | synthetic | synthetic_tokens
+    seq_len: int = 0  # tokens a row (synthetic_tokens; the vocabulary is model.num_classes)
     root: str = "./data"  # reference's `./data` (`cifar_example.py:44`)
     batch_size: int = 4  # per-process; reference parity (`cifar_example.py:42`)
     shuffle: bool = True
@@ -65,9 +83,14 @@ class DataConfig:
 
 @dataclass
 class OptimConfig:
+    name: str = "sgd"  # sgd | adamw
     lr: float = 0.001  # `cifar_example.py:64`
-    momentum: float = 0.9  # `cifar_example.py:64`
-    weight_decay: float = 0.0
+    momentum: float = 0.9  # `cifar_example.py:64` (sgd)
+    b1: float = 0.9  # adamw
+    b2: float = 0.95  # adamw
+    eps: float = 1e-8  # adamw
+    clip_norm: float = 0.0  # adamw: global gradient norm, 0 = no clipping
+    weight_decay: float = 0.0  # sgd: L2 into the gradient; adamw: decoupled
     # Exclude biases + norm scale/bias from decay (common high-accuracy
     # recipe); off by default for torch SGD parity (decays everything).
     decay_exclude_bias_and_norm: bool = False
@@ -629,7 +652,28 @@ def _preset_bf16_cosine_gb4096() -> Config:
     return c
 
 
+def _preset_sdar_30b_a3b_ep8() -> Config:
+    """SDAR-30B-A3B-Chat's layer at its published widths, cut to one chip
+    of an eight-chip expert group: 4 of 48 layers, 16 of 128 experts a
+    layer, 18,992 of 151,936 vocabulary rows; rows of 4,096 tokens, AdamW.
+    (`benchmark/configs/sdar-30b-a3b-ep8.json` states the cut.)"""
+    c = Config()
+    c.model = ModelConfig(name="sdar_moe", num_classes=18992, bf16=True)
+    c.data.dataset = "synthetic_tokens"
+    c.data.seq_len = 4096
+    c.data.batch_size = 4
+    c.data.synthetic_train_size = 128
+    c.data.synthetic_test_size = 8
+    c.optim = OptimConfig(name="adamw", lr=1e-4, b1=0.9, b2=0.95, eps=1e-8,
+                          clip_norm=1.0, weight_decay=0.1,
+                          decay_exclude_bias_and_norm=True)
+    c.train.epochs = 4
+    c.train.steps_per_call = 1
+    return c
+
+
 PRESETS = {
+    "sdar_30b_a3b_ep8": _preset_sdar_30b_a3b_ep8,
     "reference": _preset_reference_single,
     "resnet18_cifar10": _preset_resnet18_cifar10,
     "resnet50_cifar100": _preset_resnet50_cifar100,

@@ -9,6 +9,8 @@ download + `DataLoader(num_workers=2)` + `DistributedSampler`
 - `sampler`   — `DistributedSampler`-contract host sharding
 - `pipeline`  — batching, padding policy, prefetch-to-device
 - `augment`   — on-device random crop + flip (compiled into the train step)
+- `tokens`    — rows of token ids, through the same sampler and feeds
+- `noise`     — block-diffusion masking noise (compiled into the train step)
 """
 
 from tpu_dp.data.cifar import (
@@ -19,12 +21,15 @@ from tpu_dp.data.cifar import (
 )
 from tpu_dp.data.pipeline import DataPipeline
 from tpu_dp.data.sampler import ShardedSampler
+from tpu_dp.data.tokens import TokenDataset, make_synthetic_tokens
 
 __all__ = [
     "ArrayDataset",
     "DataPipeline",
     "ShardedSampler",
+    "TokenDataset",
     "load_dataset",
     "make_synthetic",
+    "make_synthetic_tokens",
     "normalize",
 ]

@@ -36,7 +36,8 @@ _DEFAULT_SYNTHETIC_TEST = 256
 class ArrayDataset:
     """An in-memory labeled image dataset.
 
-    ``images`` is uint8 NHWC; ``labels`` is int32. ``synthetic`` marks the
+    ``images`` is NHWC (uint8 from the loaders of this module, which check
+    it); ``labels`` is int32. ``synthetic`` marks the
     no-real-data fallback so callers (and benchmark reports) can tell the
     difference.
     """
@@ -48,11 +49,34 @@ class ArrayDataset:
     synthetic: bool = False
 
     def __post_init__(self):
-        assert self.images.ndim == 4 and self.images.dtype == np.uint8
         assert len(self.images) == len(self.labels)
 
     def __len__(self) -> int:
         return len(self.images)
+
+    @property
+    def arrays(self) -> dict[str, np.ndarray]:
+        """What a batch row is made of, keyed as the step reads its batch
+        (the pipeline and the resident feed ship these and nothing else)."""
+        return {"image": self.images, "label": self.labels}
+
+    @property
+    def items_per_row(self) -> int:
+        """The counted items of a batch row: one image."""
+        return 1
+
+    @property
+    def sample_input(self) -> np.ndarray:
+        """One row as the model sees it (uint8 images reach it normalized
+        to float32), for shape inference."""
+        dtype = np.float32 if self.images.dtype == np.uint8 else self.images.dtype
+        return np.zeros((1, *self.images.shape[1:]), dtype)
+
+
+def _check_images(images: np.ndarray) -> np.ndarray:
+    """What the image loaders promise: uint8 NHWC."""
+    assert images.ndim == 4 and images.dtype == np.uint8
+    return images
 
 
 def normalize(images: np.ndarray) -> np.ndarray:
@@ -98,7 +122,7 @@ def make_synthetic(
     noise = rng_e.normal(0.0, 24.0, size=(num_examples, *IMAGE_SHAPE))
     images = np.clip(templates[labels] + noise, 0, 255).astype(np.uint8)
     return ArrayDataset(
-        images=images, labels=labels, name=name,
+        images=_check_images(images), labels=labels, name=name,
         num_classes=num_classes, synthetic=True,
     )
 
@@ -117,7 +141,8 @@ def _read_pickle_batches(files: list[Path], label_key: bytes):
         labels.extend(d[label_key])
     data = np.concatenate(datas, axis=0)
     images = data.reshape(-1, 3, 32, 32).transpose(0, 2, 3, 1)
-    return np.ascontiguousarray(images), np.asarray(labels, dtype=np.int32)
+    return (_check_images(np.ascontiguousarray(images)),
+            np.asarray(labels, dtype=np.int32))
 
 
 _SPECS = {
@@ -145,10 +170,14 @@ def load_dataset(
     allow_synthetic: bool = True,
     synthetic_num_examples: int | None = None,
     seed: int = 0,
-) -> ArrayDataset:
+    seq_len: int = 0,
+    vocab_size: int | None = None,
+):
     """Load CIFAR-10/100 from ``root`` or fall back to synthetic data.
 
-    ``name`` ∈ {cifar10, cifar100, synthetic}. The on-disk layout expected
+    ``name`` ∈ {cifar10, cifar100, synthetic, synthetic_tokens}; the last
+    gives rows of ``seq_len`` token ids over ``vocab_size``
+    (`tpu_dp.data.tokens`). The on-disk layout expected
     under ``root`` is what torchvision's downloader extracts into the
     reference's `./data` (`/root/reference/cifar_example.py:44-45`). When
     the files are absent and ``allow_synthetic``, a deterministic synthetic
@@ -164,6 +193,17 @@ def load_dataset(
     # example seeds (disjoint noise/label draws).
     example_seed = seed * 2 + (0 if train else 1)
 
+    if name == "synthetic_tokens":
+        from tpu_dp.data.tokens import make_synthetic_tokens
+
+        if not seq_len or not vocab_size:
+            raise ValueError(
+                "data.dataset=synthetic_tokens needs data.seq_len and "
+                "model.num_classes (the vocabulary)")
+        return make_synthetic_tokens(
+            n_synth, seq_len, vocab_size, seed=seed,
+            example_seed=example_seed)
+
     if name == "synthetic":
         return make_synthetic(
             n_synth, 10, seed=seed, name="synthetic",
@@ -173,7 +213,7 @@ def load_dataset(
     if name not in _SPECS:
         raise ValueError(
             f"unknown dataset {name!r}; available: "
-            f"{sorted(_SPECS) + ['synthetic']}"
+            f"{sorted(_SPECS) + ['synthetic', 'synthetic_tokens']}"
         )
     spec = _SPECS[name]
     base = Path(root) / spec["dirname"]

@@ -124,7 +124,7 @@ class DataPipeline:
         always draws ``idx[s*per_step:(s+1)*per_step]`` regardless of
         where iteration starts).
         """
-        images, labels = self.dataset.images, self.dataset.labels
+        arrays = self.dataset.arrays
         idx = self.sampler.shard_indices()
         per_step = self.batch_size * self.accum_steps
         steps = len(self)
@@ -140,7 +140,7 @@ class DataPipeline:
                     [np.ones(len(take), np.float32), np.zeros(pad, np.float32)]
                 )
                 take = np.concatenate([take, np.resize(idx, pad)])
-            batch = {"image": images[take], "label": labels[take]}
+            batch = {k: v[take] for k, v in arrays.items()}
             if weight is not None:
                 batch["weight"] = weight
             if self.accum_steps > 1:
@@ -255,7 +255,7 @@ class DataPipeline:
 
     def dataset_bytes(self) -> int:
         """Host-side size of the dataset arrays (resident-staging budget)."""
-        return self.dataset.images.nbytes + self.dataset.labels.nbytes
+        return sum(v.nbytes for v in self.dataset.arrays.values())
 
     def resident_data(self):
         """Stage the WHOLE dataset on device, replicated over the mesh.
@@ -267,8 +267,7 @@ class DataPipeline:
         """
         from tpu_dp.parallel.sharding import replicated_sharding
 
-        data = {"image": self.dataset.images, "label": self.dataset.labels}
-        return shard_batch(data, self.mesh,
+        return shard_batch(dict(self.dataset.arrays), self.mesh,
                            spec=replicated_sharding(self.mesh))
 
     def index_windows(self, k: int, skip_steps: int = 0):

@@ -7,9 +7,13 @@ both (SURVEY.md §6 note).
 """
 
 from tpu_dp.models.net import Net
+from tpu_dp.models.outputs import RowLoss
 from tpu_dp.models.resnet import ResNet, ResNet18, ResNet50
+from tpu_dp.models.sdar import BlockDiffusionMoE
 
 _REGISTRY = {
+    "sdar_moe": lambda num_classes=10, **kw: BlockDiffusionMoE(
+        num_classes=num_classes, **kw),
     "net": lambda num_classes=10, **kw: Net(num_classes=num_classes, **kw),
     "resnet18": lambda num_classes=10, **kw: ResNet18(num_classes=num_classes, **kw),
     "resnet50": lambda num_classes=10, **kw: ResNet50(num_classes=num_classes, **kw),
@@ -17,6 +21,15 @@ _REGISTRY = {
 
 # Models that understand the ResNet-only kwargs (fused Pallas stages etc.).
 _RESNETS = {"resnet18", "resnet50"}
+
+# The decoder's shape keys (`ModelConfig`): the trainer hands every model's
+# factory the whole of the configuration's shapes, and a model is given
+# what it takes.
+DECODER_SHAPES = ("hidden_size", "num_layers", "num_heads", "num_kv_heads",
+                  "head_dim", "expert_width", "num_experts",
+                  "experts_per_token", "experts_held", "share_index",
+                  "block_length", "rope_theta")
+_DECODERS = {"sdar_moe"}
 
 # Models carrying BatchNorm, i.e. the ones that accept ``axis_name`` for
 # sync-BN inside shard_map (one source of truth — the trainer keys its
@@ -56,10 +69,14 @@ def build_model(name: str, num_classes: int = 10, **kwargs):
         kwargs.pop("fused_stages", None)
         kwargs.pop("fused_block_b", None)
         kwargs.pop("fused_bwd", None)
+    if key not in _DECODERS:
+        for shape in DECODER_SHAPES:
+            kwargs.pop(shape, None)
     return factory(num_classes=num_classes, **kwargs)
 
 
 __all__ = [
-    "BATCHNORM_MODELS", "Net", "ResNet", "ResNet18", "ResNet50",
-    "build_model", "parse_fused_stages",
+    "BATCHNORM_MODELS", "BlockDiffusionMoE", "DECODER_SHAPES", "Net",
+    "ResNet", "ResNet18", "ResNet50", "RowLoss", "build_model",
+    "parse_fused_stages",
 ]

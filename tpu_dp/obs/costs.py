@@ -56,6 +56,9 @@ PEAK_FLOPS_BY_KIND = tuple(
 #: XLA's compiled count within FLOPS_CHECK_RTOL). Models not listed have
 #: no analytic yardstick — their MFU needs a measured cost
 #: (`Trainer` with ``obs.measure_flops=true``, or bench's cost analysis).
+#: The unit is a batch row, which for every model listed is an image; a
+#: model whose row holds many items (a token model) has no entry, and its
+#: cost is measured from the abstract batch its data set describes.
 MODEL_TRAIN_FLOPS_PER_IMAGE = {
     "resnet18": 3.0e9,
     "resnet50": 7.0e9,

@@ -138,8 +138,18 @@ METRICS: dict[str, str] = {
     "serve.failover.retried": "requests retried on another replica",
     "serve.model_version": "model version a replica serves (gauge)",
     "serve.membership_epoch": "serve-fleet membership epoch (gauge)",
+    # a model's own counters, summed on the device and published at the
+    # epoch's fence (the model says which: `counter_names`; train/trainer.py)
+    "moe.assignments": "(position, expert) pairs the router made",
+    "moe.assignments_held": "pairs whose expert this chip holds",
+    "moe.assignments_dropped": "held pairs not computed (always 0)",
+    "moe.load_max_sum": "a layer's largest pair count over the held experts, summed over steps and layers",
+    "moe.load_mean_sum": "a layer's mean pair count over the held experts, summed likewise",
+    "diffusion.tokens": "clean tokens trained on",
+    "diffusion.masked_tokens": "tokens the noise masked (the loss's positions)",
     # observability derived rates (obs/, train/trainer.py)
     "throughput.images_per_sec": "global training throughput (gauge)",
+    "throughput.items_per_sec": "the same where a batch row holds many items (tokens)",
     "obs.comm_ms": "per-window collective time (gauge, ms)",
     "obs.exposed_comm_ms": "per-window exposed (unoverlapped) comm ms",
     "obs.overlap_frac": "fraction of comm overlapped with compute",

@@ -71,6 +71,13 @@ def psum(tree: Any, axis_name: str = DATA_AXIS) -> Any:
     return jax.tree_util.tree_map(lambda x: lax.psum(x, axis_name), tree)
 
 
+def pmax(tree: Any, axis_name: str = DATA_AXIS) -> Any:
+    """All-reduce-max a pytree across the mesh axis (inside shard_map):
+    how the sharded update agrees on a scalar optimizer slot of which
+    every replica keeps a copy (`train.optim.ShardedUpdate`)."""
+    return jax.tree_util.tree_map(lambda x: lax.pmax(x, axis_name), tree)
+
+
 def padded_size(n: int, world: int) -> int:
     """``n`` rounded up to a multiple of ``world`` (the flat shard layout)."""
     return n + (-n) % world

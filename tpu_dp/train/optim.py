@@ -28,6 +28,7 @@ it.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Protocol
 
 import jax
@@ -94,6 +95,64 @@ class SGD:
             lambda p, b: p - lr * b, params, new_buf
         )
         return new_params, new_buf
+
+
+class AdamW:
+    """AdamW as a pytree transform, through the same protocol as `SGD`.
+
+    Bias-corrected moments ``m``, ``v`` (``b1``, ``b2``, ``eps``), decoupled
+    decay ``p -= lr * wd * p`` (on every leaf, or sparing biases and norm
+    scales with ``decay_exclude_bias_and_norm``), and the gradient clipped
+    to a global norm of ``clip_norm`` before the moments (0 = no clipping).
+    The state is ``{"count", "m", "v"}``: with float32 parameters and
+    gradients, 16 bytes a parameter in all.
+
+    ``sumsq_reduce`` closes the cross-replica gap of the clip under
+    `ShardedUpdate`, where each replica holds 1/world of every gradient:
+    the local sum of squares is partial, and one scalar psum makes it the
+    global norm's (`ShardedUpdate.update` passes it).
+    """
+
+    def __init__(self, b1: float = 0.9, b2: float = 0.95, eps: float = 1e-8,
+                 weight_decay: float = 0.0, clip_norm: float = 0.0,
+                 decay_exclude_bias_and_norm: bool = False):
+        self.b1, self.b2, self.eps = b1, b2, eps
+        self.weight_decay = weight_decay
+        self.clip_norm = clip_norm
+        self.decay_exclude_bias_and_norm = decay_exclude_bias_and_norm
+
+    def init(self, params):
+        zeros = lambda: jax.tree_util.tree_map(jnp.zeros_like, params)  # noqa: E731
+        return {"count": jnp.zeros((), jnp.int32), "m": zeros(), "v": zeros()}
+
+    def update(self, grads, opt_state, params, lr, sumsq_reduce=None):
+        """Returns (new_params, new_opt_state)."""
+        if self.clip_norm:
+            sumsq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                        for g in jax.tree_util.tree_leaves(grads))
+            if sumsq_reduce is not None:
+                sumsq = sumsq_reduce(sumsq)
+            scale = jnp.minimum(1.0, self.clip_norm / (jnp.sqrt(sumsq) + 1e-6))
+            grads = jax.tree_util.tree_map(lambda g: g * scale, grads)
+        b1, b2 = self.b1, self.b2
+        count = opt_state["count"] + 1
+        c1 = 1.0 - b1 ** count.astype(jnp.float32)
+        c2 = 1.0 - b2 ** count.astype(jnp.float32)
+        m = jax.tree_util.tree_map(
+            lambda m, g: b1 * m + (1.0 - b1) * g, opt_state["m"], grads)
+        v = jax.tree_util.tree_map(
+            lambda v, g: b2 * v + (1.0 - b2) * g * g, opt_state["v"], grads)
+
+        def leaf(path, p, m, v):
+            step = (m / c1) / (jnp.sqrt(v / c2) + self.eps)
+            spared = (self.decay_exclude_bias_and_norm
+                      and _is_no_decay_leaf(path))
+            if self.weight_decay and not spared:
+                step = step + self.weight_decay * p
+            return p - lr * step
+
+        new_params = jax.tree_util.tree_map_with_path(leaf, params, m, v)
+        return new_params, {"count": count, "m": m, "v": v}
 
 
 class ShardedUpdate:
@@ -168,8 +227,24 @@ class ShardedUpdate:
         param_shards = collectives.shard_slice(
             params, self.axis_name, world=self.world
         )
+        # A scalar slot (Adam's step count) is kept one copy a replica. A
+        # checkpoint relayout pads with zeros, so after a restore onto more
+        # replicas, or from the replicated layout, some copies are zero:
+        # take the largest. (SGD's state has no scalar and gets no pmax.)
+        scalars = jax.tree_util.tree_map(
+            lambda s: s.ndim == 0, jax.eval_shape(self.inner.init, params))
+        opt_state = jax.tree_util.tree_map(
+            lambda s, scalar: jax.lax.pcast(
+                collectives.pmax(s, self.axis_name), self.axis_name,
+                to="varying") if scalar else s,
+            opt_state, scalars)
+        extra = {}
+        if getattr(self.inner, "clip_norm", 0.0):
+            # A global gradient norm over shards: one scalar psum.
+            extra["sumsq_reduce"] = functools.partial(
+                collectives.psum, axis_name=self.axis_name)
         new_param_shards, new_opt_state = self.inner.update(
-            grad_shards, opt_state, param_shards, lr
+            grad_shards, opt_state, param_shards, lr, **extra
         )
         new_params = collectives.all_gather(
             new_param_shards, params, self.axis_name

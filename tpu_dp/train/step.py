@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
+from tpu_dp.models.outputs import RowLoss
 from tpu_dp.parallel.sharding import (
     batch_sharding,
     replicated_sharding,
@@ -83,6 +84,39 @@ def _maybe_normalize(images: jnp.ndarray) -> jnp.ndarray:
     return images
 
 
+def _inputs_and_labels(batch):
+    """``(inputs, labels)`` of a batch as its data set ships it: images
+    (normalized here) with their class labels, or rows of tokens, which are
+    their own targets (`tpu_dp.data.tokens`)."""
+    if "tokens" in batch:
+        return batch["tokens"], batch["tokens"]
+    # an inference batch carries no labels
+    return _maybe_normalize(batch["image"]), batch.get("label")
+
+
+def _augment_phase(augment_fn) -> str:
+    """The phase name of what runs in the step's augmentation seam: a
+    function may carry its own (the diffusion noise is ``tpu_dp.noise``)."""
+    return getattr(augment_fn, "phase", "tpu_dp.augment")
+
+
+def _loss_of(loss_impl, outputs, labels):
+    """The step's loss from a model's outputs: logits go through
+    ``loss_impl``; a model that computes its loss by the row
+    (`tpu_dp.models.outputs.RowLoss`) hands the rows over."""
+    if isinstance(outputs, RowLoss):
+        return jnp.mean(outputs.loss)
+    return loss_impl(outputs, labels)
+
+
+def _correct_and_counters(outputs, labels):
+    """``(correct, counters)``: an argmax over logits and no counters, or
+    what a `RowLoss` says was judged right, with the model's counters."""
+    if isinstance(outputs, RowLoss):
+        return jnp.sum(outputs.correct), outputs.counters
+    return jnp.sum(jnp.argmax(outputs, axis=-1) == labels), None
+
+
 def _apply_model(model, state: TrainState, images, train: bool):
     """Run the model, handling BatchNorm's mutable running stats."""
     if state.has_batch_stats:
@@ -98,7 +132,8 @@ def _apply_model(model, state: TrainState, images, train: bool):
 
 def _forward_backward(model, loss_impl, state: TrainState, images, labels,
                       cast_params=None):
-    """Shared fwd+bwd block: loss, grads, updated BN stats, correct count.
+    """Shared fwd+bwd block: loss, grads, updated BN stats, correct count,
+    and the model's counters (None for a model that publishes none).
 
     Train batches are always full (drop_remainder enforced), so no weight
     mask on the training loss. Used by both step factories so the GSPMD and
@@ -116,16 +151,17 @@ def _forward_backward(model, loss_impl, state: TrainState, images, labels,
         params0 = jax.tree_util.tree_map(cast_params, params0)
 
     def loss_fn(params):
-        logits, new_batch_stats = _apply_model(
+        outputs, new_batch_stats = _apply_model(
             model, state.replace(params=params), images, train=True
         )
-        return loss_impl(logits, labels), (logits, new_batch_stats)
+        return _loss_of(loss_impl, outputs, labels), (outputs,
+                                                      new_batch_stats)
 
-    (loss, (logits, new_batch_stats)), grads = jax.value_and_grad(
+    (loss, (outputs, new_batch_stats)), grads = jax.value_and_grad(
         loss_fn, has_aux=True
     )(params0)
-    correct = jnp.sum(jnp.argmax(logits, axis=-1) == labels)
-    return loss, grads, new_batch_stats, correct
+    correct, counters = _correct_and_counters(outputs, labels)
+    return loss, grads, new_batch_stats, correct, counters
 
 
 def _apply_update(
@@ -356,19 +392,18 @@ def _make_step_body(model, optimizer, schedule, loss_impl, augment_fn,
         # the compiled collective schedule (dplint DP304 fingerprint) is
         # unchanged.
         with jax.named_scope("tpu_dp.input"):
-            images, labels = _maybe_normalize(batch["image"]), batch["label"]
+            images, labels = _inputs_and_labels(batch)
         if augment_fn is not None:
             # A phase of its own, beside and not inside the input's: an
             # op's name carries one phase. Keyed by the global step:
             # compiled into the program, deterministic, identical on
             # every replica.
-            with jax.named_scope("tpu_dp.augment"):
+            with jax.named_scope(_augment_phase(augment_fn)):
                 images = augment_fn(state.step, images)
         with jax.named_scope("tpu_dp.fwd_bwd"):
-            loss, grads, new_batch_stats, correct = _forward_backward(
-                model, loss_impl, state, images, labels,
-                cast_params=cast_params
-            )
+            loss, grads, new_batch_stats, correct, counters = (
+                _forward_backward(model, loss_impl, state, images, labels,
+                                  cast_params=cast_params))
         count = jnp.asarray(labels.shape[0], jnp.int32)
         if sentinel:
             gi = guard_in if guard_in is not None else default_guard_in()
@@ -379,8 +414,10 @@ def _make_step_body(model, optimizer, schedule, loss_impl, augment_fn,
                 (grads, loss, correct, count, new_batch_stats,
                  new_residuals, extra) = reduce_fn(
                     grads, loss, correct, count, new_batch_stats,
-                    state.residuals,
+                    state.residuals, counters=counters,
                 )
+        elif counters is not None:
+            extra = {"counters": counters}
         if sentinel:
             return _sentinel_tail(
                 optimizer, schedule, state, grads, new_batch_stats,
@@ -426,35 +463,42 @@ def _make_accum_body(
         # Same named_scope annotations as `_make_step_body` (HLO metadata
         # for device-side trace attribution; schedule-neutral).
         with jax.named_scope("tpu_dp.input"):
-            images, labels = _maybe_normalize(batch["image"]), batch["label"]
+            images, labels = _inputs_and_labels(batch)
         if augment_fn is not None:
             # On-device augmentation keyed by the global step and the
             # microbatch index: compiled into the step, deterministic,
             # identical on every replica.
-            with jax.named_scope("tpu_dp.augment"):
+            with jax.named_scope(_augment_phase(augment_fn)):
                 images = jax.vmap(
                     lambda i, im: augment_fn(state.step * accum_steps + i, im)
                 )(jnp.arange(accum_steps), images)
 
+        # A model that publishes counters says which (`counter_names`);
+        # their sums ride the carry beside the loss's.
+        n_counters = len(getattr(model, "counter_names", ()))
+
         def micro(carry, mb):
-            grads_acc, batch_stats, loss_acc, correct_acc = carry
+            grads_acc, batch_stats, loss_acc, correct_acc, counters_acc = carry
             mstate = state.replace(batch_stats=batch_stats)
             with jax.named_scope("tpu_dp.fwd_bwd"):
-                loss, grads, new_bs, correct = _forward_backward(
+                loss, grads, new_bs, correct, counters = _forward_backward(
                     model, loss_impl, mstate, mb["image"], mb["label"],
                     cast_params=cast_params,
                 )
             grads_acc = jax.tree_util.tree_map(
                 jnp.add, grads_acc, grads
             )
+            if counters is not None:
+                counters_acc = counters_acc + counters
             return (grads_acc, new_bs, loss_acc + loss,
-                    correct_acc + correct), None
+                    correct_acc + correct, counters_acc), None
 
         init = (
             jax.tree_util.tree_map(jnp.zeros_like, state.params),
             state.batch_stats,
             jnp.zeros((), jnp.float32),
             jnp.zeros((), jnp.int32),
+            jnp.zeros((n_counters,), jnp.float32) if n_counters else (),
         )
         if cast_params is not None:
             # Under shard_map a scan carry must enter with the type it
@@ -465,10 +509,10 @@ def _make_accum_body(
             synced_bn = getattr(model, "axis_name", None) is not None
             init = (vary(init[0]),
                     init[1] if synced_bn else vary(init[1]),
-                    vary(init[2]), vary(init[3]))
-        (grads, new_batch_stats, loss_sum, correct), _ = jax.lax.scan(
-            micro, init, {"image": images, "label": labels}
-        )
+                    vary(init[2]), vary(init[3]), vary(init[4]))
+        (grads, new_batch_stats, loss_sum, correct, counters), _ = (
+            jax.lax.scan(micro, init, {"image": images, "label": labels}))
+        counters = counters if n_counters else None
         grads = jax.tree_util.tree_map(
             lambda g: g / accum_steps, grads
         )
@@ -493,8 +537,10 @@ def _make_accum_body(
                 (grads, loss, correct, count, new_batch_stats,
                  new_residuals, extra) = reduce_fn(
                     grads, loss, correct, count, new_batch_stats,
-                    state.residuals,
+                    state.residuals, counters=counters,
                 )
+        elif counters is not None:
+            extra = {"counters": counters}
 
         if sentinel:
             return _sentinel_tail(
@@ -1016,7 +1062,8 @@ def make_local_step(
 
     loss_impl = _select_loss_impl(use_pallas_xent)
 
-    def reduce_fn(grads, loss, correct, count, batch_stats, residuals):
+    def reduce_fn(grads, loss, correct, count, batch_stats, residuals,
+                  counters=None):
         # The explicit DDP reduction: grad mean over the data axis, exactly
         # once, after any gradient-accumulation scan. Replicated mode
         # all-reduces the full leaves; sharded mode reduce-scatters, each
@@ -1063,6 +1110,10 @@ def make_local_step(
             )
         else:
             grads = collectives.pmean(grads, axis_name)
+        if counters is not None:
+            # A model's own counters are per-shard sums: one more psum,
+            # in programs of such a model only.
+            extra["counters"] = collectives.psum(counters, axis_name)
         loss = collectives.pmean(loss, axis_name)
         correct = collectives.psum(correct, axis_name)
         count = count * world
@@ -1196,15 +1247,31 @@ def _infer_forward(model, state: TrainState, batch):
     BatchNorm models; ``state`` only needs params/batch_stats populated
     (serve passes a TrainState with an empty opt_state).
     """
-    images = _maybe_normalize(batch["image"])
-    logits, _ = _apply_model(model, state, images, train=False)
+    logits = _infer_outputs(model, state, batch)
     predictions = jnp.argmax(logits, axis=-1)
     return logits, predictions
 
 
+def _infer_outputs(model, state: TrainState, batch, input_fn=None):
+    """The model's outputs on a batch at ``train=False``: logits, or a
+    `RowLoss` from a model that computes its own. ``input_fn(inputs)`` is
+    the part of the training step's input seam that the objective needs at
+    evaluation too (the diffusion noise, on a fixed key)."""
+    inputs, _ = _inputs_and_labels(batch)
+    if input_fn is not None:
+        with jax.named_scope(_augment_phase(input_fn)):
+            inputs = input_fn(inputs)
+    return _apply_model(model, state, inputs, train=False)[0]
+
+
 def make_eval_step(model, mesh: Mesh,
-                   update_sharding: str = "replicated") -> Callable:
+                   update_sharding: str = "replicated",
+                   input_fn: Callable | None = None) -> Callable:
     """Build the jitted eval step: global (correct, count) per batch.
+
+    A model that returns a `RowLoss` (`tpu_dp.models.outputs`) reports its
+    own loss, with ``correct`` and ``count`` the judged items predicted
+    right and their number, in the same three slots.
 
     ``update_sharding`` must match the TrainState's layout: with the
     sharded weight update the opt_state leaves arrive sharded over ``data``
@@ -1226,9 +1293,17 @@ def make_eval_step(model, mesh: Mesh,
     state_sh = _state_shardings(mesh, update_sharding)
 
     def step(state: TrainState, batch):
-        labels = batch["label"]
         weight = batch.get("weight")
-        logits, predictions = _infer_forward(model, state, batch)
+        outputs = _infer_outputs(model, state, batch, input_fn)
+        if isinstance(outputs, RowLoss):
+            w = jnp.ones_like(outputs.loss) if weight is None else weight
+            return {
+                "loss": jnp.sum(outputs.loss * w) / jnp.maximum(jnp.sum(w), 1.0),
+                "correct": jnp.sum(outputs.correct * w).astype(jnp.int32),
+                "count": jnp.sum(outputs.count * w).astype(jnp.int32),
+            }
+        labels = batch["label"]
+        logits, predictions = outputs, jnp.argmax(outputs, axis=-1)
         if weight is None:
             correct = jnp.sum(predictions == labels)
             count = jnp.asarray(labels.shape[0], jnp.int32)

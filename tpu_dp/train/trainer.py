@@ -20,6 +20,7 @@ step, `cifar_example.py:83`).
 
 from __future__ import annotations
 
+import functools
 import json
 import time
 from pathlib import Path
@@ -114,7 +115,8 @@ def _elastic_fatal_errors() -> tuple[type[BaseException], ...]:
 class Trainer:
     def __init__(self, cfg: Config, mesh=None, datasets=None):
         """``datasets`` hands the trainer its ``(train, test)`` data sets
-        (`ArrayDataset`s) in place of those ``cfg.data`` would load."""
+        (`ArrayDataset`s or `TokenDataset`s) in place of those ``cfg.data``
+        would load."""
         self.cfg = cfg
         # Elastic grow (docs/RESILIENCE.md "Grow"): before any classic
         # bootstrap, a starting process may instead JOIN a live run it
@@ -258,13 +260,15 @@ class Trainer:
                  cfg.train.profile, cfg.model.name, self.num_devices,
                  jax.default_backend())
 
+        from tpu_dp.models import BATCHNORM_MODELS, DECODER_SHAPES
+
         model_kwargs = dict(
             num_classes=num_classes, dtype=dtype,
             fused_stages=parse_fused_stages(cfg.model.fused_stages),
             fused_block_b=cfg.model.fused_block_b,
             fused_bwd=cfg.model.fused_bwd,
+            **{k: getattr(cfg.model, k) for k in DECODER_SHAPES},
         )
-        from tpu_dp.models import BATCHNORM_MODELS
 
         if us == "sharded" and cfg.model.name.lower() in BATCHNORM_MODELS:
             model_kwargs["axis_name"] = dist.DATA_AXIS
@@ -279,12 +283,22 @@ class Trainer:
                 **{k: v for k, v in model_kwargs.items()
                    if k != "axis_name"})
 
+        # What the step does to its inputs between the feed and the model,
+        # keyed by (seed + 1, step): the noise a model's objective is made
+        # of (a model that has one makes the function), or the crops.
         augment_fn = None
-        if cfg.data.augment:
+        if hasattr(self.model, "make_noise_fn"):
+            augment_fn = self.model.make_noise_fn(cfg.train.seed + 1)
+        elif cfg.data.augment:
             from tpu_dp.data.augment import make_augment_fn
 
             augment_fn = make_augment_fn(cfg.train.seed + 1)
         self._augment_fn = augment_fn
+        # A batch row's counted items (an image: 1; a row of tokens: its
+        # length), and the name its rate goes by.
+        self._items_per_row = int(self.train_ds.items_per_row)
+        self._rate_name = ("images_per_sec" if self._items_per_row == 1
+                           else "items_per_sec")
         # RecompileGuard (dplint DP305's runtime half): any post-warmup
         # growth of a step's trace cache is a silent recompile — a
         # step-time cliff this surfaces instead of swallowing. The eval
@@ -699,9 +713,8 @@ class Trainer:
         regroup reload targets all build states through here so none can
         forget a layout-bearing field."""
         rng = jax.random.PRNGKey(self.cfg.train.seed)
-        sample = np.zeros((1, 32, 32, 3), np.float32)
         return self._with_residuals(create_train_state(
-            self._init_model, rng, sample, self.optimizer
+            self._init_model, rng, self.train_ds.sample_input, self.optimizer
         ))
 
     def _publish_quant_counters(self, window, first_step: int) -> None:
@@ -791,11 +804,26 @@ class Trainer:
         augment_fn = self._augment_fn
         steps_per_epoch = len(self.train_pipe)
         total_steps = steps_per_epoch * cfg.train.epochs
-        self.optimizer = SGD(
-            cfg.optim.momentum,
-            cfg.optim.weight_decay,
-            decay_exclude_bias_and_norm=cfg.optim.decay_exclude_bias_and_norm,
-        )
+        if cfg.optim.name == "sgd":
+            self.optimizer = SGD(
+                cfg.optim.momentum,
+                cfg.optim.weight_decay,
+                decay_exclude_bias_and_norm=(
+                    cfg.optim.decay_exclude_bias_and_norm),
+            )
+        elif cfg.optim.name == "adamw":
+            from tpu_dp.train.optim import AdamW
+
+            self.optimizer = AdamW(
+                cfg.optim.b1, cfg.optim.b2, cfg.optim.eps,
+                weight_decay=cfg.optim.weight_decay,
+                clip_norm=cfg.optim.clip_norm,
+                decay_exclude_bias_and_norm=(
+                    cfg.optim.decay_exclude_bias_and_norm),
+            )
+        else:
+            raise ValueError(
+                f"optim.name must be sgd|adamw, got {cfg.optim.name!r}")
         # Sharded mode wraps the optimizer so its state initializes — and
         # persists — sharded over the data axis; the train step then routes
         # through the explicit-collectives factory that reduce-scatters
@@ -834,8 +862,15 @@ class Trainer:
                 augment_fn=augment_fn,
                 sentinel=self.guard_enabled,
             ))
+        eval_input_fn = None
+        if getattr(augment_fn, "in_eval", False):
+            from tpu_dp.data.noise import EVAL_STEP
+
+            eval_input_fn = functools.partial(augment_fn, EVAL_STEP)
+            eval_input_fn.phase = augment_fn.phase
         self.eval_step = make_eval_step(self.model, self.mesh,
-                                        update_sharding=us)
+                                        update_sharding=us,
+                                        input_fn=eval_input_fn)
         spc = int(cfg.train.steps_per_call)
         if spc == 0:
             # Auto: windowed dispatch whenever the pipeline shape allows.
@@ -1075,17 +1110,13 @@ class Trainer:
         """Abstract (state, batch[, guard_in]) args of the shipped per-step
         program — shared by the DP304 fingerprint check and the
         cost-analysis FLOPs measurement."""
-        import jax.numpy as jnp
-
         cfg = self.cfg
         gb = cfg.data.batch_size * self.ctx.process_count
         accum = cfg.optim.grad_accum_steps
         prefix = (accum,) if accum > 1 else ()
         batch = {
-            "image": jax.ShapeDtypeStruct(
-                prefix + (gb, 32, 32, 3), jnp.uint8
-            ),
-            "label": jax.ShapeDtypeStruct(prefix + (gb,), jnp.int32),
+            k: jax.ShapeDtypeStruct(prefix + (gb, *v.shape[1:]), v.dtype)
+            for k, v in self.train_ds.arrays.items()
         }
         args = (self.state, batch)
         if self.guard_enabled:
@@ -1295,19 +1326,18 @@ class Trainer:
         """
 
         def _load():
-            train = load_dataset(
-                cfg.data.dataset, cfg.data.root, train=True,
+            load = functools.partial(
+                load_dataset, cfg.data.dataset, cfg.data.root,
                 allow_synthetic=cfg.data.allow_synthetic,
-                synthetic_num_examples=cfg.data.synthetic_train_size,
-                seed=cfg.train.seed,
+                seed=cfg.train.seed, seq_len=cfg.data.seq_len,
+                vocab_size=cfg.model.num_classes,
             )
-            test = load_dataset(
-                cfg.data.dataset, cfg.data.root, train=False,
-                allow_synthetic=cfg.data.allow_synthetic,
-                synthetic_num_examples=cfg.data.synthetic_test_size,
-                seed=cfg.train.seed,
+            return (
+                load(train=True,
+                     synthetic_num_examples=cfg.data.synthetic_train_size),
+                load(train=False,
+                     synthetic_num_examples=cfg.data.synthetic_test_size),
             )
-            return train, test
 
         if self.ctx.process_count == 1 or self._join is not None:
             # A joiner must not run the materialization barrier: the
@@ -1608,8 +1638,11 @@ class Trainer:
         pipe.set_epoch(epoch)  # `cifar_example_ddp.py:92` parity
         gbs = self.global_batch_size
         run_loss, run_steps = None, 0  # device-side running-loss accumulator
-        ep_loss = ep_correct = None
+        # `ep_counters`: the sums of a model's own counters (a step of a
+        # model that publishes some carries them as one vector).
+        ep_loss = ep_correct = ep_counters = None
         ep_steps, ep_count = 0, 0
+        step_items = gbs * self._items_per_row
         i = start_step - 1
         done = base + start_step  # epoch steps completed (snapshot meta)
         self._epoch_done = done
@@ -1694,9 +1727,14 @@ class Trainer:
                     m["correct"] if ep_correct is None
                     else ep_correct + m["correct"]
                 )
+                if "counters" in m:
+                    ep_counters = (
+                        m["counters"] if ep_counters is None
+                        else ep_counters + m["counters"]
+                    )
                 ep_steps += 1
                 ep_count += gbs
-                self.meter.step(gbs)
+                self.meter.step(step_items)
                 if i % cfg.train.log_every == cfg.train.log_every - 1:
                     # Reference print format (`cifar_example.py:85-86`); the
                     # float() here is the only sync per log interval.
@@ -1739,6 +1777,15 @@ class Trainer:
                 hook.on_step_end(ev)
         if last_rec is not None:
             spans.begin("epoch_fence", rec=last_rec)
+        if ep_counters is not None:
+            # Published where the epoch's loss is fetched: the same fence.
+            totals = dict(zip(self.model.counter_names,
+                              np.asarray(ep_counters, np.float64)))
+            for name, value in totals.items():
+                _obs_counters.inc(name, float(value))
+            # What `correct` is a share of, where the model counts it.
+            ep_count = int(totals.get(
+                getattr(self.model, "count_counter", None), ep_count))
         stats = {
             "loss": float(ep_loss) / max(1, ep_steps) if ep_steps else 0.0,
             "accuracy": float(ep_correct) / ep_count if ep_count else 0.0,
@@ -1752,6 +1799,14 @@ class Trainer:
         if last_rec is not None:
             self._fence_t = spans.end()
         return stats
+
+    def _publish_rate(self) -> None:
+        """The meter's rate as a gauge, under the name of what it counts."""
+        rate = round(self.meter.items_per_sec, 1)
+        if self._items_per_row == 1:
+            _obs_counters.gauge("throughput.images_per_sec", rate)
+        else:
+            _obs_counters.gauge("throughput.items_per_sec", rate)
 
     def _window_telemetry(self, n: int, out, stacked: bool,
                           fence_t: float | None) -> dict:
@@ -1770,10 +1825,7 @@ class Trainer:
             float(out["loss"][-1] if stacked else out["loss"])
             self.meter.mark()  # the same fence feeds the meter
             spans.begin("telemetry")
-            _obs_counters.gauge(
-                "throughput.images_per_sec",
-                round(self.meter.images_per_sec, 1),
-            )
+            self._publish_rate()
             from tpu_dp.obs import update_device_memory_gauges
 
             update_device_memory_gauges()
@@ -2801,20 +2853,18 @@ class Trainer:
                             epoch, start_step = self._execute_regroup(sig)
                         continue
                     history.append(stats)
-                    log0("epoch %d: train loss %.4f acc %.4f (%.1f img/s)",
+                    log0("epoch %d: train loss %.4f acc %.4f (%.1f %s/s)",
                          epoch + 1, stats["loss"], stats["accuracy"],
-                         self.meter.images_per_sec)
+                         self.meter.items_per_sec,
+                         "img" if self._items_per_row == 1 else "items")
                     epoch_rec = {"epoch": epoch + 1, **stats,
-                                 "images_per_sec":
-                                     round(self.meter.images_per_sec, 1)}
+                                 self._rate_name:
+                                     round(self.meter.items_per_sec, 1)}
                     if self.spans is not None:
                         # Epoch rollup: span percentiles over the ring +
                         # the counter registry — the at-a-glance record
                         # (per-step records are obs=full only).
-                        _obs_counters.gauge(
-                            "throughput.images_per_sec",
-                            round(self.meter.images_per_sec, 1),
-                        )
+                        self._publish_rate()
                         from tpu_dp.obs import update_device_memory_gauges
 
                         update_device_memory_gauges()
@@ -2967,15 +3017,17 @@ class Trainer:
         result: dict[str, Any] = {
             "history": history,
             "wall_time_s": wall,
-            "images_per_sec": self.meter.images_per_sec,
+            self._rate_name: self.meter.items_per_sec,
         }
         if cfg.train.eval_at_end:
             eval_stats = self.evaluate()
             result["eval"] = eval_stats
             self._log_metrics({"eval": eval_stats})
             # Reference integer-percent print (`cifar_example.py:111-112`).
-            print0("Accuracy of the network on the %d test images: %d %%"
-                   % (len(self.test_ds), int(100 * eval_stats["accuracy"])))
+            print0("Accuracy of the network on the %d test %s: %d %%"
+                   % (len(self.test_ds),
+                      "images" if self._items_per_row == 1 else "rows",
+                      int(100 * eval_stats["accuracy"])))
         return result
 
 

@@ -80,8 +80,15 @@ class ThroughputMeter:
         return self._last - self._start
 
     @property
-    def images_per_sec(self) -> float:
+    def items_per_sec(self) -> float:
+        """The counted items a second: images, or a token model's tokens
+        (the caller says how many a step holds)."""
         return self._images / self.elapsed if self.elapsed > 0 else 0.0
+
+    @property
+    def images_per_sec(self) -> float:
+        """`items_per_sec` under the name it has where an item is an image."""
+        return self.items_per_sec
 
     @property
     def step_time_ms(self) -> float:

@@ -64,7 +64,8 @@ def main(argv=None) -> int:
         "dataset": trainer.train_ds.name,
         "synthetic": trainer.train_ds.synthetic,
         "devices": trainer.num_devices,
-        "images_per_sec": round(result["images_per_sec"], 1),
+        **{k: round(result[k], 1)
+           for k in ("images_per_sec", "items_per_sec") if k in result},
         "wall_time_s": round(result["wall_time_s"], 1),
         "final_train_loss": round(result["history"][-1]["loss"], 4)
         if result["history"] else None,
