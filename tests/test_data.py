@@ -202,6 +202,46 @@ def test_index_windows_match_windows(mesh8):
                      drop_remainder=False).index_windows(4)
 
 
+def _dataset_of(kind):
+    from tpu_dp.data.cifar import ArrayDataset
+    from tpu_dp.data.tokens import make_synthetic_tokens
+
+    if kind == "tokens":
+        return make_synthetic_tokens(32, 24, 50, seed=0)
+    if kind == "cifar":
+        return make_synthetic(32, 10, seed=0, name="synthetic")
+    rng = np.random.default_rng(0)
+    return ArrayDataset(
+        images=rng.integers(0, 256, (32, 28, 28, 1), dtype=np.uint8),
+        labels=rng.integers(0, 10, 32).astype(np.int32),
+        name="mnist-like", num_classes=10, synthetic=True)
+
+
+@pytest.mark.parametrize("kind,shapes", [
+    ("cifar", {"image": (32, 3072), "label": (32,)}),
+    ("mnist-like", {"image": (32, 784), "label": (32,)}),
+    ("tokens", {"tokens": (32, 24)}),
+])
+def test_resident_data_stages_rows_flat(mesh8, kind, shapes):
+    """Every staged array has rank at most 2 (a 4-D uint8 argument is
+    N-minor on the chip, and a gather along it relays the whole data set
+    out, PERF.md PR 29), the rows' bytes and dtype are the data set's, and
+    `sample_shapes` says what the step reshapes a gathered row to."""
+    from tpu_dp.data.pipeline import DataPipeline
+
+    ds = _dataset_of(kind)
+    pipe = DataPipeline(ds, 16, mesh8)
+    staged = pipe.resident_data()
+    assert {k: v.shape for k, v in staged.items()} == shapes
+    assert pipe.sample_shapes == {k: v.shape[1:]
+                                  for k, v in ds.arrays.items()}
+    for k, v in ds.arrays.items():
+        assert staged[k].dtype == v.dtype
+        assert staged[k].sharding.is_fully_replicated
+        np.testing.assert_array_equal(
+            np.asarray(staged[k]).reshape(v.shape), v)
+
+
 def test_index_windows_accum_shape(mesh8):
     from tpu_dp.data.pipeline import DataPipeline
 

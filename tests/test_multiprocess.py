@@ -392,7 +392,8 @@ pipe = DataPipeline(ds, batch_size=8, mesh=mesh, shuffle=True, seed=7,
 # by process-locally assembled sharded indices.
 rdata = pipe.resident_data()
 rloop = make_multi_step_resident(model, opt, mesh, constant_lr(0.05),
-                                 num_steps=2)
+                                 num_steps=2,
+                                 sample_shapes=pipe.sample_shapes)
 pipe.set_epoch(0)
 state = fresh_state()
 for n, idx in pipe.index_windows(2):   # 4 steps -> 2 windows of 2

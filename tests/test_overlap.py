@@ -390,7 +390,8 @@ def test_factory_and_config_validation():
                         bucket_mb=1.0)
     with pytest.raises(ValueError, match="bucket_mb"):
         make_multi_step_resident(model, opt, mesh, constant_lr(0.1),
-                                 num_steps=2, bucket_mb=1.0)
+                                 num_steps=2, sample_shapes={},
+                                 bucket_mb=1.0)
     from tpu_dp.config import Config
     from tpu_dp.train.trainer import Trainer
 

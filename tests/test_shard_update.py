@@ -357,19 +357,21 @@ def test_sharded_multi_step_matches_replicated_multi_step(mesh8):
 def test_sharded_resident_loop_matches_replicated(mesh8):
     """Device-resident feed + sharded update ≡ resident feed + replicated
     update: the feed redesign and the update redesign compose."""
-    from tpu_dp.parallel.sharding import replicated_sharding, shard_batch
+    from tpu_dp.data.pipeline import DataPipeline
     from tpu_dp.train.step import make_multi_step_resident
 
     model, opt, sopt, state_r, state_s = _states()
     K, n = 3, 16
     sched = constant_lr(0.05)
     ds = make_synthetic(K * n, 10, seed=7, name="res")
-    data = shard_batch({"image": ds.images, "label": ds.labels}, mesh8,
-                       spec=replicated_sharding(mesh8))
+    pipe = DataPipeline(ds, batch_size=n, mesh=mesh8)
+    data = pipe.resident_data()
     idx = np.arange(K * n, dtype=np.int32).reshape(K, n)
 
-    loop_r = make_multi_step_resident(model, opt, mesh8, sched, num_steps=K)
+    loop_r = make_multi_step_resident(model, opt, mesh8, sched, num_steps=K,
+                                      sample_shapes=pipe.sample_shapes)
     loop_s = make_multi_step_resident(model, sopt, mesh8, sched, num_steps=K,
+                                      sample_shapes=pipe.sample_shapes,
                                       update_sharding="sharded")
     sr, _ = loop_r(_copy(state_r), data, idx)
     ss, _ = loop_s(_copy(state_s), data, idx)

@@ -283,60 +283,106 @@ def test_scanned_multi_step_matches_host_loop(setup, mesh8):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
 
 
-def test_resident_loop_matches_multi_step(setup, mesh8):
-    """Device-resident feed ≡ streaming feed, exactly.
+def _image_dataset(sample_shape, n, seed):
+    """uint8 images of any row shape (`make_synthetic`'s are 32x32x3)."""
+    from tpu_dp.data.cifar import ArrayDataset
+
+    rng = np.random.default_rng(seed)
+    return ArrayDataset(
+        images=rng.integers(0, 256, (n, *sample_shape), dtype=np.uint8),
+        labels=rng.integers(0, 10, n).astype(np.int32),
+        name="res", num_classes=10, synthetic=True)
+
+
+# 28x28x1: a row of 784 bytes is no whole number of 128-lane tiles.
+@pytest.mark.parametrize("sample_shape", [(32, 32, 3), (28, 28, 1)],
+                         ids=["32x32x3", "28x28x1"])
+def test_resident_loop_matches_multi_step(mesh8, sample_shape):
+    """Device-resident feed ≡ streaming feed, bit for bit.
 
     `make_multi_step_resident` gathers each step's batch on-device from the
-    staged dataset by index; the trajectory and per-step metrics must be
-    indistinguishable from `make_multi_step` on the equivalent stacked pool
+    data set staged with its rows flat (`DataPipeline.resident_data`) and
+    restores the rows' shape; the trajectory and per-step metrics must be
+    those of `make_multi_step` on the equivalent stacked pool
     (VERDICT r4 next-steps #3). Exercises uint8 staging: normalization
     happens in-body for both paths.
     """
-    from tpu_dp.parallel.sharding import replicated_sharding, shard_batch
+    from tpu_dp.data.pipeline import DataPipeline
     from tpu_dp.train import cosine_lr, make_multi_step
     from tpu_dp.train.step import make_multi_step_resident
 
-    model, opt, state = setup
+    model, opt = Net(), SGD(momentum=0.9)
+    state = create_train_state(
+        model, jax.random.PRNGKey(0), np.zeros((1, *sample_shape), np.float32),
+        opt)
     K, n = 4, 16
     sched = cosine_lr(0.05, 10, 2)
-    ds = make_synthetic(K * n, 10, seed=7, name="res")
+    ds = _image_dataset(sample_shape, K * n, seed=7)
 
     loop = make_multi_step(model, opt, mesh8, sched, num_steps=K)
+    # Shuffled indices: the pool holds the same examples in the same order.
+    idx = np.random.default_rng(3).permutation(K * n).astype(np.int32)
+    idx = idx.reshape(K, n)
     pool = {
-        "image": ds.images.reshape(K, n, 32, 32, 3),  # uint8: in-body norm
-        "label": ds.labels.reshape(K, n),
+        "image": ds.images[idx],  # uint8: in-body norm
+        "label": ds.labels[idx],
     }
     s_stream, stream_m = loop(_copy(state), pool)
 
-    rloop = make_multi_step_resident(model, opt, mesh8, sched, num_steps=K)
-    data = shard_batch({"image": ds.images, "label": ds.labels}, mesh8,
-                       spec=replicated_sharding(mesh8))
-    # Shuffled indices covering the same examples in the same step order.
-    idx = np.arange(K * n, dtype=np.int32).reshape(K, n)
-    s_res, res_m = rloop(_copy(state), data, idx)
+    pipe = DataPipeline(ds, batch_size=n, mesh=mesh8)
+    rloop = make_multi_step_resident(model, opt, mesh8, sched, num_steps=K,
+                                     sample_shapes=pipe.sample_shapes)
+    s_res, res_m = rloop(_copy(state), pipe.resident_data(), idx)
 
     assert int(s_res.step) == int(s_stream.step) == K
-    np.testing.assert_allclose(np.asarray(res_m["loss"]),
-                               np.asarray(stream_m["loss"]), rtol=1e-6)
+    np.testing.assert_array_equal(np.asarray(res_m["loss"]),
+                                  np.asarray(stream_m["loss"]))
     np.testing.assert_array_equal(np.asarray(res_m["correct"]),
                                   np.asarray(stream_m["correct"]))
     for a, b in zip(
         jax.tree_util.tree_leaves(s_res.params),
         jax.tree_util.tree_leaves(s_stream.params),
     ):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-6)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("idx_shape", [(16,), (2, 8)],
+                         ids=["batch", "microbatches"])
+@pytest.mark.parametrize("arrays", [
+    {"image": ((32, 32, 3), np.uint8), "label": ((), np.int32)},
+    {"image": ((28, 28, 1), np.uint8), "label": ((), np.int32)},
+    {"tokens": ((24,), np.int32)},
+], ids=["images", "images-784", "tokens"])
+def test_gather_rows_gives_the_rows_their_shape_back(arrays, idx_shape):
+    """What the resident step gathers from flat rows is, in shape, dtype
+    and every bit, what indexing the data set's own arrays gives."""
+    from tpu_dp.train.step import gather_rows
+
+    rng = np.random.default_rng(0)
+    full = {k: rng.integers(0, 200, (40, *shape)).astype(dtype)
+            for k, (shape, dtype) in arrays.items()}
+    flat = {k: v.reshape(len(v), -1) if v.ndim > 2 else v
+            for k, v in full.items()}
+    idx = rng.integers(0, 40, idx_shape).astype(np.int32)
+    shapes = {k: v.shape[1:] for k, v in full.items()}
+    got = jax.jit(lambda d, i: gather_rows(d, i, shapes))(flat, idx)
+    assert set(got) == set(full)
+    for k, v in full.items():
+        assert got[k].dtype == v.dtype
+        assert got[k].shape == idx_shape + v.shape[1:]
+        np.testing.assert_array_equal(np.asarray(got[k]), v[idx])
 
 
 def test_resident_loop_with_accum(setup, mesh8):
     """Scan-of-scan over the resident feed: (window, accum, batch) indices."""
-    from tpu_dp.parallel.sharding import replicated_sharding, shard_batch
+    from tpu_dp.data.pipeline import DataPipeline
     from tpu_dp.train import constant_lr
     from tpu_dp.train.step import make_multi_step_resident
 
     model, opt, state = setup
     ds = make_synthetic(64, 10, seed=8, name="res")
-    data = shard_batch({"image": ds.images, "label": ds.labels}, mesh8,
-                       spec=replicated_sharding(mesh8))
+    pipe = DataPipeline(ds, batch_size=16, mesh=mesh8, accum_steps=2)
+    data = pipe.resident_data()
 
     ref = make_train_step(model, opt, mesh8, constant_lr(0.05), accum_steps=2)
     s_ref = _copy(state)
@@ -348,7 +394,8 @@ def test_resident_loop_with_accum(setup, mesh8):
         })
 
     rloop = make_multi_step_resident(model, opt, mesh8, constant_lr(0.05),
-                                     num_steps=2, accum_steps=2)
+                                     num_steps=2, accum_steps=2,
+                                     sample_shapes=pipe.sample_shapes)
     idx = np.arange(64, dtype=np.int32).reshape(2, 2, 16)
     s_res, m = rloop(_copy(state), data, idx)
 

@@ -129,3 +129,23 @@ def test_scopes_leave_the_collective_fingerprint_alone(tmp_path, monkeypatch,
     text, _, _ = hlo.lower_and_compile(fn, args)
     assert not re.search(r"tpu_dp\.\w+", text)
     assert hlo.schedule_digest(hlo.collect_ops(text)) == with_scopes
+
+
+def test_the_reshape_after_the_gather_is_of_its_phase(tmp_path):
+    """The resident feed gathers flat rows and gives a row its shape back
+    (`train/step.py:gather_rows`): that reshape belongs to `tpu_dp.gather`
+    and to no other phase. Read in the lowered program, since a compiler
+    may fold a reshape away (the CPU's makes it a bitcast of the gather)."""
+    fn, args = _step_program(tmp_path, "resnet18", "on")
+    text = fn.lower(*args).as_text(debug_info=True)
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    restored = re.findall(
+        r"stablehlo\.reshape .*\(tensor<16x3072xui8>\) -> "
+        r"tensor<16x32x32x3xui8> loc\((#loc\d+)\)", text)
+    assert len(restored) == 1
+    name = locs[restored[0]]
+    assert name == "tpu_dp.gather/reshape"
+    # and nothing else reshapes under the gather's name: rows of rank 1 (the
+    # labels) are gathered as they are
+    assert sum(n.startswith("tpu_dp.gather/reshape")
+               for n in locs.values()) == 1

@@ -5,10 +5,12 @@ misaligned slice, an over-budget VMEM tile or an op Mosaic refuses; the
 TPU's compiler can, and it is installed here. Each case lowers one kernel
 at a shape `chip_smoke.py` runs on the chip, compiles it for one device of
 a described ``v5e:2x2`` topology, and asserts the compiled program holds a
-``tpu_custom_call``. The last test compiles the step's random crop
-(`tpu_dp.data.augment`) the same way and asserts the opposite kind of
-thing: that the TPU's compiler makes no loop of it, which only that
-compiler can say. Nothing runs, so nothing here is a result or a time.
+``tpu_custom_call``. Two tests ask the opposite kind of thing of the
+step's own code, which only the TPU's compiler can say: that it makes no
+loop of the random crop (`tpu_dp.data.augment`), and that it copies no
+array the size of the data set to gather a batch from the resident feed
+(`tpu_dp.train.step.gather_rows`). Nothing runs, so nothing here is a
+result or a time.
 
 One file and in-process on purpose: only one process at a time may load
 the TPU's library, so the topology is described inside a module-scoped
@@ -19,6 +21,7 @@ no test starts a child.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -152,6 +155,58 @@ def test_random_crop_is_no_loop_for_v5e(one_chip, compiled_kernels, fn,
     assert " select(" in text
     for kind in ("while", "gather", "dynamic-slice", "dynamic-update-slice"):
         assert f" {kind}(" not in text, kind
+
+
+def _first_layer_of(gather):
+    """A batch out of the resident data set as the images go: the gather,
+    the step's normalisation, one 3x3 convolution in bfloat16."""
+    from tpu_dp.train.step import _maybe_normalize
+
+    def fn(data, idx, w):
+        x = _maybe_normalize(gather(data, idx)).astype(jnp.bfloat16)
+        return jax.lax.conv_general_dilated(
+            x, w, (1, 1), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"))
+    return fn
+
+
+def _moves_over(text: str, rows: int) -> list[str]:
+    """The `copy` and `transpose` ops of a compiled program that have
+    ``rows`` among the dimensions of their result."""
+    return [m.group(0) for m in re.finditer(
+        r"= \w+\[([\d,]*)\]\S* (?:copy|transpose)\(", text)
+        if str(rows) in m.group(1).split(",")]
+
+
+@pytest.mark.parametrize("rows,batch", [(131072, 4096), (32768, 1024)])
+def test_resident_gather_copies_no_data_set_for_v5e(one_chip,
+                                                    compiled_kernels, rows,
+                                                    batch):
+    """A ``uint8[N, 32, 32, 3]`` argument's default layout on the v5e is
+    N-minor, and to gather rows along the minor dimension the compiler
+    first relays the whole array out, on every call: ``copy(%data)`` over
+    ``u8[131072,32,32,3]``, 10 ms of a 129 ms step (PERF.md §6, PR 29).
+    Staged with its rows flat (`DataPipeline.resident_data`) the gather
+    reads the argument in place. The 4-D staging stays here as the
+    yardstick: the same question finds its copy."""
+    from tpu_dp.train.step import gather_rows
+
+    sample = (32, 32, 3)
+    idx = jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((3, 3, 3, 64), jnp.bfloat16, sharding=one_chip)
+
+    def compiled_text(gather, data_shape):
+        data = jax.ShapeDtypeStruct(data_shape, jnp.uint8, sharding=one_chip)
+        return jax.jit(_first_layer_of(gather)).lower(
+            data, idx, w).compile().as_text()
+
+    flat = compiled_text(
+        lambda data, i: gather_rows({"image": data}, i,
+                                    {"image": sample})["image"],
+        (rows, 3072))
+    assert f"u8[{rows},3072]" in flat
+    assert _moves_over(flat, rows) == []
+    four_d = compiled_text(lambda data, i: data[i], (rows, *sample))
+    assert _moves_over(four_d, rows) != []
 
 
 def _flash_attention_fwd_bwd(q, k, v):

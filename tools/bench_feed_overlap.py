@@ -151,7 +151,8 @@ def _run(args) -> None:
         pipe = make_pipe(0)
         rdata = pipe.resident_data()
         rloop = make_multi_step_resident(model, opt, mesh, sched,
-                                         num_steps=args.window)
+                                         num_steps=args.window,
+                                         sample_shapes=pipe.sample_shapes)
         run("resident", 0, pipe,
             lambda state, idx: rloop(state, rdata, idx))
 

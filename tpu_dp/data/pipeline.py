@@ -257,18 +257,36 @@ class DataPipeline:
         """Host-side size of the dataset arrays (resident-staging budget)."""
         return sum(v.nbytes for v in self.dataset.arrays.values())
 
+    @property
+    def sample_shapes(self) -> dict[str, tuple[int, ...]]:
+        """A row's shape by array: what the resident step restores after
+        its gather (`resident_data` stages the rows flat)."""
+        return {k: v.shape[1:] for k, v in self.dataset.arrays.items()}
+
     def resident_data(self):
-        """Stage the WHOLE dataset on device, replicated over the mesh.
+        """Stage the WHOLE dataset on device, replicated over the mesh,
+        every row flat: ``(N, ...)`` of rank over 2 goes up as
+        ``(N, prod(...))``, rank 1 and 2 as they are.
 
         One transfer per run (CIFAR-10 train: 150 MB uint8); afterwards the
         resident path feeds the compiled window only indices
         (`index_windows`). Every process holds the full dataset (the loader
         materializes it everywhere), so replicated assembly is uniform.
+
+        Flat, because the step gathers rows of it and the chip's default
+        layout of a ``uint8[N, 32, 32, 3]`` argument puts N on the minor
+        (lane) dimension: to gather along it the v5e compiler first relays
+        the whole data set out, on every call (10 ms a step for 131,072
+        images, PERF.md §6, PR 29). ``uint8[N, 3072]`` is row-major there
+        and the gather reads it in place; ``(N, 32, 96)`` is N-minor again.
+        The step puts the row's shape (`sample_shapes`) back after the
+        gather (`tpu_dp.train.step.gather_rows`).
         """
         from tpu_dp.parallel.sharding import replicated_sharding
 
-        return shard_batch(dict(self.dataset.arrays), self.mesh,
-                           spec=replicated_sharding(self.mesh))
+        flat = {k: v.reshape(len(v), -1) if v.ndim > 2 else v
+                for k, v in self.dataset.arrays.items()}
+        return shard_batch(flat, self.mesh, spec=replicated_sharding(self.mesh))
 
     def index_windows(self, k: int, skip_steps: int = 0):
         """Yield ``(n_steps, idx_device)`` windows of dataset indices.

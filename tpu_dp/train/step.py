@@ -764,12 +764,27 @@ def make_multi_step(
     )
 
 
+def gather_rows(data, idx_step, sample_shapes):
+    """One step's batch from the resident data set: rows ``idx_step`` of
+    every array, then each row's own shape back (`DataPipeline.
+    resident_data` stages rows flat, and says why). The reshape comes
+    after the gather and inside its phase: the gathered batch is the
+    array a 4-D staging gave, bit for bit, and the data set is never
+    reshaped on the device."""
+    with jax.named_scope("tpu_dp.gather"):
+        return {
+            k: x[idx_step].reshape(*idx_step.shape, *sample_shapes[k])
+            for k, x in data.items()
+        }
+
+
 def make_multi_step_resident(
     model,
     optimizer: Optimizer,
     mesh: Mesh,
     schedule: Schedule,
     num_steps: int,
+    sample_shapes: dict[str, tuple[int, ...]],
     use_pallas_xent: bool = False,
     augment_fn: Callable | None = None,
     accum_steps: int = 1,
@@ -795,9 +810,25 @@ def make_multi_step_resident(
     unchanged and trajectory-identical (equivalence-tested).
 
     Returns ``loop(state, data, idx) -> (new_state, stacked_metrics)``:
-    ``data`` leaves are (N, ...) device-resident (replicated; uint8 images
-    fine — normalization is in-body), ``idx`` is int32 with the window axis
-    in front. Only ``state`` is donated — ``data`` must survive the call.
+    ``data`` is `DataPipeline.resident_data()`: a dict of device-resident
+    arrays (replicated; uint8 images fine — normalization is in-body) with
+    the rows flat, ``(N,)`` or ``(N, row)``; ``idx`` is int32 with the
+    window axis in front. Only ``state`` is donated — ``data`` must survive
+    the call.
+
+    ``sample_shapes`` (`DataPipeline.sample_shapes`) is each array's row
+    shape, static, which `gather_rows` restores after the gather. The
+    rows are staged flat because of what the v5e compiler makes of a
+    gather from ``uint8[N, 32, 32, 3]``, whose default layout there is
+    N-minor (nothing in the mathematics asks for it; do not stage 4-D)::
+
+        %copy.3 = u8[131072,32,32,3]{2,1,3,0:T(8,128)(4,1)} copy(%data.1)  # the whole data set, every call
+        %fusion = u8[4096,32,32,3]{2,1,3,0:...} fusion(%copy.3, %idx)       # the gather
+        %copy.4 = u8[4096,32,32,3]{0,2,3,1:...} copy(%fusion)               # the batch, back to batch-minor
+
+    From ``uint8[N, 3072]`` the gather fusion reads the argument in place
+    and one relayout of the batch is left (`tests/test_tpu_compile.py`
+    holds both forms; PERF.md §6, PR 29, the times).
 
     ``update_sharding="sharded"`` composes the resident feed with the
     sharded weight update: the indices shard over ``data`` (each replica
@@ -835,9 +866,7 @@ def make_multi_step_resident(
         )
 
         def indexed_body(st, idx_step):
-            with jax.named_scope("tpu_dp.gather"):
-                mb = jax.tree_util.tree_map(lambda x: x[idx_step], data)
-            return step_body(st, mb)
+            return step_body(st, gather_rows(data, idx_step, sample_shapes))
 
         # length pins the window size: a mis-shaped idx errors at trace
         # time instead of silently running a different number of steps.

@@ -1603,7 +1603,8 @@ class Trainer:
             _costs.registry.alias(f"resident_loop[w{n}]", "train_step")
             loop = self._guarded(f"resident_loop[w{n}]", make_multi_step_resident(
                 self.model, self.optimizer, self.mesh, self.schedule,
-                num_steps=n, use_pallas_xent=self.cfg.train.pallas_xent,
+                num_steps=n, sample_shapes=self.train_pipe.sample_shapes,
+                use_pallas_xent=self.cfg.train.pallas_xent,
                 augment_fn=self._augment_fn,
                 accum_steps=self.cfg.optim.grad_accum_steps,
                 update_sharding=self.update_sharding,
