@@ -30,7 +30,7 @@ Modes:
     python bench.py --platform cpu  # smoke-test the harness off-TPU (not archived as headline)
 
 Measurement: one dispatch of the device-side scanned training loop
-(`make_multi_step`): N steps compiled into a single XLA program cycling a
+(`make_train_step(feed="window")`): N steps compiled into a single XLA program cycling a
 4-slot pool of pre-staged device-resident synthetic batches, so neither the
 (single-core) host nor per-step launch latency can bottleneck the
 measurement. One full window runs first as compile+warmup, then a second
@@ -193,24 +193,21 @@ def compile_with_flops(jitted, *eg_args):
 def _make_step(model, opt, mesh, sched, use_pallas, update_sharding,
                sentinel=False, collective_dtype=None, quant_block=None,
                bucket_mb=0.0):
-    """The production per-step program for the requested update mode:
-    GSPMD (`make_train_step`) for replicated, explicit-collectives
-    `make_train_step_shard_map` for the sharded weight update (optionally
-    with the bf16/int8 compressed wire — `--collective-dtype` — and/or
-    the bucketed overlap schedule — `--bucket-mb`).
+    """The production per-step program for the requested update mode
+    (`make_train_step`): GSPMD's inferred all-reduce for replicated,
+    explicit collectives for the sharded weight update (optionally with
+    the bf16/int8 compressed wire — `--collective-dtype` — and/or the
+    bucketed overlap schedule — `--bucket-mb`).
     ``sentinel=True`` builds the guardrail variant (`--guard-overhead`)."""
-    from tpu_dp.train import make_train_step, make_train_step_shard_map
+    from tpu_dp.train import make_train_step
 
-    if update_sharding == "sharded":
-        return make_train_step_shard_map(
-            model, opt, mesh, sched, use_pallas_xent=use_pallas,
-            update_sharding=update_sharding, sentinel=sentinel,
-            collective_dtype=collective_dtype or None,
-            quant_block_size=quant_block,
-            bucket_mb=bucket_mb,
-        )
-    return make_train_step(model, opt, mesh, sched,
-                           use_pallas_xent=use_pallas, sentinel=sentinel)
+    return make_train_step(
+        model, opt, mesh, sched, use_pallas_xent=use_pallas,
+        update_sharding=update_sharding, sentinel=sentinel,
+        collective_dtype=collective_dtype or None,
+        quant_block_size=quant_block,
+        bucket_mb=bucket_mb,
+    )
 
 
 def measure_point(cfg: dict) -> dict:
@@ -235,7 +232,7 @@ def measure_point(cfg: dict) -> dict:
         batch_sharding, scan_batch_sharding, shard_batch,
     )
     from tpu_dp.train import (
-        SGD, cosine_lr, create_train_state, make_multi_step,
+        SGD, cosine_lr, create_train_state, make_train_step,
     )
 
     from tpu_dp.parallel import bucketing as bucketing_mod
@@ -291,7 +288,8 @@ def measure_point(cfg: dict) -> dict:
     # Timing fence: `jax.block_until_ready` on the window's metrics (JAX
     # returns before the device finishes).
     if window > 1:
-        loop = make_multi_step(model, opt, mesh, sched, num_steps=window,
+        loop = make_train_step(model, opt, mesh, sched, feed="window",
+                               num_steps=window,
                                use_pallas_xent=use_pallas,
                                update_sharding=update_sharding,
                                collective_dtype=collective_dtype or None,

@@ -51,7 +51,7 @@ def test_chip_registry_is_the_single_source():
     )
     for sub, spec in chips.CHIP_SPECS:
         assert costs.peak_flops(sub) == chips.peak_flops(sub)
-    # The historical v5e numbers profile_breakdown hardcoded.
+    # The v5e's published peaks.
     v5e = chips.chip_spec("TPU v5 lite")
     assert v5e is not None
     assert v5e.peak_flops == 197e12
@@ -61,17 +61,6 @@ def test_chip_registry_is_the_single_source():
     assert chips.chip_spec("tpu v5").name == "v5p"
     assert chips.chip_spec("unknown accelerator") is None
     assert chips.ici_gbs("v2") is None  # unknown field: absent, never 0
-
-
-def test_profile_breakdown_consumes_the_registry():
-    import tools.profile_breakdown as pb
-
-    # The drift-prone local constants are gone; the tool reads chips.
-    assert not hasattr(pb, "V5E_PEAK_TFLOPS")
-    assert not hasattr(pb, "V5E_PEAK_HBM_GBS")
-    from tpu_dp.obs import chips
-
-    assert pb._V5E is chips.chip_spec("v5e")
 
 
 def test_collective_kinds_pinned_to_analyzer():

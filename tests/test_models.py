@@ -240,7 +240,6 @@ class TestFusedResNet:
         from tpu_dp.parallel import dist
         from tpu_dp.train import (
             SGD, constant_lr, create_train_state, make_train_step,
-            make_train_step_shard_map,
         )
 
         opt = SGD(momentum=0.9)
@@ -252,7 +251,8 @@ class TestFusedResNet:
                          fused_stages=(0,), fused_block_b=2,
                          axis_name=dist.DATA_AXIS)
         sf = create_train_state(mf, jax.random.PRNGKey(0), x0, opt)
-        _, m_sm = make_train_step_shard_map(mf, opt, mesh8, constant_lr(0.1))(
+        _, m_sm = make_train_step(mf, opt, mesh8, constant_lr(0.1),
+                                  explicit=True)(
             sf, dict(batch))
 
         mg = build_model("resnet18", num_classes=10, dtype=jnp.bfloat16,

@@ -376,7 +376,6 @@ from tpu_dp.data.pipeline import DataPipeline
 from tpu_dp.models import Net
 from tpu_dp.parallel import dist
 from tpu_dp.train import SGD, constant_lr, create_train_state
-from tpu_dp.train.step import make_multi_step, make_multi_step_resident
 
 mesh = dist.data_mesh()
 ds = make_synthetic(64, 10, seed=0, name="mpres")  # identical on both ranks
@@ -391,8 +390,8 @@ pipe = DataPipeline(ds, batch_size=8, mesh=mesh, shuffle=True, seed=7,
 # Resident: dataset assembled replicated from both processes, windows fed
 # by process-locally assembled sharded indices.
 rdata = pipe.resident_data()
-rloop = make_multi_step_resident(model, opt, mesh, constant_lr(0.05),
-                                 num_steps=2,
+rloop = make_train_step(model, opt, mesh, constant_lr(0.05),
+                        feed="resident", num_steps=2,
                                  sample_shapes=pipe.sample_shapes)
 pipe.set_epoch(0)
 state = fresh_state()
@@ -402,7 +401,8 @@ for n, idx in pipe.index_windows(2):   # 4 steps -> 2 windows of 2
 res_loss = float(m["loss"][-1])
 
 # Streaming control: same sampler order, same body.
-sloop = make_multi_step(model, opt, mesh, constant_lr(0.05), num_steps=2)
+sloop = make_train_step(model, opt, mesh, constant_lr(0.05),
+                        feed="window", num_steps=2)
 pipe.set_epoch(0)
 sstate = fresh_state()
 for n, item in pipe.windows(2):

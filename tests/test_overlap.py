@@ -54,7 +54,7 @@ from tpu_dp.train import (
     SGD,
     constant_lr,
     create_train_state,
-    make_train_step_shard_map,
+    make_train_step,
     shard_optimizer,
 )
 
@@ -351,14 +351,13 @@ def _states(bucket_mb=0.05):
 
 def test_bucketed_multi_step_window_tracks_f32(mesh8):
     """Bucketing composes with the windowed device-side loop."""
-    from tpu_dp.train import make_multi_step
-
     model, opt, sopt, state_r, state_s, _ = _states()
     K = 4
-    loop_r = make_multi_step(model, opt, mesh8, constant_lr(0.05),
-                             num_steps=K)
-    loop_b = make_multi_step(model, sopt, mesh8, constant_lr(0.05),
-                             num_steps=K, update_sharding="sharded",
+    loop_r = make_train_step(model, opt, mesh8, constant_lr(0.05),
+                             feed="window", num_steps=K)
+    loop_b = make_train_step(model, sopt, mesh8, constant_lr(0.05),
+                             feed="window", num_steps=K,
+                             update_sharding="sharded",
                              bucket_mb=0.05)
     batches = [_make_batch(100 + i) for i in range(K)]
     pool = {"image": np.stack([b["image"] for b in batches]),
@@ -372,26 +371,24 @@ def test_bucketed_multi_step_window_tracks_f32(mesh8):
 
 
 def test_factory_and_config_validation():
-    from tpu_dp.train import make_multi_step
-    from tpu_dp.train.step import make_multi_step_resident
 
     model, opt, sopt, *_ = _states()
     mesh = dist.data_mesh()
     with pytest.raises(ValueError, match="bucket_mb"):
-        make_train_step_shard_map(model, opt, mesh, constant_lr(0.1),
-                                  bucket_mb=1.0)  # replicated mode
+        make_train_step(model, opt, mesh, constant_lr(0.1),
+                        bucket_mb=1.0, explicit=True)  # replicated mode
     with pytest.raises(ValueError, match="bucket_mb"):
-        make_train_step_shard_map(model, sopt, mesh, constant_lr(0.1),
-                                  update_sharding="sharded", bucket_mb=-1)
+        make_train_step(model, sopt, mesh, constant_lr(0.1),
+                        update_sharding="sharded", bucket_mb=-1)
     # The windowed factories refuse too — a silently-dropped bucket_mb
     # would leave the caller believing the overlap schedule is armed.
     with pytest.raises(ValueError, match="bucket_mb"):
-        make_multi_step(model, opt, mesh, constant_lr(0.1), num_steps=2,
+        make_train_step(model, opt, mesh, constant_lr(0.1), feed="window", num_steps=2,
                         bucket_mb=1.0)
     with pytest.raises(ValueError, match="bucket_mb"):
-        make_multi_step_resident(model, opt, mesh, constant_lr(0.1),
-                                 num_steps=2, sample_shapes={},
-                                 bucket_mb=1.0)
+        make_train_step(model, opt, mesh, constant_lr(0.1),
+                        feed="resident", num_steps=2, sample_shapes={},
+                        bucket_mb=1.0)
     from tpu_dp.config import Config
     from tpu_dp.train.trainer import Trainer
 
@@ -428,7 +425,7 @@ def _bucketed_program():
     shares it)."""
     model, opt, sopt, state_r, state_s, _ = _states()
     mesh = dist.data_mesh()
-    step = make_train_step_shard_map(
+    step = make_train_step(
         model, sopt, mesh, constant_lr(0.05), update_sharding="sharded",
         bucket_mb=0.05)
     plan = bucketing.plan_for_tree(
@@ -644,7 +641,7 @@ def test_real_run_residuals_survive_bucket_resize(tmp_path, mesh8):
     from tpu_dp.checkpoint import load_checkpoint, save_checkpoint
 
     model, opt, sopt, state_r, state_s, state_q = _states(bucket_mb=0.05)
-    step = make_train_step_shard_map(
+    step = make_train_step(
         model, sopt, mesh8, constant_lr(0.05), update_sharding="sharded",
         collective_dtype="int8", bucket_mb=0.05)
     s = _copy(state_q)
@@ -667,7 +664,7 @@ def test_real_run_residuals_survive_bucket_resize(tmp_path, mesh8):
                                    err_msg=k)
     for k in set(after) - set(before):
         np.testing.assert_array_equal(after[k], 0.0)
-    step2 = make_train_step_shard_map(
+    step2 = make_train_step(
         model, sopt, mesh8, constant_lr(0.05), update_sharding="sharded",
         collective_dtype="int8", bucket_mb=0.01)
     s2, m = step2(_copy(restored), _make_batch(9))

@@ -91,7 +91,6 @@ def test_shard_map_step_with_pallas_xent(mesh8):
     from tpu_dp.models import Net
     from tpu_dp.train import (
         SGD, constant_lr, create_train_state, make_train_step,
-        make_train_step_shard_map,
     )
 
     opt = SGD(momentum=0.9)
@@ -101,8 +100,9 @@ def test_shard_map_step_with_pallas_xent(mesh8):
 
     m_sm = Net()
     s_sm = create_train_state(m_sm, jax.random.PRNGKey(0), x0, opt)
-    _, met_sm = make_train_step_shard_map(
-        m_sm, opt, mesh8, constant_lr(0.1), use_pallas_xent=True)(
+    _, met_sm = make_train_step(
+        m_sm, opt, mesh8, constant_lr(0.1), use_pallas_xent=True,
+        explicit=True)(
         s_sm, dict(batch))
 
     m_g = Net()

@@ -52,7 +52,7 @@ from tpu_dp.train import (
     SGD,
     constant_lr,
     create_train_state,
-    make_train_step_shard_map,
+    make_train_step,
     shard_optimizer,
 )
 
@@ -350,8 +350,9 @@ def test_wire_dtype_parity_harness(mesh8, wire, bucket_mb, bitwise, atol):
         state_q = state_q.replace(residuals=quant.init_residuals(
             state_q.params, WORLD, BLOCK,
             bucket_bytes=bucketing.parse_bucket_mb(bucket_mb)))
-    step_r = make_train_step_shard_map(model, opt, mesh8, constant_lr(0.05))
-    step_w = make_train_step_shard_map(
+    step_r = make_train_step(model, opt, mesh8, constant_lr(0.05),
+                             explicit=True)
+    step_w = make_train_step(
         model, sopt, mesh8, constant_lr(0.05), update_sharding="sharded",
         collective_dtype=wire or None, bucket_mb=bucket_mb,
     )
@@ -411,8 +412,8 @@ def test_error_feedback_telescopes_over_24_steps(mesh8, bucket_mb):
         state_q = state_q.replace(residuals=quant.init_residuals(
             state_q.params, WORLD, BLOCK,
             bucket_bytes=bucketing.parse_bucket_mb(bucket_mb)))
-    probe = make_train_step_shard_map(model, SGD(momentum=0.0), mesh8,
-                                      constant_lr(1.0))
+    probe = make_train_step(model, SGD(momentum=0.0), mesh8,
+                            constant_lr(1.0), explicit=True)
 
     def true_grad(params, batch):
         before = _flat(params)
@@ -420,7 +421,7 @@ def test_error_feedback_telescopes_over_24_steps(mesh8, bucket_mb):
         return before - _flat(after.params)
 
     def run(error_feedback):
-        step = make_train_step_shard_map(
+        step = make_train_step(
             model, sopt, mesh8, constant_lr(lr), update_sharding="sharded",
             collective_dtype="int8", bucket_mb=bucket_mb,
             quant_error_feedback=error_feedback)
@@ -457,14 +458,13 @@ def test_error_feedback_telescopes_over_24_steps(mesh8, bucket_mb):
 
 def test_int8_multi_step_window_tracks_f32(mesh8):
     """The quantized wire composes with the windowed device-side loop."""
-    from tpu_dp.train import make_multi_step
-
     model, opt, sopt, state_r, state_q = _states()
     K = 4
-    loop_r = make_multi_step(model, opt, mesh8, constant_lr(0.05),
-                             num_steps=K)
-    loop_q = make_multi_step(model, sopt, mesh8, constant_lr(0.05),
-                             num_steps=K, update_sharding="sharded",
+    loop_r = make_train_step(model, opt, mesh8, constant_lr(0.05),
+                             feed="window", num_steps=K)
+    loop_q = make_train_step(model, sopt, mesh8, constant_lr(0.05),
+                             feed="window", num_steps=K,
+                             update_sharding="sharded",
                              collective_dtype="int8")
     batches = [_make_batch(100 + i) for i in range(K)]
     pool = {
@@ -484,9 +484,9 @@ def test_residual_memory_is_flat_sharded(mesh8):
     """Residuals live like the opt state: per-replica addressable shard =
     one [1, qpad] row per leaf — world-sharded, never replicated."""
     model, _, sopt, _, state_q = _states()
-    step = make_train_step_shard_map(model, sopt, mesh8, constant_lr(0.05),
-                                     update_sharding="sharded",
-                                     collective_dtype="int8")
+    step = make_train_step(model, sopt, mesh8, constant_lr(0.05),
+                           update_sharding="sharded",
+                           collective_dtype="int8")
     new_state, _ = step(_copy(state_q), _make_batch(0))
     for key, leaf in new_state.residuals.items():
         shards = leaf.addressable_shards
@@ -498,14 +498,14 @@ def test_factory_validation():
     mesh = dist.data_mesh()
     sopt = shard_optimizer(SGD(momentum=0.9), WORLD)
     with pytest.raises(ValueError, match="quant_block_size"):
-        make_train_step_shard_map(Net(), sopt, mesh, constant_lr(0.05),
-                                  update_sharding="sharded",
-                                  collective_dtype="int8",
-                                  quant_block_size=0)
+        make_train_step(Net(), sopt, mesh, constant_lr(0.05),
+                        update_sharding="sharded",
+                        collective_dtype="int8",
+                        quant_block_size=0)
     with pytest.raises(ValueError, match="collective_dtype"):
-        make_train_step_shard_map(Net(), SGD(momentum=0.9), mesh,
-                                  constant_lr(0.05),
-                                  collective_dtype="int8")
+        make_train_step(Net(), SGD(momentum=0.9), mesh,
+                        constant_lr(0.05),
+                        collective_dtype="int8", explicit=True)
 
 
 # --------------------------------------------------------------------------
@@ -522,7 +522,7 @@ def test_sentinel_reads_dequantized_health_and_skips_nan(mesh8):
     from tpu_dp.train.step import default_guard_in
 
     model, _, sopt, _, state_q = _states()
-    step = make_train_step_shard_map(
+    step = make_train_step(
         model, sopt, mesh8, constant_lr(0.05), update_sharding="sharded",
         collective_dtype="int8", sentinel=True,
     )

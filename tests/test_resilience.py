@@ -447,7 +447,7 @@ def test_sharded_opt_state_snapshot_roundtrip(tmp_path):
     into a fresh sharded target is bitwise-complete."""
     from tpu_dp.models import Net
     from tpu_dp.train import SGD, create_train_state, shard_optimizer
-    from tpu_dp.train.step import make_train_step_shard_map
+    from tpu_dp.train.step import make_train_step
     from tpu_dp.train.schedule import constant_lr
     from tpu_dp.parallel import dist
     from tpu_dp.data.cifar import make_synthetic, normalize
@@ -458,8 +458,8 @@ def test_sharded_opt_state_snapshot_roundtrip(tmp_path):
         Net(), jax.random.PRNGKey(0), np.zeros((1, 32, 32, 3), np.float32),
         sopt,
     )
-    step = make_train_step_shard_map(Net(), sopt, mesh, constant_lr(0.05),
-                                     update_sharding="sharded")
+    step = make_train_step(Net(), sopt, mesh, constant_lr(0.05),
+                           update_sharding="sharded")
     ds = make_synthetic(16, 10, seed=0, name="snap")
     # One real step so the momentum shards are nonzero and device-committed
     # in their sharded layout.

@@ -28,7 +28,6 @@ from tpu_dp.train import (
     constant_lr,
     create_train_state,
     make_train_step,
-    make_train_step_shard_map,
     shard_optimizer,
 )
 from tpu_dp.train.hooks import StepHook
@@ -99,8 +98,8 @@ def test_adamw_sharded_is_adamw_replicated_and_the_checkpoint_carries_it(
     state_r = create_train_state(model, rng, sample, opt)
     state_s = create_train_state(model, rng, sample, sopt)
     step_r = make_train_step(model, opt, mesh8, constant_lr(1e-2))
-    step_s = make_train_step_shard_map(model, sopt, mesh8, constant_lr(1e-2),
-                                       update_sharding="sharded")
+    step_s = make_train_step(model, sopt, mesh8, constant_lr(1e-2),
+                             update_sharding="sharded")
     for k in range(3):
         batch = _image_batch(k, 64)
         state_r, m_r = step_r(state_r, batch)

@@ -32,7 +32,6 @@ from tpu_dp.train import (
     constant_lr,
     create_train_state,
     make_train_step,
-    make_train_step_shard_map,
     shard_optimizer,
 )
 
@@ -186,11 +185,11 @@ def test_sharded_update_bitwise_matches_replicated(mesh8, accum_steps):
     params AND momentum bitwise-identical over a multi-step trajectory,
     accum ∈ {1,4}, with non-divisible leaf sizes (Net on 8 devices)."""
     model, opt, sopt, state_r, state_s = _states()
-    step_r = make_train_step_shard_map(model, opt, mesh8, constant_lr(0.05),
-                                       accum_steps=accum_steps)
-    step_s = make_train_step_shard_map(model, sopt, mesh8, constant_lr(0.05),
-                                       accum_steps=accum_steps,
-                                       update_sharding="sharded")
+    step_r = make_train_step(model, opt, mesh8, constant_lr(0.05),
+                             accum_steps=accum_steps, explicit=True)
+    step_s = make_train_step(model, sopt, mesh8, constant_lr(0.05),
+                             accum_steps=accum_steps,
+                             update_sharding="sharded")
     sr, ss = _copy(state_r), _copy(state_s)
     n = 16 * accum_steps
     for i in range(3):
@@ -229,9 +228,10 @@ def test_sharded_weight_decay_and_exclusion_bitwise(mesh8):
     rng = jax.random.PRNGKey(0)
     state_r = create_train_state(model, rng, _sample(), opt)
     state_s = create_train_state(model, rng, _sample(), sopt)
-    step_r = make_train_step_shard_map(model, opt, mesh8, constant_lr(0.05))
-    step_s = make_train_step_shard_map(model, sopt, mesh8, constant_lr(0.05),
-                                       update_sharding="sharded")
+    step_r = make_train_step(model, opt, mesh8, constant_lr(0.05),
+                             explicit=True)
+    step_s = make_train_step(model, sopt, mesh8, constant_lr(0.05),
+                             update_sharding="sharded")
     sr, ss = _copy(state_r), _copy(state_s)
     for i in range(2):
         batch = _make_batch(i, 16)
@@ -253,8 +253,8 @@ def test_sharded_matches_gspmd_path(mesh8):
     two ends of the implementation spectrum agree bitwise for f32 SGD."""
     model, opt, sopt, state_r, state_s = _states()
     step_g = make_train_step(model, opt, mesh8, constant_lr(0.05))
-    step_s = make_train_step_shard_map(model, sopt, mesh8, constant_lr(0.05),
-                                       update_sharding="sharded")
+    step_s = make_train_step(model, sopt, mesh8, constant_lr(0.05),
+                             update_sharding="sharded")
     sg, ss = _copy(state_r), _copy(state_s)
     for i in range(3):
         batch = _make_batch(i, 16)
@@ -282,8 +282,8 @@ def test_sharded_opt_state_memory_is_one_over_world(mesh8):
 
     # Laid onto the mesh by the step's in_shardings, each device addresses
     # exactly its shard.
-    step_s = make_train_step_shard_map(Net(), sopt, mesh8, constant_lr(0.05),
-                                       update_sharding="sharded")
+    step_s = make_train_step(Net(), sopt, mesh8, constant_lr(0.05),
+                             update_sharding="sharded")
     new_state, _ = step_s(_copy(state_s), _make_batch(0, 16))
     for r, leaf in zip(repl_leaves,
                        jax.tree_util.tree_leaves(new_state.opt_state)):
@@ -298,10 +298,11 @@ def test_bf16_collective_dtype_close_to_f32(mesh8):
     trajectory within bf16 tolerance (and is NOT bitwise — it really ran
     through the compressed path)."""
     model, opt, sopt, state_r, state_s = _states()
-    step_r = make_train_step_shard_map(model, opt, mesh8, constant_lr(0.05))
-    step_b = make_train_step_shard_map(model, sopt, mesh8, constant_lr(0.05),
-                                       update_sharding="sharded",
-                                       collective_dtype="bf16")
+    step_r = make_train_step(model, opt, mesh8, constant_lr(0.05),
+                             explicit=True)
+    step_b = make_train_step(model, sopt, mesh8, constant_lr(0.05),
+                             update_sharding="sharded",
+                             collective_dtype="bf16")
     sr, sb = _copy(state_r), _copy(state_s)
     for i in range(2):
         batch = _make_batch(i, 16)
@@ -326,13 +327,13 @@ def test_sharded_multi_step_matches_replicated_multi_step(mesh8):
     scan-vs-host-loop comparison itself is only ulp-close — XLA fuses scan
     bodies differently — and is already covered for the shared body by
     test_step.test_scanned_multi_step_matches_host_loop)."""
-    from tpu_dp.train import make_multi_step
-
     model, opt, sopt, state_r, state_s = _states()
     K, n = 4, 16
     sched = constant_lr(0.05)
-    loop_r = make_multi_step(model, opt, mesh8, sched, num_steps=K)
-    loop_s = make_multi_step(model, sopt, mesh8, sched, num_steps=K,
+    loop_r = make_train_step(model, opt, mesh8, sched,
+                             feed="window", num_steps=K)
+    loop_s = make_train_step(model, sopt, mesh8, sched,
+                             feed="window", num_steps=K,
                              update_sharding="sharded")
     batches = [_make_batch(100 + i, n) for i in range(K)]
     pool = {
@@ -358,7 +359,6 @@ def test_sharded_resident_loop_matches_replicated(mesh8):
     """Device-resident feed + sharded update ≡ resident feed + replicated
     update: the feed redesign and the update redesign compose."""
     from tpu_dp.data.pipeline import DataPipeline
-    from tpu_dp.train.step import make_multi_step_resident
 
     model, opt, sopt, state_r, state_s = _states()
     K, n = 3, 16
@@ -368,11 +368,13 @@ def test_sharded_resident_loop_matches_replicated(mesh8):
     data = pipe.resident_data()
     idx = np.arange(K * n, dtype=np.int32).reshape(K, n)
 
-    loop_r = make_multi_step_resident(model, opt, mesh8, sched, num_steps=K,
-                                      sample_shapes=pipe.sample_shapes)
-    loop_s = make_multi_step_resident(model, sopt, mesh8, sched, num_steps=K,
-                                      sample_shapes=pipe.sample_shapes,
-                                      update_sharding="sharded")
+    loop_r = make_train_step(model, opt, mesh8, sched,
+                             feed="resident", num_steps=K,
+                             sample_shapes=pipe.sample_shapes)
+    loop_s = make_train_step(model, sopt, mesh8, sched,
+                             feed="resident", num_steps=K,
+                             sample_shapes=pipe.sample_shapes,
+                             update_sharding="sharded")
     sr, _ = loop_r(_copy(state_r), data, idx)
     ss, _ = loop_s(_copy(state_s), data, idx)
     for a, b in zip(jax.tree_util.tree_leaves(sr.params),
@@ -483,22 +485,22 @@ def test_factory_rejects_mismatched_optimizer(mesh8):
     opt = SGD(momentum=0.9)
     sopt = shard_optimizer(SGD(momentum=0.9), 8)
     with pytest.raises(ValueError, match="ShardedUpdate"):
-        make_train_step_shard_map(Net(), opt, mesh8, constant_lr(0.05),
-                                  update_sharding="sharded")
+        make_train_step(Net(), opt, mesh8, constant_lr(0.05),
+                        update_sharding="sharded")
     with pytest.raises(ValueError, match="incompatible"):
-        make_train_step_shard_map(Net(), sopt, mesh8, constant_lr(0.05))
+        make_train_step(Net(), sopt, mesh8, constant_lr(0.05), explicit=True)
     with pytest.raises(ValueError, match="update_sharding"):
-        make_train_step_shard_map(Net(), opt, mesh8, constant_lr(0.05),
-                                  update_sharding="diagonal")
+        make_train_step(Net(), opt, mesh8, constant_lr(0.05),
+                        update_sharding="diagonal", explicit=True)
     with pytest.raises(ValueError, match="collective_dtype"):
-        make_train_step_shard_map(Net(), sopt, mesh8, constant_lr(0.05),
-                                  update_sharding="sharded",
-                                  collective_dtype="int4")
+        make_train_step(Net(), sopt, mesh8, constant_lr(0.05),
+                        update_sharding="sharded",
+                        collective_dtype="int4")
     # A wire dtype on the replicated path would be silently ignored —
     # rejected at the factory boundary instead.
     with pytest.raises(ValueError, match="collective_dtype"):
-        make_train_step_shard_map(Net(), opt, mesh8, constant_lr(0.05),
-                                  collective_dtype="bf16")
+        make_train_step(Net(), opt, mesh8, constant_lr(0.05),
+                        collective_dtype="bf16", explicit=True)
     with pytest.raises(ValueError, match="world"):
         ShardedUpdate(opt, 0)
 

@@ -111,11 +111,10 @@ def test_shard_map_matches_gspmd(setup, mesh8):
     explicit `lax.pmean` vs sharding annotations + inferred all-reduce —
     must produce identical losses, counts, and parameter trajectories.
     """
-    from tpu_dp.train import make_train_step_shard_map
-
     model, opt, state = setup
     step_g = make_train_step(model, opt, mesh8, constant_lr(0.05))
-    step_s = make_train_step_shard_map(model, opt, mesh8, constant_lr(0.05))
+    step_s = make_train_step(model, opt, mesh8, constant_lr(0.05),
+                             explicit=True)
     sg, ss = _copy(state), _copy(state)
     for i in range(3):
         batch = _make_batch(i, 16)
@@ -139,13 +138,11 @@ def test_shard_map_accum_matches_gspmd(setup, mesh8):
     `tpu_dp.analysis` DP202 verifies statically); numerically that means
     the accum shard_map step tracks the accum GSPMD step exactly.
     """
-    from tpu_dp.train import make_train_step_shard_map
-
     model, opt, state = setup
     step_g = make_train_step(model, opt, mesh8, constant_lr(0.05),
                              accum_steps=2)
-    step_s = make_train_step_shard_map(model, opt, mesh8, constant_lr(0.05),
-                                       accum_steps=2)
+    step_s = make_train_step(model, opt, mesh8, constant_lr(0.05),
+                             accum_steps=2, explicit=True)
     sg, ss = _copy(state), _copy(state)
     for i in range(2):
         flat = _make_batch(i, 32)
@@ -170,8 +167,6 @@ def test_shard_map_sync_bn_resnet(mesh8):
     """shard_map path with a BatchNorm model (axis_name-synced stats)."""
     from tpu_dp.models import ResNet18
     from tpu_dp.parallel.dist import DATA_AXIS
-    from tpu_dp.train import make_train_step_shard_map
-
     model_s = ResNet18(num_classes=10, num_filters=8, axis_name=DATA_AXIS)
     model_g = ResNet18(num_classes=10, num_filters=8)
     opt = SGD(momentum=0.9)
@@ -179,7 +174,8 @@ def test_shard_map_sync_bn_resnet(mesh8):
         model_g, jax.random.PRNGKey(0), np.zeros((1, 32, 32, 3), np.float32), opt
     )
     step_g = make_train_step(model_g, opt, mesh8, constant_lr(0.05))
-    step_s = make_train_step_shard_map(model_s, opt, mesh8, constant_lr(0.05))
+    step_s = make_train_step(model_s, opt, mesh8, constant_lr(0.05),
+                             explicit=True)
     sg, ss = _copy(state), _copy(state)
     batch = _make_batch(0, 16)
     sg, mg = step_g(sg, batch)
@@ -239,19 +235,20 @@ def test_eval_step_counts(setup, mesh8):
 def test_scanned_multi_step_matches_host_loop(setup, mesh8):
     """K scanned steps (one dispatch) ≡ K host-loop step calls, exactly.
 
-    `make_multi_step` is the device-side training loop (lax.scan over the
+    `make_train_step(feed="window")` is the device-side training loop (lax.scan over the
     step body); its trajectory, per-step losses, and LR schedule positions
     must be indistinguishable from driving `make_train_step` from the host.
     """
     import jax.numpy as jnp
 
-    from tpu_dp.train import cosine_lr, make_multi_step
+    from tpu_dp.train import cosine_lr
 
     model, opt, state = setup
     K, n = 4, 16
     sched = cosine_lr(0.05, 10, 2)
     step = make_train_step(model, opt, mesh8, sched)
-    loop = make_multi_step(model, opt, mesh8, sched, num_steps=K)
+    loop = make_train_step(model, opt, mesh8, sched,
+                           feed="window", num_steps=K)
 
     batches = [_make_batch(100 + i, n) for i in range(K)]
     pool = {
@@ -300,16 +297,15 @@ def _image_dataset(sample_shape, n, seed):
 def test_resident_loop_matches_multi_step(mesh8, sample_shape):
     """Device-resident feed ≡ streaming feed, bit for bit.
 
-    `make_multi_step_resident` gathers each step's batch on-device from the
+    `make_train_step(feed="resident")` gathers each step's batch on-device from the
     data set staged with its rows flat (`DataPipeline.resident_data`) and
     restores the rows' shape; the trajectory and per-step metrics must be
-    those of `make_multi_step` on the equivalent stacked pool
+    those of `feed="window"` on the equivalent stacked pool
     (VERDICT r4 next-steps #3). Exercises uint8 staging: normalization
     happens in-body for both paths.
     """
     from tpu_dp.data.pipeline import DataPipeline
-    from tpu_dp.train import cosine_lr, make_multi_step
-    from tpu_dp.train.step import make_multi_step_resident
+    from tpu_dp.train import cosine_lr
 
     model, opt = Net(), SGD(momentum=0.9)
     state = create_train_state(
@@ -319,7 +315,8 @@ def test_resident_loop_matches_multi_step(mesh8, sample_shape):
     sched = cosine_lr(0.05, 10, 2)
     ds = _image_dataset(sample_shape, K * n, seed=7)
 
-    loop = make_multi_step(model, opt, mesh8, sched, num_steps=K)
+    loop = make_train_step(model, opt, mesh8, sched,
+                           feed="window", num_steps=K)
     # Shuffled indices: the pool holds the same examples in the same order.
     idx = np.random.default_rng(3).permutation(K * n).astype(np.int32)
     idx = idx.reshape(K, n)
@@ -330,8 +327,9 @@ def test_resident_loop_matches_multi_step(mesh8, sample_shape):
     s_stream, stream_m = loop(_copy(state), pool)
 
     pipe = DataPipeline(ds, batch_size=n, mesh=mesh8)
-    rloop = make_multi_step_resident(model, opt, mesh8, sched, num_steps=K,
-                                     sample_shapes=pipe.sample_shapes)
+    rloop = make_train_step(model, opt, mesh8, sched,
+                            feed="resident", num_steps=K,
+                            sample_shapes=pipe.sample_shapes)
     s_res, res_m = rloop(_copy(state), pipe.resident_data(), idx)
 
     assert int(s_res.step) == int(s_stream.step) == K
@@ -377,7 +375,6 @@ def test_resident_loop_with_accum(setup, mesh8):
     """Scan-of-scan over the resident feed: (window, accum, batch) indices."""
     from tpu_dp.data.pipeline import DataPipeline
     from tpu_dp.train import constant_lr
-    from tpu_dp.train.step import make_multi_step_resident
 
     model, opt, state = setup
     ds = make_synthetic(64, 10, seed=8, name="res")
@@ -393,9 +390,9 @@ def test_resident_loop_with_accum(setup, mesh8):
             "label": ds.labels[lo:lo + 32].reshape(2, 16),
         })
 
-    rloop = make_multi_step_resident(model, opt, mesh8, constant_lr(0.05),
-                                     num_steps=2, accum_steps=2,
-                                     sample_shapes=pipe.sample_shapes)
+    rloop = make_train_step(model, opt, mesh8, constant_lr(0.05),
+                            feed="resident", num_steps=2, accum_steps=2,
+                            sample_shapes=pipe.sample_shapes)
     idx = np.arange(64, dtype=np.int32).reshape(2, 2, 16)
     s_res, m = rloop(_copy(state), data, idx)
 
@@ -414,13 +411,14 @@ def test_scanned_loop_modular_pool_matches_host_loop(setup, mesh8):
     This is the exact path bench.py measures (4-slot pool, 30-step window):
     the in-program modular gather must feed batch i % pool to step i.
     """
-    from tpu_dp.train import cosine_lr, make_multi_step
+    from tpu_dp.train import cosine_lr
 
     model, opt, state = setup
     K, pool_n, n = 6, 3, 16
     sched = cosine_lr(0.05, 10, 2)
     step = make_train_step(model, opt, mesh8, sched)
-    loop = make_multi_step(model, opt, mesh8, sched, num_steps=K)
+    loop = make_train_step(model, opt, mesh8, sched,
+                           feed="window", num_steps=K)
 
     batches = [_make_batch(200 + i, n) for i in range(pool_n)]
     pool = {

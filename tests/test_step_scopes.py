@@ -44,9 +44,11 @@ def _step_program(tmp_path, model: str, resident: str):
     tr = Trainer(c)
     if tr.resident_train is not None:
         _, idx = next(iter(tr.train_pipe.index_windows(1)))
-        return tr._resident_loop(1), (tr.state, tr.resident_train, idx)
-    _, batch = next(iter(tr.train_pipe.windows(1)))
-    return tr.train_step, (tr.state, batch)
+        args = (tr.state, tr.resident_train, idx)
+    else:
+        _, batch = next(iter(tr.train_pipe.windows(1)))
+        args = (tr.state, batch)
+    return tr._program(1).run, args
 
 
 def _phases_by_op(text: str) -> list[tuple[str, str | None, set]]:

@@ -28,14 +28,9 @@ from tpu_dp.analysis.astlint import _dotted, iter_py_files, scope_index, \
     scope_at
 from tpu_dp.analysis.report import Finding
 
-# Factories returning a step jitted with donate_argnums=(0,): calling the
-# result consumes its first argument.
-DONATING_FACTORIES = {
-    "make_train_step",
-    "make_multi_step",
-    "make_multi_step_resident",
-    "make_train_step_shard_map",
-}
+# The factory of every train program, each jitted with
+# donate_argnums=(0,): calling the result consumes its first argument.
+DONATING_FACTORIES = {"make_train_step"}
 
 # Wrappers that preserve the donating call signature: a name bound to
 # `RecompileGuard(make_train_step(...))` or the trainer's

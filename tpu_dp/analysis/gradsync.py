@@ -11,7 +11,7 @@ program finally traces on a real mesh.
 
 This pass checks the contract on the *real shipped program*: it traces the
 per-shard step `tpu_dp.train.step.make_local_step` builds (the exact body
-`make_train_step_shard_map` wraps) on abstract values with the data axis
+`make_train_step(explicit=True)` wraps) on abstract values with the data axis
 bound, then walks the jaxpr backward from each updated-parameter output.
 Because the SGD update is an independent per-leaf dataflow, the backward
 slice of one parameter output contains precisely the collectives that
@@ -289,7 +289,7 @@ def verify_repo_step(
 
     Builds the real model/optimizer/schedule, asks
     `tpu_dp.train.step.make_local_step` for the per-shard program (the one
-    `make_train_step_shard_map` compiles), and checks every parameter
+    `make_train_step(explicit=True)` compiles), and checks every parameter
     leaf's reduction count — under gradient accumulation too, where the
     single reduction must sit after the microbatch scan.
 

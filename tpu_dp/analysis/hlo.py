@@ -685,9 +685,9 @@ def shipped_programs(
         )
     yield (
         "train_step[shard_map]@accum1",
-        step_mod.make_train_step_shard_map(model, opt, mesh, sched),
+        step_mod.make_train_step(model, opt, mesh, sched, explicit=True),
         (state, _example_batch(batch)),
-        spec(step_mod.make_train_step_shard_map, n_state, 2, True),
+        spec(step_mod.make_train_step, n_state, 2, True),
     )
     # The sharded weight update's second legal schedule: one combinable
     # reduce-scatter group + one all-gather group (DP301 sharded mode).
@@ -695,12 +695,12 @@ def shipped_programs(
         prefix = () if accum == 1 else (accum,)
         yield (
             f"train_step[shard_map,sharded]@accum{accum}",
-            step_mod.make_train_step_shard_map(
+            step_mod.make_train_step(
                 model, sharded_opt, mesh, sched, accum_steps=accum,
                 update_sharding="sharded",
             ),
             (sharded_state, _example_batch(batch, prefix)),
-            spec(step_mod.make_train_step_shard_map, n_state, 2, True,
+            spec(step_mod.make_train_step, n_state, 2, True,
                  mode="sharded"),
         )
     # The quantized-wire variants (train.collective_dtype=int8): the THIRD
@@ -712,12 +712,12 @@ def shipped_programs(
         prefix = () if accum == 1 else (accum,)
         yield (
             f"train_step[shard_map,sharded,int8]@accum{accum}",
-            step_mod.make_train_step_shard_map(
+            step_mod.make_train_step(
                 model, sharded_opt, mesh, sched, accum_steps=accum,
                 update_sharding="sharded", collective_dtype="int8",
             ),
             (int8_state, _example_batch(batch, prefix)),
-            spec(step_mod.make_train_step_shard_map, n_int8_state, 4, True,
+            spec(step_mod.make_train_step, n_int8_state, 4, True,
                  mode="sharded", wire="int8"),
         )
     # The bucketed overlap schedule (train.bucket_mb, docs/PERF.md
@@ -744,55 +744,59 @@ def shipped_programs(
     n_bucket_state = len(jax.tree_util.tree_leaves(bucket_int8_state))
     yield (
         "train_step[shard_map,sharded,bucketed]@accum1",
-        step_mod.make_train_step_shard_map(
+        step_mod.make_train_step(
             model, sharded_opt, mesh, sched, update_sharding="sharded",
             bucket_mb=bucket_mb,
         ),
         (sharded_state, _example_batch(batch)),
-        spec(step_mod.make_train_step_shard_map, n_state, 2, True,
+        spec(step_mod.make_train_step, n_state, 2, True,
              mode="sharded",
              bucket_layout=bucket_expectations(plan_f32, world, block)),
     )
     yield (
         "train_step[shard_map,sharded,int8,bucketed]@accum1",
-        step_mod.make_train_step_shard_map(
+        step_mod.make_train_step(
             model, sharded_opt, mesh, sched, update_sharding="sharded",
             collective_dtype="int8", bucket_mb=bucket_mb,
         ),
         (bucket_int8_state, _example_batch(batch)),
-        spec(step_mod.make_train_step_shard_map, n_bucket_state, 4, True,
+        spec(step_mod.make_train_step, n_bucket_state, 4, True,
              mode="sharded", wire="int8",
              bucket_layout=bucket_expectations(plan_int8, world, block)),
     )
     yield (
         "multi_step[sharded,bucketed]@w2",
-        step_mod.make_multi_step(model, sharded_opt, mesh, sched,
-                                 num_steps=2, update_sharding="sharded",
+        step_mod.make_train_step(model, sharded_opt, mesh, sched,
+                                 feed="window", num_steps=2,
+                                 update_sharding="sharded",
                                  bucket_mb=bucket_mb),
         (sharded_state, _example_batch(batch, (2,))),
-        spec(step_mod.make_multi_step, n_state, 2, True, mode="sharded",
+        spec(step_mod.make_train_step, n_state, 2, True, mode="sharded",
              bucket_layout=bucket_expectations(plan_f32, world, block)),
     )
     yield (
         "multi_step@w2",
-        step_mod.make_multi_step(model, opt, mesh, sched, num_steps=2),
+        step_mod.make_train_step(model, opt, mesh, sched, feed="window",
+                                 num_steps=2),
         (state, _example_batch(batch, (2,))),
-        spec(step_mod.make_multi_step, n_state, 2, True),
+        spec(step_mod.make_train_step, n_state, 2, True),
     )
     yield (
         "multi_step[sharded]@w2",
-        step_mod.make_multi_step(model, sharded_opt, mesh, sched,
-                                 num_steps=2, update_sharding="sharded"),
+        step_mod.make_train_step(model, sharded_opt, mesh, sched,
+                                 feed="window", num_steps=2,
+                                 update_sharding="sharded"),
         (sharded_state, _example_batch(batch, (2,))),
-        spec(step_mod.make_multi_step, n_state, 2, True, mode="sharded"),
+        spec(step_mod.make_train_step, n_state, 2, True, mode="sharded"),
     )
     yield (
         "multi_step[sharded,int8]@w2",
-        step_mod.make_multi_step(model, sharded_opt, mesh, sched,
-                                 num_steps=2, update_sharding="sharded",
+        step_mod.make_train_step(model, sharded_opt, mesh, sched,
+                                 feed="window", num_steps=2,
+                                 update_sharding="sharded",
                                  collective_dtype="int8"),
         (int8_state, _example_batch(batch, (2,))),
-        spec(step_mod.make_multi_step, n_int8_state, 4, True,
+        spec(step_mod.make_train_step, n_int8_state, 4, True,
              mode="sharded", wire="int8"),
     )
     yield (
@@ -820,19 +824,19 @@ def shipped_programs(
     )
     yield (
         "train_step[shard_map,sentinel]@accum1",
-        step_mod.make_train_step_shard_map(model, opt, mesh, sched,
-                                           sentinel=True),
+        step_mod.make_train_step(model, opt, mesh, sched, sentinel=True,
+                                 explicit=True),
         (state, _example_batch(batch), gi),
-        spec(step_mod.make_train_step_shard_map, n_state, 2, True),
+        spec(step_mod.make_train_step, n_state, 2, True),
     )
     yield (
         "train_step[shard_map,sharded,sentinel]@accum1",
-        step_mod.make_train_step_shard_map(
+        step_mod.make_train_step(
             model, sharded_opt, mesh, sched, update_sharding="sharded",
             sentinel=True,
         ),
         (sharded_state, _example_batch(batch), gi),
-        spec(step_mod.make_train_step_shard_map, n_state, 3, True,
+        spec(step_mod.make_train_step, n_state, 3, True,
              mode="sharded"),
     )
     # Guard + quantized wire together (the interaction the guard suite
@@ -842,20 +846,20 @@ def shipped_programs(
     # overflow, clip.
     yield (
         "train_step[shard_map,sharded,int8,sentinel]@accum1",
-        step_mod.make_train_step_shard_map(
+        step_mod.make_train_step(
             model, sharded_opt, mesh, sched, update_sharding="sharded",
             collective_dtype="int8", sentinel=True,
         ),
         (int8_state, _example_batch(batch), gi),
-        spec(step_mod.make_train_step_shard_map, n_int8_state, 5, True,
+        spec(step_mod.make_train_step, n_int8_state, 5, True,
              mode="sharded", wire="int8"),
     )
     yield (
         "multi_step[sentinel]@w2",
-        step_mod.make_multi_step(model, opt, mesh, sched, num_steps=2,
-                                 sentinel=True),
+        step_mod.make_train_step(model, opt, mesh, sched, sentinel=True,
+                                 feed="window", num_steps=2),
         (state, _example_batch(batch, (2,)), gi),
-        spec(step_mod.make_multi_step, n_state, 2, True),
+        spec(step_mod.make_train_step, n_state, 2, True),
     )
     # The serving forwards (`tpu_dp.serve`, docs/SERVING.md): one program
     # per batch bucket, donating the ServeStats pytree (2 leaves — DP303

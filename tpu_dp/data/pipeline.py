@@ -330,7 +330,8 @@ class DataPipeline:
                                   self.mesh, spec=spec))
 
     def windows(self, k: int, skip_steps: int = 0):
-        """Yield ``(n_steps, device_item)`` pairs for `make_multi_step`.
+        """Yield ``(n_steps, device_item)`` pairs for
+        `make_train_step(feed="window")`.
 
         Full windows stack ``k`` consecutive host batches on a leading scan
         axis (one host→device transfer, one dispatch for ``k`` optimizer

@@ -1,13 +1,9 @@
 """One chip-spec registry: peak FLOP/s, HBM and ICI bandwidth per kind.
 
-Before this module the chip peaks lived in two drift-prone copies:
-`tpu_dp.obs.costs.PEAK_FLOPS_BY_KIND` (the MFU denominator) and
-`tools/profile_breakdown.py`'s ``V5E_PEAK_TFLOPS`` / ``V5E_PEAK_HBM_GBS``
-(the per-op efficiency table). A per-collective wire-bandwidth health
-metric (arXiv:2204.06514 treats it as first-class) needs a third number —
-the chip's ICI bandwidth — and a third hardcoded copy was the moment to
-merge all of them: `costs.py`, `tpu_dp.obs.commprof` and
-`tools/profile_breakdown.py` all consume THIS table now, pinned by a
+`tpu_dp.obs.costs.PEAK_FLOPS_BY_KIND` (the MFU denominator) is derived
+from this table, and the per-collective wire-bandwidth health metric of
+`tpu_dp.obs.commprof` (arXiv:2204.06514 treats it as first-class) reads
+the chip's ICI bandwidth from it: one copy of each peak, pinned by a
 cross-import test.
 
 Values are public spec-sheet numbers (Cloud TPU system-architecture

@@ -1,9 +1,7 @@
 """xplane-proto parsing: one reusable reader for `jax.profiler` traces.
 
-Hoisted out of ``tools/profile_breakdown.py`` (which is now a thin CLI
-over this module) so the in-run comm/compute attribution layer
-(`tpu_dp.obs.commprof`) and the offline breakdown tool read traces
-through one code path. A captured trace directory holds one
+The in-run comm/compute attribution layer (`tpu_dp.obs.commprof`) reads
+traces through this module. A captured trace directory holds one
 ``*.xplane.pb`` per capture; this module finds the newest, parses it with
 tensorflow's bundled xplane proto, and aggregates the op events into a
 backend-neutral summary:
@@ -12,8 +10,7 @@ backend-neutral summary:
   ``"XLA Ops"`` line whose events have ``hlo_category`` /
   ``model_flops`` / ``bytes_accessed`` stats; the ``%while`` scan
   wrapper spans the whole window and is excluded from op totals (it is
-  the window clock instead) — exactly `profile_breakdown`'s historical
-  reading.
+  the window clock instead).
 - **Host thunk planes** (the CPU backend): there is no device plane;
   the ``/host:CPU`` plane's ``tf_XLA*`` thread lines carry one event per
   executed thunk, named after the HLO op (``all-reduce.1``,
@@ -261,9 +258,8 @@ def device_plane_summary(plane) -> dict:
     """Summary of one TPU device plane's ``"XLA Ops"`` line.
 
     The ``%while`` scan wrapper spans the whole window — it becomes
-    ``window_s``, never an op (the historical `profile_breakdown`
-    reading). Empty op lists are the caller's verdict to make (the CLI
-    prints its own diagnostic; `summarize` raises).
+    ``window_s``, never an op. Empty op lists are the caller's verdict
+    to make (`summarize` raises).
     """
     walk = _PlaneWalk()
     md, sm = plane.event_metadata, plane.stat_metadata

@@ -27,7 +27,7 @@ def batch_sharding(mesh: Mesh) -> NamedSharding:
 def scan_batch_sharding(mesh: Mesh, prefix_dims: int = 1) -> NamedSharding:
     """Sharding for batches with ``prefix_dims`` leading scan axes
     (microbatches under gradient accumulation, step windows under
-    `make_multi_step`, or both at once — scan-of-scan): scan dims
+    `make_train_step`'s scanned feeds, or both at once — scan-of-scan): scan dims
     replicated, batch dim sharded over ``data``."""
     return NamedSharding(mesh, P(*([None] * prefix_dims), DATA_AXIS))
 

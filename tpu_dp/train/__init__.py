@@ -14,9 +14,7 @@ from tpu_dp.train.step import (
     cross_entropy_loss,
     make_eval_step,
     make_local_step,
-    make_multi_step,
     make_train_step,
-    make_train_step_shard_map,
 )
 from tpu_dp.train.trainer import Trainer
 
@@ -33,8 +31,6 @@ __all__ = [
     "cross_entropy_loss",
     "make_eval_step",
     "make_local_step",
-    "make_multi_step",
     "make_schedule",
     "make_train_step",
-    "make_train_step_shard_map",
 ]

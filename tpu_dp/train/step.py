@@ -27,11 +27,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from tpu_dp.models.outputs import RowLoss
-from tpu_dp.parallel.sharding import (
-    batch_sharding,
-    replicated_sharding,
-    scan_batch_sharding,
-)
+from tpu_dp.parallel.sharding import batch_sharding, replicated_sharding
 from tpu_dp.train.optim import Optimizer
 from tpu_dp.train.schedule import Schedule
 from tpu_dp.train.state import TrainState
@@ -136,8 +132,8 @@ def _forward_backward(model, loss_impl, state: TrainState, images, labels,
     and the model's counters (None for a model that publishes none).
 
     Train batches are always full (drop_remainder enforced), so no weight
-    mask on the training loss. Used by both step factories so the GSPMD and
-    explicit-`shard_map` paths cannot drift apart.
+    mask on the training loss. One block for the GSPMD and the
+    explicit-`shard_map` programs, so they cannot drift apart.
 
     ``cast_params`` (per-leaf, applied *before* differentiation) is the
     explicit-collectives path's varying-cast hook: under shard_map's
@@ -242,12 +238,8 @@ def default_guard_in():
 
 def guard_in_struct():
     """ShapeDtypeStruct twin of `default_guard_in` (AOT fingerprinting)."""
-    return {
-        "loss_cap": jax.ShapeDtypeStruct((), jnp.float32),
-        "lr_scale": jax.ShapeDtypeStruct((), jnp.float32),
-        "fault_step": jax.ShapeDtypeStruct((), jnp.int32),
-        "fault_scale": jax.ShapeDtypeStruct((), jnp.float32),
-    }
+    return {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
+            for k, v in default_guard_in().items()}
 
 
 def _inject_guard_fault(step, loss, grads, guard_in):
@@ -360,11 +352,50 @@ def _sentinel_tail(optimizer, schedule, state, grads, new_batch_stats,
     return new_state, metrics
 
 
+def _make_update_tail(optimizer, schedule, reduce_fn, sentinel,
+                      health_reduce, opt_pred_cast):
+    """What both step bodies do with one update's gradients: the
+    cross-replica reduce hook, then the (guarded) optimizer update and the
+    step's metrics — stated once, so the plain and the accumulating body
+    cannot drift apart."""
+
+    def finish(state, grads, new_batch_stats, loss, correct, count,
+               counters, guard_in):
+        new_residuals, extra = None, {}
+        if reduce_fn is not None:
+            with jax.named_scope("tpu_dp.grad_reduce"):
+                (grads, loss, correct, count, new_batch_stats,
+                 new_residuals, extra) = reduce_fn(
+                    grads, loss, correct, count, new_batch_stats,
+                    state.residuals, counters=counters,
+                )
+        elif counters is not None:
+            extra = {"counters": counters}
+        if sentinel:
+            return _sentinel_tail(
+                optimizer, schedule, state, grads, new_batch_stats,
+                loss, correct, count, guard_in, health_reduce,
+                opt_pred_cast=opt_pred_cast, new_residuals=new_residuals,
+                extra_metrics=extra,
+            )
+        with jax.named_scope("tpu_dp.update"):
+            new_state, lr = _apply_update(
+                optimizer, schedule, state, grads, new_batch_stats,
+                new_residuals=new_residuals,
+            )
+        metrics = {"loss": loss, "correct": correct, "count": count,
+                   "lr": lr}
+        metrics.update(extra)
+        return new_state, metrics
+
+    return finish
+
+
 def _make_step_body(model, optimizer, schedule, loss_impl, augment_fn,
                     reduce_fn=None, cast_params=None, sentinel=False,
                     health_reduce=None, opt_pred_cast=None):
-    """The single-microbatch step body shared by `make_train_step`
-    (accum_steps=1) and `make_multi_step`'s scan — one source of truth for
+    """The single-microbatch step body of every `make_train_step` program
+    (accum_steps=1), called once or scanned — one source of truth for
     normalize → augment → fwd/bwd → [cross-replica reduce] → update →
     metrics, so the host-loop and device-loop paths cannot drift apart.
 
@@ -384,6 +415,9 @@ def _make_step_body(model, optimizer, schedule, loss_impl, augment_fn,
     default) the body — and its compiled HLO — is bit-for-bit the program
     it always was.
     """
+
+    finish = _make_update_tail(optimizer, schedule, reduce_fn, sentinel,
+                               health_reduce, opt_pred_cast)
 
     def body(state: TrainState, batch, guard_in=None):
         # jax.named_scope: names land in HLO op metadata, so device-side
@@ -408,36 +442,8 @@ def _make_step_body(model, optimizer, schedule, loss_impl, augment_fn,
         if sentinel:
             gi = guard_in if guard_in is not None else default_guard_in()
             loss, grads = _inject_guard_fault(state.step, loss, grads, gi)
-        new_residuals, extra = None, {}
-        if reduce_fn is not None:
-            with jax.named_scope("tpu_dp.grad_reduce"):
-                (grads, loss, correct, count, new_batch_stats,
-                 new_residuals, extra) = reduce_fn(
-                    grads, loss, correct, count, new_batch_stats,
-                    state.residuals, counters=counters,
-                )
-        elif counters is not None:
-            extra = {"counters": counters}
-        if sentinel:
-            return _sentinel_tail(
-                optimizer, schedule, state, grads, new_batch_stats,
-                loss, correct, count, guard_in, health_reduce,
-                opt_pred_cast=opt_pred_cast, new_residuals=new_residuals,
-                extra_metrics=extra,
-            )
-        with jax.named_scope("tpu_dp.update"):
-            new_state, lr = _apply_update(
-                optimizer, schedule, state, grads, new_batch_stats,
-                new_residuals=new_residuals,
-            )
-        metrics = {
-            "loss": loss,
-            "correct": correct,
-            "count": count,
-            "lr": lr,
-        }
-        metrics.update(extra)
-        return new_state, metrics
+        return finish(state, grads, new_batch_stats, loss, correct, count,
+                      counters, guard_in)
 
     return body
 
@@ -454,10 +460,13 @@ def _make_accum_body(
     microbatch dim is the sharded one). ``lax.scan`` runs the microbatches
     sequentially, accumulating grads on-device — how a logical global batch
     larger than HBM (e.g. BASELINE config 5's 4096) runs on few chips.
-    Shared by `make_train_step` (one dispatch per update) and
-    `make_multi_step` (scan-of-scan: a window of accumulated updates in one
-    program), so the two paths cannot drift apart.
+    One body for `make_train_step`'s single-batch feed (one dispatch per
+    update) and its scanned feeds (scan-of-scan: a window of accumulated
+    updates in one program), so the two cannot drift apart.
     """
+
+    finish = _make_update_tail(optimizer, schedule, reduce_fn, sentinel,
+                               health_reduce, opt_pred_cast)
 
     def body(state: TrainState, batch, guard_in=None):
         # Same named_scope annotations as `_make_step_body` (HLO metadata
@@ -531,37 +540,8 @@ def _make_accum_body(
         # never one per microbatch (`tpu_dp.analysis` DP202 verifies this)
         # — and so the int8 codec quantizes (and its residual updates) once
         # per optimizer update too.
-        new_residuals, extra = None, {}
-        if reduce_fn is not None:
-            with jax.named_scope("tpu_dp.grad_reduce"):
-                (grads, loss, correct, count, new_batch_stats,
-                 new_residuals, extra) = reduce_fn(
-                    grads, loss, correct, count, new_batch_stats,
-                    state.residuals, counters=counters,
-                )
-        elif counters is not None:
-            extra = {"counters": counters}
-
-        if sentinel:
-            return _sentinel_tail(
-                optimizer, schedule, state, grads, new_batch_stats,
-                loss, correct, count, guard_in, health_reduce,
-                opt_pred_cast=opt_pred_cast, new_residuals=new_residuals,
-                extra_metrics=extra,
-            )
-        with jax.named_scope("tpu_dp.update"):
-            new_state, lr = _apply_update(
-                optimizer, schedule, state, grads, new_batch_stats,
-                new_residuals=new_residuals,
-            )
-        metrics = {
-            "loss": loss,
-            "correct": correct,
-            "count": count,
-            "lr": lr,
-        }
-        metrics.update(extra)
-        return new_state, metrics
+        return finish(state, grads, new_batch_stats, loss, correct, count,
+                      counters, guard_in)
 
     return body
 
@@ -571,9 +551,8 @@ def _select_body(model, optimizer, schedule, loss_impl, augment_fn,
                  sentinel=False, health_reduce=None, opt_pred_cast=None):
     """One source of truth for the per-update body: plain step at
     accum_steps == 1, gradient-accumulation body otherwise. Used by
-    `make_train_step`, `make_multi_step`, and (via `make_local_step`) the
-    explicit-collectives `shard_map` path, so all step programs share the
-    exact same body."""
+    `make_train_step` and (via `make_local_step`) its explicit-collectives
+    `shard_map` form, so all step programs share the exact same body."""
     if accum_steps == 1:
         return _make_step_body(model, optimizer, schedule, loss_impl,
                                augment_fn, reduce_fn=reduce_fn,
@@ -585,183 +564,6 @@ def _select_body(model, optimizer, schedule, loss_impl, augment_fn,
                             cast_params=cast_params, sentinel=sentinel,
                             health_reduce=health_reduce,
                             opt_pred_cast=opt_pred_cast)
-
-
-def make_train_step(
-    model,
-    optimizer: Optimizer,
-    mesh: Mesh,
-    schedule: Schedule,
-    use_pallas_xent: bool = False,
-    accum_steps: int = 1,
-    augment_fn: Callable | None = None,
-    sentinel: bool = False,
-) -> Callable:
-    """Build the jitted DP train step for this model/optimizer/mesh.
-
-    Returns ``step(state, batch) -> (new_state, metrics)`` where ``batch``
-    is the device-placed global batch (leading dim sharded over ``data``)
-    and metrics are replicated scalars: mean loss, correct-prediction count,
-    and example count — the per-step statistics the reference prints
-    (`cifar_example.py:83-87`) plus what its synced eval metric accumulates
-    (`cifar_example_ddp.py:133`).
-
-    ``sentinel=True`` (guard.enabled, docs/RESILIENCE.md "Guardrails")
-    compiles the on-device health summary + guarded update into the
-    program: the signature becomes ``step(state, batch, guard_in)``
-    (`default_guard_in` — replicated scalars, not donated) and metrics
-    gain ``loss_raw`` / ``grad_norm`` / ``applied``. Off, the factory —
-    and the compiled HLO — is exactly the pre-guardrails program (the
-    DP304 fingerprint is digest-identical).
-    """
-    # The GSPMD path is replicated-update only (the sharded update needs
-    # explicit collectives — `make_train_step_shard_map`); reject a
-    # sharded-layout optimizer at the factory boundary.
-    _check_update_sharding("replicated", optimizer)
-    repl = replicated_sharding(mesh)
-    batch_sh = batch_sharding(mesh)
-    loss_impl = _select_loss_impl(use_pallas_xent)
-
-    # `batch_sh` is a pytree-prefix: every batch leaf (image, label, and
-    # the optional weight mask) shards on its leading dim — or, with
-    # accumulation, on the microbatch dim after the scan axis.
-    step = _select_body(model, optimizer, schedule, loss_impl, augment_fn,
-                        accum_steps, sentinel=sentinel)
-    in_batch_sh = batch_sh if accum_steps == 1 else scan_batch_sharding(mesh)
-    in_sh = (repl, in_batch_sh) + ((repl,) if sentinel else ())
-    return jax.jit(
-        step,
-        in_shardings=in_sh,
-        out_shardings=(repl, repl),
-        donate_argnums=(0,),
-    )
-
-
-def make_multi_step(
-    model,
-    optimizer: Optimizer,
-    mesh: Mesh,
-    schedule: Schedule,
-    num_steps: int,
-    use_pallas_xent: bool = False,
-    augment_fn: Callable | None = None,
-    accum_steps: int = 1,
-    update_sharding: str = "replicated",
-    collective_dtype: str | None = None,
-    quant_block_size: int | None = None,
-    quant_error_feedback: bool = True,
-    bucket_mb: float = 0.0,
-    sentinel: bool = False,
-) -> Callable:
-    """Device-side training loop: ``num_steps`` train steps in ONE program.
-
-    ``lax.scan`` over the same step body `make_train_step` compiles, fed by a
-    device-resident pool of batches with a leading (num_steps,) axis. One
-    dispatch executes the whole window, so host→device round-trips (launch
-    latency) amortize across the window — the
-    reference's eager loop pays them every step
-    (`/root/reference/cifar_example_ddp.py:94-107`). Semantically identical
-    to calling the single step ``num_steps`` times (equivalence-tested);
-    metrics come back stacked per step.
-
-    Returns ``loop(state, batches) -> (new_state, stacked_metrics)`` where
-    every ``batches`` leaf has shape (pool, global_batch, ...). When
-    ``pool == num_steps`` the scan consumes the pool directly; a smaller
-    pool is cycled modularly *inside* the program (device-side gather per
-    step), so HBM cost stays constant in ``num_steps`` — e.g. a benchmark
-    can run a 30-step window over 4 staged batches without 30 copies.
-
-    With ``accum_steps > 1`` the scanned body is the gradient-accumulation
-    step (scan-of-scan): batch leaves gain a second leading axis,
-    (pool, accum_steps, microbatch, ...), and each of the ``num_steps``
-    window elements performs one accumulated optimizer update — BASELINE
-    config 5's global-batch-4096 recipe running windowed on a small mesh,
-    where both amortizations (dispatch RTT and HBM) are needed at once.
-
-    ``update_sharding="sharded"`` runs the window over the explicit
-    sharded-weight-update body (`make_local_step` — reduce-scatter →
-    1/world update → params all-gather inside every scanned step, opt state
-    permanently sharded over ``data``); ``optimizer`` must then be a
-    `train.optim.ShardedUpdate`, as for `make_train_step_shard_map`.
-
-    ``sentinel=True`` scans the sentinel body: the loop signature becomes
-    ``loop(state, batches, guard_in)`` with ONE replicated ``guard_in``
-    shared by every step of the window (the policy's cap/ease values are
-    per-window by construction — the host only observes window
-    boundaries). A window step that trips the guard emits the unchanged
-    carry, so the remaining scanned steps continue from the pre-fault
-    state exactly like the per-step path.
-    """
-    from jax.sharding import PartitionSpec as P
-
-    from tpu_dp.parallel.dist import DATA_AXIS, data_axis_size
-
-    repl = replicated_sharding(mesh)
-    loss_impl = _select_loss_impl(use_pallas_xent)
-
-    if update_sharding == "sharded":
-        body = make_local_step(
-            model, optimizer, schedule, use_pallas_xent=use_pallas_xent,
-            accum_steps=accum_steps, augment_fn=augment_fn,
-            world=data_axis_size(mesh), axis_name=DATA_AXIS,
-            update_sharding=update_sharding,
-            collective_dtype=collective_dtype,
-            quant_block_size=quant_block_size,
-            quant_error_feedback=quant_error_feedback,
-            bucket_mb=bucket_mb,
-            sentinel=sentinel,
-        )
-    else:
-        _check_update_sharding(update_sharding, optimizer)
-        _refuse_replicated_bucketing(bucket_mb)
-        body = _select_body(model, optimizer, schedule, loss_impl,
-                            augment_fn, accum_steps, sentinel=sentinel)
-
-    def loop(state: TrainState, batches, guard_in=None):
-        step_body = body if guard_in is None else (
-            lambda st, mb: body(st, mb, guard_in)
-        )
-        pool = jax.tree_util.tree_leaves(batches)[0].shape[0]
-        if pool == num_steps:
-            return jax.lax.scan(step_body, state, batches, length=num_steps)
-
-        def indexed_body(st, i):
-            mb = jax.tree_util.tree_map(
-                lambda x: jax.lax.dynamic_index_in_dim(
-                    x, i % pool, keepdims=False
-                ),
-                batches,
-            )
-            return step_body(st, mb)
-
-        return jax.lax.scan(
-            indexed_body, state, jnp.arange(num_steps, dtype=jnp.int32)
-        )
-
-    # Scan axis (and, with accumulation, the microbatch-stack axis) in
-    # front, batch dim sharded over data.
-    prefix_dims = 1 if accum_steps == 1 else 2
-    in_batch_sh = scan_batch_sharding(mesh, prefix_dims=prefix_dims)
-    state_sh = _state_shardings(mesh, update_sharding)
-    run = loop
-    if update_sharding == "sharded":
-        # The explicit-collectives window: the whole scan runs per-shard
-        # under shard_map, each scanned step performing the reduce-scatter /
-        # sharded-update / all-gather sequence of `make_local_step`.
-        batch_spec = P(*([None] * prefix_dims), DATA_AXIS)
-        run = _shard_map(
-            loop,
-            mesh=mesh,
-            in_specs=(_state_specs(update_sharding), batch_spec)
-            + ((P(),) if sentinel else ()),
-            out_specs=(_state_specs(update_sharding), P()),
-        )
-    return jax.jit(
-        run,
-        in_shardings=(state_sh, in_batch_sh) + ((repl,) if sentinel else ()),
-        out_shardings=(state_sh, repl),
-        donate_argnums=(0,),
-    )
 
 
 def gather_rows(data, idx_step, sample_shapes):
@@ -778,134 +580,7 @@ def gather_rows(data, idx_step, sample_shapes):
         }
 
 
-def make_multi_step_resident(
-    model,
-    optimizer: Optimizer,
-    mesh: Mesh,
-    schedule: Schedule,
-    num_steps: int,
-    sample_shapes: dict[str, tuple[int, ...]],
-    use_pallas_xent: bool = False,
-    augment_fn: Callable | None = None,
-    accum_steps: int = 1,
-    update_sharding: str = "replicated",
-    collective_dtype: str | None = None,
-    quant_block_size: int | None = None,
-    quant_error_feedback: bool = True,
-    bucket_mb: float = 0.0,
-    sentinel: bool = False,
-) -> Callable:
-    """Windowed training loop fed by a device-resident dataset + indices.
-
-    The end-to-end feed redesign (VERDICT r4 next-steps #3): instead of the
-    host gathering and shipping ~MBs of batch per step (the reference's
-    DataLoader feed, `/root/reference/cifar_example.py:46-52`), the whole
-    train set is staged in HBM once (CIFAR-10: 150 MB uint8) and each window
-    dispatch carries only int32 *indices* — (num_steps, [accum,] batch),
-    ~KBs. The compiled program gathers each step's batch on-device from the
-    replicated dataset (the gather partitions trivially: indices are
-    sharded over ``data``, the operand is replicated, so every device
-    gathers exactly its shard's examples), then runs the same shared step
-    body as `make_multi_step` — normalize/augment/fwd/bwd/update all
-    unchanged and trajectory-identical (equivalence-tested).
-
-    Returns ``loop(state, data, idx) -> (new_state, stacked_metrics)``:
-    ``data`` is `DataPipeline.resident_data()`: a dict of device-resident
-    arrays (replicated; uint8 images fine — normalization is in-body) with
-    the rows flat, ``(N,)`` or ``(N, row)``; ``idx`` is int32 with the
-    window axis in front. Only ``state`` is donated — ``data`` must survive
-    the call.
-
-    ``sample_shapes`` (`DataPipeline.sample_shapes`) is each array's row
-    shape, static, which `gather_rows` restores after the gather. The
-    rows are staged flat because of what the v5e compiler makes of a
-    gather from ``uint8[N, 32, 32, 3]``, whose default layout there is
-    N-minor (nothing in the mathematics asks for it; do not stage 4-D)::
-
-        %copy.3 = u8[131072,32,32,3]{2,1,3,0:T(8,128)(4,1)} copy(%data.1)  # the whole data set, every call
-        %fusion = u8[4096,32,32,3]{2,1,3,0:...} fusion(%copy.3, %idx)       # the gather
-        %copy.4 = u8[4096,32,32,3]{0,2,3,1:...} copy(%fusion)               # the batch, back to batch-minor
-
-    From ``uint8[N, 3072]`` the gather fusion reads the argument in place
-    and one relayout of the batch is left (`tests/test_tpu_compile.py`
-    holds both forms; PERF.md §6, PR 29, the times).
-
-    ``update_sharding="sharded"`` composes the resident feed with the
-    sharded weight update: the indices shard over ``data`` (each replica
-    gathers only its shard's examples from the replicated dataset) and the
-    scanned body is the explicit reduce-scatter / 1/world-update /
-    all-gather step of `make_local_step`.
-    """
-    from jax.sharding import PartitionSpec as P
-
-    from tpu_dp.parallel.dist import DATA_AXIS, data_axis_size
-
-    repl = replicated_sharding(mesh)
-    loss_impl = _select_loss_impl(use_pallas_xent)
-    if update_sharding == "sharded":
-        body = make_local_step(
-            model, optimizer, schedule, use_pallas_xent=use_pallas_xent,
-            accum_steps=accum_steps, augment_fn=augment_fn,
-            world=data_axis_size(mesh), axis_name=DATA_AXIS,
-            update_sharding=update_sharding,
-            collective_dtype=collective_dtype,
-            quant_block_size=quant_block_size,
-            quant_error_feedback=quant_error_feedback,
-            bucket_mb=bucket_mb,
-            sentinel=sentinel,
-        )
-    else:
-        _check_update_sharding(update_sharding, optimizer)
-        _refuse_replicated_bucketing(bucket_mb)
-        body = _select_body(model, optimizer, schedule, loss_impl,
-                            augment_fn, accum_steps, sentinel=sentinel)
-
-    def loop(state: TrainState, data, idx, guard_in=None):
-        step_body = body if guard_in is None else (
-            lambda st, mb: body(st, mb, guard_in)
-        )
-
-        def indexed_body(st, idx_step):
-            return step_body(st, gather_rows(data, idx_step, sample_shapes))
-
-        # length pins the window size: a mis-shaped idx errors at trace
-        # time instead of silently running a different number of steps.
-        return jax.lax.scan(indexed_body, state, idx, length=num_steps)
-
-    prefix_dims = 1 if accum_steps == 1 else 2
-    idx_sh = scan_batch_sharding(mesh, prefix_dims=prefix_dims)
-    state_sh = _state_shardings(mesh, update_sharding)
-    run = loop
-    if update_sharding == "sharded":
-        idx_spec = P(*([None] * prefix_dims), DATA_AXIS)
-        run = _shard_map(
-            loop,
-            mesh=mesh,
-            in_specs=(_state_specs(update_sharding), P(), idx_spec)
-            + ((P(),) if sentinel else ()),
-            out_specs=(_state_specs(update_sharding), P()),
-        )
-    return jax.jit(
-        run,
-        in_shardings=(state_sh, repl, idx_sh) + ((repl,) if sentinel else ()),
-        out_shardings=(state_sh, repl),
-        donate_argnums=(0,),
-    )
-
-
 UPDATE_SHARDING_MODES = ("replicated", "sharded")
-
-
-def _refuse_replicated_bucketing(bucket_mb: float) -> None:
-    """Bucketing restructures the explicit reduce-scatter schedule; the
-    replicated GSPMD path has no explicit exchange to bucket. Refused at
-    every factory boundary — a silently-dropped `bucket_mb` would leave
-    the caller believing the overlap schedule is armed."""
-    if bucket_mb and float(bucket_mb) > 0:
-        raise ValueError(
-            "bucket_mb applies to the sharded update's reduce-scatter; "
-            "pass update_sharding='sharded'"
-        )
 
 
 def _check_update_sharding(update_sharding: str, optimizer) -> None:
@@ -956,6 +631,40 @@ def _parse_wire_codec(collective_dtype: str | None,
                     else quant_block_size),
         error_feedback=quant_error_feedback,
     )
+
+
+def _parse_exchange(
+    optimizer,
+    update_sharding: str,
+    collective_dtype: str | None = None,
+    quant_block_size: int | None = None,
+    quant_error_feedback: bool = True,
+    bucket_mb: float = 0.0,
+):
+    """The exchange keywords checked together: ``(codec, bucket_bytes)``.
+
+    The wire codec and the bucketing both restructure the sharded update's
+    explicit reduce-scatter; the replicated exchange (a pmean, or the
+    all-reduce GSPMD infers) has nothing for them to act on, and a
+    silently dropped keyword would leave the caller believing the
+    compression or the overlap schedule armed. Refused here, for every
+    program that trains.
+    """
+    from tpu_dp.parallel import bucketing
+
+    _check_update_sharding(update_sharding, optimizer)
+    codec = _parse_wire_codec(collective_dtype, quant_block_size,
+                              quant_error_feedback)
+    bucket_bytes = bucketing.parse_bucket_mb(bucket_mb)
+    if update_sharding != "sharded":
+        for name, armed in (("collective_dtype", codec is not None),
+                            ("bucket_mb", bucket_bytes)):
+            if armed:
+                raise ValueError(
+                    f"{name} applies to the sharded update's "
+                    "reduce-scatter; pass update_sharding='sharded'"
+                )
+    return codec, bucket_bytes
 
 
 def _state_specs(update_sharding: str):
@@ -1020,7 +729,7 @@ def make_local_step(
     """The per-shard step program with *explicit* collectives, unjitted.
 
     This is the SPMD program each device runs under
-    `make_train_step_shard_map`: the shared step body (`_select_body` — the
+    `make_train_step(explicit=True)`: the shared step body (`_select_body` — the
     same normalize → augment → fwd/bwd → update the GSPMD path compiles)
     with the cross-replica reduction written out between the per-shard
     grads and the optimizer update — pmean(grads) / pmean(loss) /
@@ -1070,24 +779,14 @@ def make_local_step(
     ``cast_params=False`` skips the varying-cast of the params; the
     analyzer uses it to trace outside a real `shard_map` scope.
     """
-    from tpu_dp.parallel import bucketing, collectives, quant
+    from tpu_dp.parallel import collectives, quant
     from tpu_dp.parallel.dist import DATA_AXIS
 
     if axis_name is None:
         axis_name = DATA_AXIS
-    _check_update_sharding(update_sharding, optimizer)
-    codec = _parse_wire_codec(collective_dtype, quant_block_size,
-                              quant_error_feedback)
-    if codec is not None and update_sharding != "sharded":
-        # Only the sharded reduce-scatter reads the wire codec; accepting
-        # it here would silently run full-precision pmean instead.
-        raise ValueError(
-            "collective_dtype applies to the sharded update's "
-            "reduce-scatter; pass update_sharding='sharded'"
-        )
-    bucket_bytes = bucketing.parse_bucket_mb(bucket_mb)
-    if update_sharding != "sharded":
-        _refuse_replicated_bucketing(bucket_mb)
+    codec, bucket_bytes = _parse_exchange(
+        optimizer, update_sharding, collective_dtype, quant_block_size,
+        quant_error_feedback, bucket_mb)
 
     loss_impl = _select_loss_impl(use_pallas_xent)
 
@@ -1181,7 +880,10 @@ def make_local_step(
                         opt_pred_cast=opt_pred_cast)
 
 
-def make_train_step_shard_map(
+FEEDS = ("batch", "window", "resident")
+
+
+def make_train_step(
     model,
     optimizer: Optimizer,
     mesh: Mesh,
@@ -1189,79 +891,181 @@ def make_train_step_shard_map(
     use_pallas_xent: bool = False,
     accum_steps: int = 1,
     augment_fn: Callable | None = None,
+    sentinel: bool = False,
+    *,
+    feed: str = "batch",
+    num_steps: int = 1,
+    sample_shapes: dict[str, tuple[int, ...]] | None = None,
     update_sharding: str = "replicated",
     collective_dtype: str | None = None,
     quant_block_size: int | None = None,
     quant_error_feedback: bool = True,
     bucket_mb: float = 0.0,
-    sentinel: bool = False,
+    explicit: bool | None = None,
 ) -> Callable:
-    """Explicit-collectives variant of the DP train step (`shard_map`).
+    """Build the jitted DP train program for this model/optimizer/mesh:
+    the one factory of every program that trains.
 
-    Where `make_train_step` lets GSPMD *infer* the gradient all-reduce from
-    sharding annotations, this path writes the distributed program per-shard,
-    with the collectives explicit (`make_local_step`): each device computes
-    loss/grads over its local shard of the global batch, then pmeans the
-    gradients over the ``data`` mesh axis (ICI) — a line-for-line statement
-    of what DDP's C++ reducer does from backward hooks
-    (`/root/reference/cifar_example_ddp.py:83`), but inside one compiled
-    program. Both paths are equivalence-tested against each other; this one
-    is also the extension point for hand-scheduled comms (e.g. overlapping
-    grad reduction with remaining backward compute). Composes with gradient
-    accumulation: batch leaves gain a leading replicated (accum_steps,)
-    axis, the microbatch dim is the sharded one.
+    Every program runs the same per-update body (`_select_body`; with
+    ``accum_steps > 1`` one update from that many microbatches, the fed
+    leaves gaining a leading replicated (accum_steps,) axis), donates its
+    state, and returns ``(new_state, metrics)``: mean loss, correct count,
+    example count and lr as replicated scalars — what the reference prints
+    (`cifar_example.py:83-87`) and its synced eval metric accumulates
+    (`cifar_example_ddp.py:133`). ``feed`` says what a call is handed and
+    how many steps it runs:
 
-    ``update_sharding="sharded"`` is that extension point exercised: the
-    gradient pmean becomes reduce-scatter → 1/world optimizer update →
-    params all-gather (`make_local_step` docs; Xu et al., PAPERS.md), with
-    ``optimizer`` a `train.optim.ShardedUpdate` and the TrainState's
-    opt_state living sharded over ``data`` (in/out specs P(DATA_AXIS) —
-    per-replica optimizer memory ~1/world). ``collective_dtype="bf16"``
-    additionally compresses the reduce-scatter wire format (EQuARX-style).
+    - ``"batch"``: ``step(state, batch)``, the device-placed global batch
+      (leading dim sharded over ``data``), one step.
+    - ``"window"``: ``loop(state, batches)``, ``num_steps`` steps in one
+      dispatch (the reference's eager loop pays a launch every step,
+      `/root/reference/cifar_example_ddp.py:94-107`): a ``lax.scan`` over
+      batches with the scan axis in front, (pool, [accum_steps,]
+      global_batch, ...). A pool smaller than ``num_steps`` is cycled
+      modularly inside the program, so HBM cost stays constant in
+      ``num_steps``.
+    - ``"resident"``: ``loop(state, data, idx)``: ``data`` is
+      `DataPipeline.resident_data()`, the train set staged in HBM once
+      (replicated, rows flat — that method says why — and not donated),
+      ``idx`` int32 indices with the scan axis in front: ~KBs a dispatch
+      where the reference's DataLoader ships the batch
+      (`/root/reference/cifar_example.py:46-52`). Each scanned step gathers
+      its batch on the device (`gather_rows`: the indices are sharded, so
+      every device gathers its own examples) and ``sample_shapes``
+      (`DataPipeline.sample_shapes`) gives each row its shape back.
 
-    BatchNorm models must be constructed with ``axis_name=DATA_AXIS`` so
-    batch statistics sync across shards (the `shard_map` analogue of the
-    global-batch stats GSPMD computes automatically — sync-BN semantics).
+    The scanned feeds return their metrics stacked by step; all three
+    follow one trajectory (equivalence-tested).
+
+    The exchange. By default GSPMD *infers* the gradient all-reduce from
+    the shardings (batch sharded, state replicated, the loss a mean over
+    the logical batch). ``explicit=True`` states the same program per shard
+    under `shard_map` with the collectives written out (`make_local_step`,
+    which documents ``update_sharding``, ``collective_dtype``,
+    ``quant_block_size``, ``quant_error_feedback`` and ``bucket_mb``): what
+    DDP's C++ reducer does from backward hooks
+    (`/root/reference/cifar_example_ddp.py:83`), equivalence-tested against
+    the inferred form, and the extension point for hand-scheduled comms.
+    ``update_sharding="sharded"`` is that point used (``optimizer`` a
+    `train.optim.ShardedUpdate`, the opt state sharded over ``data``), and
+    ``explicit=None`` means explicit exactly then. BatchNorm models under
+    explicit collectives are built with ``axis_name=DATA_AXIS`` (sync-BN:
+    the global-batch statistics GSPMD computes by itself). Replication
+    checking stays on: a rank-varying output (a forgotten pmean on a new
+    metric) is a trace-time error, not device 0's answer.
+
+    ``sentinel=True`` (guard.enabled, docs/RESILIENCE.md "Guardrails")
+    compiles the on-device health summary and the guarded update in: the
+    program takes a last argument ``guard_in`` (`default_guard_in`:
+    replicated scalars, not donated, one for every step of a window, since
+    the host observes window boundaries only) and metrics gain
+    ``loss_raw`` / ``grad_norm`` / ``applied``. A step that trips the guard
+    emits its state unchanged, and a window's remaining steps go on from
+    there. Off, the compiled HLO is the pre-guardrails program (the DP304
+    fingerprint is digest-identical).
     """
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     from tpu_dp.parallel.dist import DATA_AXIS, data_axis_size
 
-    repl = replicated_sharding(mesh)
-    repl_spec = P()
-    state_spec = _state_specs(update_sharding)
-    state_sh = _state_shardings(mesh, update_sharding)
-    if accum_steps == 1:
-        batch_sh = batch_sharding(mesh)
-        batch_spec = P(DATA_AXIS)
-    else:
-        batch_sh = scan_batch_sharding(mesh)
-        batch_spec = P(None, DATA_AXIS)
-
-    local_step = make_local_step(
-        model, optimizer, schedule, use_pallas_xent=use_pallas_xent,
-        accum_steps=accum_steps, augment_fn=augment_fn,
-        world=data_axis_size(mesh), axis_name=DATA_AXIS,
+    if feed not in FEEDS:
+        raise ValueError(f"feed must be one of {FEEDS}, got {feed!r}")
+    if feed == "batch" and num_steps != 1:
+        raise ValueError(
+            f"feed='batch' runs one step a call, got num_steps={num_steps}; "
+            "a window is feed='window' or feed='resident'"
+        )
+    if feed == "resident" and sample_shapes is None:
+        raise ValueError(
+            "feed='resident' needs sample_shapes "
+            "(DataPipeline.sample_shapes): the staged rows are flat"
+        )
+    if explicit is None:
+        explicit = update_sharding == "sharded"
+    exchange = dict(
         update_sharding=update_sharding, collective_dtype=collective_dtype,
         quant_block_size=quant_block_size,
-        quant_error_feedback=quant_error_feedback,
-        bucket_mb=bucket_mb,
-        sentinel=sentinel,
+        quant_error_feedback=quant_error_feedback, bucket_mb=bucket_mb,
     )
+    if explicit:
+        body = make_local_step(
+            model, optimizer, schedule, use_pallas_xent=use_pallas_xent,
+            accum_steps=accum_steps, augment_fn=augment_fn,
+            world=data_axis_size(mesh), axis_name=DATA_AXIS,
+            sentinel=sentinel, **exchange,
+        )
+    else:
+        _parse_exchange(optimizer, **exchange)
+        if update_sharding == "sharded":
+            raise ValueError(
+                "update_sharding='sharded' needs explicit collectives; "
+                "GSPMD infers the replicated all-reduce only"
+            )
+        body = _select_body(model, optimizer, schedule,
+                            _select_loss_impl(use_pallas_xent), augment_fn,
+                            accum_steps, sentinel=sentinel)
 
-    # Replication checking stays ON: an output that is rank-varying (a
-    # forgotten pmean/psum on a new metric) is a trace-time error instead of
-    # a silent wrong answer from device 0's shard.
-    sharded = _shard_map(
-        local_step,
-        mesh=mesh,
-        in_specs=(state_spec, batch_spec) + ((repl_spec,) if sentinel else ()),
-        out_specs=(state_spec, repl_spec),
-    )
+    # One window's guard_in serves each of its steps.
+    def per_step(guard_in):
+        if guard_in is None:
+            return body
+        return lambda st, mb: body(st, mb, guard_in)
+
+    if feed == "batch":
+        run = body
+    elif feed == "window":
+        def loop(state: TrainState, batches, guard_in=None):
+            step_body = per_step(guard_in)
+            pool = jax.tree_util.tree_leaves(batches)[0].shape[0]
+            if pool == num_steps:
+                return jax.lax.scan(step_body, state, batches,
+                                    length=num_steps)
+
+            def indexed_body(st, i):
+                mb = jax.tree_util.tree_map(
+                    lambda x: jax.lax.dynamic_index_in_dim(
+                        x, i % pool, keepdims=False
+                    ),
+                    batches,
+                )
+                return step_body(st, mb)
+
+            return jax.lax.scan(
+                indexed_body, state, jnp.arange(num_steps, dtype=jnp.int32)
+            )
+
+        run = loop
+    else:
+        def loop(state: TrainState, data, idx, guard_in=None):
+            step_body = per_step(guard_in)
+
+            def indexed_body(st, idx_step):
+                return step_body(st, gather_rows(data, idx_step,
+                                                 sample_shapes))
+
+            return jax.lax.scan(indexed_body, state, idx, length=num_steps)
+
+        run = loop
+
+    # What a call is handed after the state: the staged data set whole on
+    # every device, then the fed leaves with the scan axis and the
+    # microbatch-stack axis (where there is one) in front and the batch dim
+    # sharded over data, then the guard's scalars.
+    prefix_dims = (feed != "batch") + (accum_steps > 1)
+    fed = [P()] if feed == "resident" else []
+    fed.append(P(*([None] * prefix_dims), DATA_AXIS))
+    if sentinel:
+        fed.append(P())
+    state_sh = _state_shardings(mesh, update_sharding)
+    if explicit:
+        state_spec = _state_specs(update_sharding)
+        run = _shard_map(run, mesh=mesh, in_specs=(state_spec, *fed),
+                         out_specs=(state_spec, P()))
     return jax.jit(
-        sharded,
-        in_shardings=(state_sh, batch_sh) + ((repl,) if sentinel else ()),
-        out_shardings=(state_sh, repl),
+        run,
+        in_shardings=(state_sh,
+                      *(NamedSharding(mesh, spec) for spec in fed)),
+        out_shardings=(state_sh, replicated_sharding(mesh)),
         donate_argnums=(0,),
     )
 

@@ -24,7 +24,7 @@ import functools
 import json
 import time
 from pathlib import Path
-from typing import Any
+from typing import Any, Callable, NamedTuple
 
 import jax
 import numpy as np
@@ -53,6 +53,23 @@ from tpu_dp.utils import (
 def _unstack(stacked, n):
     """Lazy per-step views over a window's stacked metrics — no host sync."""
     return tuple({k: v[j] for k, v in stacked.items()} for j in range(n))
+
+
+class _Program(NamedTuple):
+    """One compiled train program as the loop calls it (`Trainer._program`).
+
+    ``run(state, *fed, *guard_args)`` is the (recompile-guarded) program,
+    ``tag`` its name to the cost registry, the efficiency meter and the
+    guard's reports, ``stacked`` whether its metrics come back with a
+    leading step axis (the scanned feeds) or as one step's scalars."""
+
+    run: Callable
+    tag: str
+    stacked: bool
+
+    def steps(self, out, n: int):
+        """Per-step views of a dispatch's metrics — lazy, no host sync."""
+        return _unstack(out, n) if self.stacked else (out,)
 
 
 def _iso_ts(epoch_seconds: float) -> str:
@@ -825,10 +842,10 @@ class Trainer:
             raise ValueError(
                 f"optim.name must be sgd|adamw, got {cfg.optim.name!r}")
         # Sharded mode wraps the optimizer so its state initializes — and
-        # persists — sharded over the data axis; the train step then routes
-        # through the explicit-collectives factory that reduce-scatters
-        # grads and all-gathers updated params. The replicated default
-        # keeps the GSPMD path.
+        # persists — sharded over the data axis; `make_train_step` then
+        # states the collectives explicitly (reduce-scatter of grads,
+        # all-gather of updated params). The replicated default keeps the
+        # all-reduce GSPMD infers.
         if us == "sharded":
             from tpu_dp.train.optim import shard_optimizer
 
@@ -839,29 +856,24 @@ class Trainer:
             cfg.optim.schedule, cfg.optim.lr, total_steps,
             int(cfg.optim.warmup_epochs * steps_per_epoch), cfg.optim.final_lr,
         )
-        if us == "sharded":
-            from tpu_dp.train.step import make_train_step_shard_map
-
-            self.train_step = self._guarded(
-                "train_step", make_train_step_shard_map(
-                    self.model, self.optimizer, self.mesh, self.schedule,
-                    use_pallas_xent=cfg.train.pallas_xent,
-                    accum_steps=cfg.optim.grad_accum_steps,
-                    augment_fn=augment_fn,
-                    update_sharding=us,
-                    collective_dtype=cfg.train.collective_dtype or None,
-                    quant_block_size=cfg.train.quant_block_size,
-                    bucket_mb=cfg.train.bucket_mb,
-                    sentinel=self.guard_enabled,
-                ))
-        else:
-            self.train_step = self._guarded("train_step", make_train_step(
-                self.model, self.optimizer, self.mesh, self.schedule,
-                use_pallas_xent=cfg.train.pallas_xent,
-                accum_steps=cfg.optim.grad_accum_steps,
-                augment_fn=augment_fn,
-                sentinel=self.guard_enabled,
-            ))
+        # Every train program is `make_train_step` over these keywords;
+        # `_program` adds the feed and the window's length.
+        self._step_kwargs = dict(
+            model=self.model, optimizer=self.optimizer, mesh=self.mesh,
+            schedule=self.schedule,
+            use_pallas_xent=cfg.train.pallas_xent,
+            accum_steps=cfg.optim.grad_accum_steps,
+            augment_fn=augment_fn,
+            sentinel=self.guard_enabled,
+            update_sharding=us,
+            collective_dtype=cfg.train.collective_dtype or None,
+            quant_block_size=cfg.train.quant_block_size,
+            bucket_mb=cfg.train.bucket_mb,
+        )
+        # The single-batch program, whatever the feed: the DP304
+        # fingerprint, the cost analysis and the comm profiler lower it.
+        self.train_step = self._guarded(
+            "train_step", make_train_step(**self._step_kwargs))
         eval_input_fn = None
         if getattr(augment_fn, "in_eval", False):
             from tpu_dp.data.noise import EVAL_STEP
@@ -879,27 +891,6 @@ class Trainer:
             # log cadence and HBM batch staging reasonable.
             spc = min(24, steps_per_epoch) if cfg.data.drop_remainder else 1
         self.steps_per_call = max(1, spc)
-        self.multi_step = None
-        if self.steps_per_call > 1:
-            from tpu_dp.train.step import make_multi_step
-
-            # Composes with gradient accumulation (scan-of-scan): each
-            # window element is one accumulated optimizer update, so
-            # BASELINE config 5 (global batch 4096) runs windowed on a
-            # small mesh — both the dispatch-RTT and the HBM amortization
-            # at once.
-            self.multi_step = self._guarded("multi_step", make_multi_step(
-                self.model, self.optimizer, self.mesh, self.schedule,
-                num_steps=self.steps_per_call,
-                use_pallas_xent=cfg.train.pallas_xent,
-                augment_fn=augment_fn,
-                accum_steps=cfg.optim.grad_accum_steps,
-                update_sharding=us,
-                collective_dtype=cfg.train.collective_dtype or None,
-                quant_block_size=cfg.train.quant_block_size,
-                bucket_mb=cfg.train.bucket_mb,
-                sentinel=self.guard_enabled,
-            ))
 
         # Device-resident feed (VERDICT r4 next-steps #3): stage the train
         # set in HBM once; per-window dispatch ships only indices. The
@@ -909,7 +900,7 @@ class Trainer:
         # Staging is lazy (`resident_train` property): eval-only or tooling
         # constructions never pay the host→HBM transfer (ADVICE r5).
         self._resident_train = None
-        self._resident_loops: dict[int, Any] = {}
+        self._programs: dict[int, _Program] = {}
         mode = cfg.data.device_resident
         self._resident_enabled = mode == "on" or (
             mode == "auto"
@@ -1129,12 +1120,11 @@ class Trainer:
         """Stamp this topology's per-step program cost into the registry.
 
         One optimizer step costs the same FLOPs whether it is dispatched
-        per-step, windowed (`multi_step`) or resident, so one entry is
-        registered under "train_step" and aliased to the other tags the
-        hot loop routes through. Source is the analytic per-model
-        estimate (`tpu_dp.obs.costs`); ``obs.measure_flops=true`` upgrades
-        it to XLA's cost analysis of the real compiled step — the exact
-        resolution order bench.py uses, now shared
+        per-step, windowed or resident, so one entry is registered under
+        "train_step" and `_program` aliases each program's tag to it.
+        Source is the analytic per-model estimate (`tpu_dp.obs.costs`);
+        ``obs.measure_flops=true`` upgrades it to XLA's cost analysis of
+        the real compiled step — the exact resolution order bench.py uses
         (docs/OBSERVABILITY.md "Efficiency accounting").
         """
         from tpu_dp.obs import costs
@@ -1166,9 +1156,8 @@ class Trainer:
             # one tag per world the run passed through, so post-hoc MFU
             # questions ("was the shrunk mesh efficient?") resolve per
             # shape instead of against whatever topology ended the run.
-            for tag in ("multi_step", f"multi_step[w{self.steps_per_call}]",
-                        f"train_step@w{dist.data_axis_size(self.mesh)}"):
-                costs.registry.alias(tag, "train_step")
+            costs.registry.alias(
+                f"train_step@w{dist.data_axis_size(self.mesh)}", "train_step")
             from tpu_dp.obs.counters import counters as _c
 
             _c.gauge("obs.flops_per_step_per_chip",
@@ -1591,30 +1580,39 @@ class Trainer:
         return (self.cfg.data.batch_size * self.ctx.process_count
                 * self.cfg.optim.grad_accum_steps)
 
+    def _program(self, n: int) -> _Program:
+        """The train program that a dispatch of ``n`` steps calls (cached;
+        an epoch uses at most two sizes: steps_per_call and 1). The feed is
+        decided here and nowhere else: resident when the data set is
+        staged on the device, else one placed batch or a window of them."""
+        prog = self._programs.get(n)
+        if prog is None:
+            if self._resident_enabled:
+                # A scan of length 1 at n == 1 too: stacked all the same.
+                tag, stacked = f"resident_loop[w{n}]", True
+                run = self._guarded(tag, self._resident_loop(n))
+            elif n == 1:
+                tag, stacked, run = "train_step", False, self.train_step
+            else:
+                tag, stacked = "multi_step", True
+                run = self._guarded(tag, make_train_step(
+                    **self._step_kwargs, feed="window", num_steps=n))
+            if tag != "train_step":
+                from tpu_dp.obs import costs
+
+                # One optimizer step costs the same however it is fed.
+                costs.registry.alias(tag, "train_step")
+            prog = self._programs[n] = _Program(run, tag, stacked)
+        return prog
+
     def _resident_loop(self, n: int):
-        """Compiled resident window program for window size ``n`` (cached;
-        an epoch uses at most two sizes: steps_per_call and 1)."""
-        loop = self._resident_loops.get(n)
-        if loop is None:
-            from tpu_dp.train.step import make_multi_step_resident
-
-            from tpu_dp.obs import costs as _costs
-
-            _costs.registry.alias(f"resident_loop[w{n}]", "train_step")
-            loop = self._guarded(f"resident_loop[w{n}]", make_multi_step_resident(
-                self.model, self.optimizer, self.mesh, self.schedule,
-                num_steps=n, sample_shapes=self.train_pipe.sample_shapes,
-                use_pallas_xent=self.cfg.train.pallas_xent,
-                augment_fn=self._augment_fn,
-                accum_steps=self.cfg.optim.grad_accum_steps,
-                update_sharding=self.update_sharding,
-                collective_dtype=self.cfg.train.collective_dtype or None,
-                quant_block_size=self.cfg.train.quant_block_size,
-                bucket_mb=self.cfg.train.bucket_mb,
-                sentinel=self.guard_enabled,
-            ))
-            self._resident_loops[n] = loop
-        return loop
+        """The resident feed's program for a window of ``n`` steps. A
+        method of its own because `benchmark/tests/test_correct.py`
+        replaces it by this name to plant a fault under the timed path;
+        the loop reaches it through `_program` alone."""
+        return make_train_step(
+            **self._step_kwargs, feed="resident", num_steps=n,
+            sample_shapes=self.train_pipe.sample_shapes)
 
     def train_epoch(self, epoch: int, start_step: int = 0) -> dict[str, float]:
         """One epoch of training; ``start_step`` resumes it mid-way.
@@ -1647,12 +1645,13 @@ class Trainer:
         i = start_step - 1
         done = base + start_step  # epoch steps completed (snapshot meta)
         self._epoch_done = done
+        # Resident: indices in, and the staged data set goes with every
+        # call, never re-crossing the host→device link.
         if self.resident_train is not None:
-            items = pipe.index_windows(
-                self.steps_per_call, skip_steps=start_step)
+            staged, windows = (self.resident_train,), pipe.index_windows
         else:
-            items = pipe.windows(
-                self.steps_per_call, skip_steps=start_step)
+            staged, windows = (), pipe.windows
+        items = windows(self.steps_per_call, skip_steps=start_step)
         # Telemetry (train.obs != off): `spans.begin` ends one span and
         # opens the next, so the spans tile the iteration (obs/spans.py);
         # h2d (block on the placed batch) and device (a scalar fetch, the
@@ -1660,7 +1659,6 @@ class Trainer:
         # only obs mode that adds a host sync, which is why it is opt-in.
         spans = self.spans
         obs_full = self.obs_mode == "full"
-        resident = self.resident_train is not None
         # The moment the last epoch's fence returned, once: an epoch a
         # hook raised out of leaves none behind for its re-entry.
         fence_t, self._fence_t = self._fence_t, None
@@ -1696,26 +1694,15 @@ class Trainer:
                     jax.block_until_ready(item)
                 self._inflight.before_dispatch(first_of_epoch)
                 spans.begin("dispatch")
-            if resident:
-                # Indices in, stacked metrics out — the dataset never
-                # re-crosses the host→device link.
-                self.state, out = self._resident_loop(n)(
-                    self.state, self.resident_train, item, *guard_args
-                )
-            elif n == 1:
-                self.state, out = self.train_step(self.state, item,
-                                                  *guard_args)
-            else:
-                # One dispatch, n optimizer steps (device-side scanned loop).
-                self.state, out = self.multi_step(self.state, item,
-                                                  *guard_args)
-            stacked = resident or n > 1
+            prog = self._program(n)
+            self.state, out = prog.run(self.state, *staged, item,
+                                       *guard_args)
             if spans is not None:
                 last_rec = self._window_telemetry(
-                    n, out, stacked, fence_t if first_of_epoch else None)
+                    prog, n, out, fence_t if first_of_epoch else None)
                 first_of_epoch = False
                 spans.begin("accumulate")
-            window = _unstack(out, n) if stacked else (out,)
+            window = prog.steps(out, n)
             for m in window:
                 i += 1
                 # On-device async adds; no host sync inside the loop.
@@ -1809,12 +1796,12 @@ class Trainer:
         else:
             _obs_counters.gauge("throughput.items_per_sec", rate)
 
-    def _window_telemetry(self, n: int, out, stacked: bool,
+    def _window_telemetry(self, prog: _Program, n: int, out,
                           fence_t: float | None) -> dict:
         """The ``device`` (full only) and ``telemetry`` spans of one
         dispatched window: the fence, the window's records, the efficiency
         gauges and, at full, the per-step `metrics.jsonl` lines. ``out`` is
-        the step's metrics (``stacked`` over the window's steps);
+        the metrics of ``prog``'s dispatch of ``n`` steps;
         ``fence_t`` is when the last epoch's fence returned, given with an
         epoch's first window. Returns the window's last record."""
         spans = self.spans
@@ -1823,7 +1810,7 @@ class Trainer:
         self._inflight.dispatched(out["loss"], n)
         if obs_full:
             # scalar fetch: honest fence
-            float(out["loss"][-1] if stacked else out["loss"])
+            float(out["loss"][-1] if prog.stacked else out["loss"])
             self.meter.mark()  # the same fence feeds the meter
             spans.begin("telemetry")
             self._publish_rate()
@@ -1850,11 +1837,7 @@ class Trainer:
             # data_wait: at obs=full it ends on the device fence (honest
             # device time); at basic on the dispatch's return, a dispatch
             # rate (documented in OBSERVABILITY.md).
-            if self.resident_train is not None:
-                tag = f"resident_loop[w{n}]"
-            else:
-                tag = "train_step" if n == 1 else "multi_step"
-            eff = self._eff.observe(tag, n, wall_ms, data_wait_ms)
+            eff = self._eff.observe(prog.tag, n, wall_ms, data_wait_ms)
             self._last_efficiency = eff
             _obs_counters.gauge("obs.step_time_ms", eff["step_time_ms"])
             _obs_counters.gauge("obs.goodput", eff["goodput"])
@@ -1868,8 +1851,7 @@ class Trainer:
             # existing fence) so the same window's records carry them.
             if self._quant_enabled:
                 self._publish_quant_counters(
-                    _unstack(out, n) if stacked else (out,),
-                    self._host_step + 1)
+                    prog.steps(out, n), self._host_step + 1)
             snap = _obs_counters.snapshot()
             for r in new_recs:
                 rec = {
@@ -2447,7 +2429,7 @@ class Trainer:
         # the old distributed context (graveyard semantics, see
         # `dist.abandon_distributed`) and bootstrap the new epoch's.
         self._resident_train = None
-        self._resident_loops = {}
+        self._programs = {}
         self._elastic_tail = None
         self.state = None
         if self._comm_profiler is not None:
