@@ -211,10 +211,11 @@ def test_epoch_spans_land_once_an_epoch(tmp_path, steps_per_call):
         spans = by_step[first]
         # From the fence's return to the first dispatch's: the caller's
         # reading and the one that opens data_wait, then the window's
-        # data_wait, pre_dispatch and dispatch.
-        assert spans["epoch_gap"] == pytest.approx(5.0)
+        # data_wait, pre_dispatch, inflight_wait and dispatch.
+        assert spans["epoch_gap"] == pytest.approx(6.0)
         assert spans["epoch_gap"] == pytest.approx(2.0 + steps_per_call * (
-            spans["data_wait"] + spans["pre_dispatch"] + spans["dispatch"]))
+            spans["data_wait"] + spans["pre_dispatch"]
+            + spans["inflight_wait"] + spans["dispatch"]))
         assert fence_t is not None
     assert tr._fence_t == fences[2]
     # The Perfetto export ends the gap where the first dispatch ends.
@@ -282,8 +283,8 @@ def test_profiler_trace_holds_the_spans_with_their_step(tmp_path):
                 if ev.name.startswith("tpu_dp."):
                     stats = dict(ev.stats)
                     found.setdefault(ev.name, set()).add(int(stats["step"]))
-    for name in ("data_wait", "pre_dispatch", "dispatch", "telemetry",
-                 "accumulate", "hooks"):
+    for name in ("data_wait", "pre_dispatch", "inflight_wait", "dispatch",
+                 "telemetry", "accumulate", "hooks"):
         assert found["tpu_dp." + name] >= {4, 5, 6}, (name, found)
     assert found["tpu_dp.epoch_fence"] == {6}
 

@@ -580,7 +580,7 @@ def test_trainer_obs_full_end_to_end(tmp_path):
         # The line is written inside `telemetry`: it holds the spans that
         # had ended by then; the ring (the rollup below) holds them all.
         assert set(r["spans"]) == {"data_wait", "pre_dispatch", "h2d",
-                                   "dispatch", "device"}
+                                   "inflight_wait", "dispatch", "device"}
         assert r["spans"]["device"] > 0.0  # full mode fences per window
         assert isinstance(r["counters"], dict)
     epoch_rec = next(r for r in records if "epoch" in r)

@@ -13,14 +13,19 @@ order, so their sum is the iteration's wall time (`STEP_SPANS`):
 - ``pre_dispatch`` — the ``on_window_start`` hooks and the guard's input;
 - ``h2d``          — (``full`` only) waiting for the batch's host→device
   transfer to land (zero when prefetch overlapped it);
+- ``inflight_wait`` — the loop's own bound on its lead: blocked until the
+  dispatch about to be made will be at most the `trainer.MAX_INFLIGHT`th
+  unfinished (about a step's time whenever the device is the slower of the
+  two, which is the aim);
 - ``dispatch``     — the host's own cost of launching the compiled step;
 - ``device``       — (``full`` only) from dispatch return to a
   device→host scalar fetch, the same fence discipline as
   `ThroughputMeter.mark()` (`tpu_dp/utils/meter.py`);
 - ``telemetry``    — what the recorder, the efficiency meter and the
   gauges cost themselves;
-- ``accumulate``   — unstacking the window's metrics, the on-device
-  running sums, the meter, the log line;
+- ``accumulate``   — keeping the dispatch's metrics for the host's sums
+  (unstacking a window's for the hooks), the meter, the log line and its
+  fetch;
 - ``hooks``        — the ``on_step_end`` sweep.
 
 Two more are per epoch, each on one record (`EPOCH_SPANS`):
@@ -52,8 +57,8 @@ from typing import Callable, Iterable, Mapping
 from tpu_dp.obs.counters import counters as _registry
 
 #: The spans that tile one iteration of the trainer's loop, in loop order.
-STEP_SPANS = ("data_wait", "pre_dispatch", "h2d", "dispatch", "device",
-              "telemetry", "accumulate", "hooks")
+STEP_SPANS = ("data_wait", "pre_dispatch", "h2d", "inflight_wait", "dispatch",
+              "device", "telemetry", "accumulate", "hooks")
 #: Per-epoch spans, each on one record: the last step's, the first step's.
 EPOCH_SPANS = ("epoch_fence", "epoch_gap")
 
@@ -271,7 +276,11 @@ class InflightSteps:
     ``loop.dispatch_onto_idle`` (+1 when nothing was left: the previous
     step had finished, the device had nothing queued). An epoch's first
     dispatch follows a fence that drained the device: ``epoch_gap`` counts
-    it, and it is left out here.
+    it, and it is left out here. The loop asks on arrival, before its
+    ``inflight_wait`` (which then holds the unfinished, the new dispatch
+    among them, to `trainer.MAX_INFLIGHT`), so ``loop.inflight_steps`` reads
+    that bound wherever the device is the slower, and less wherever the
+    host is.
     """
 
     def __init__(self, registry=_registry):

@@ -934,8 +934,11 @@ def make_train_step(
       every device gathers its own examples) and ``sample_shapes``
       (`DataPipeline.sample_shapes`) gives each row its shape back.
 
-    The scanned feeds return their metrics stacked by step; all three
-    follow one trajectory (equivalence-tested).
+    A program of several steps returns its metrics stacked by step; one of
+    ``num_steps == 1`` returns that step's own, whatever the feed, so its
+    caller never indexes a stack of one (outside the program ``v[0]`` is a
+    device program of its own, launched between two steps). All three
+    feeds follow one trajectory (equivalence-tested).
 
     The exchange. By default GSPMD *infers* the gradient all-reduce from
     the shardings (batch sharded, state replicated, the loss a mean over
@@ -1011,6 +1014,12 @@ def make_train_step(
             return body
         return lambda st, mb: body(st, mb, guard_in)
 
+    def scan(step_body, state, xs):
+        state, metrics = jax.lax.scan(step_body, state, xs, length=num_steps)
+        if num_steps == 1:  # the step's own metrics, indexed where it is free
+            metrics = jax.tree_util.tree_map(lambda v: v[0], metrics)
+        return state, metrics
+
     if feed == "batch":
         run = body
     elif feed == "window":
@@ -1018,8 +1027,7 @@ def make_train_step(
             step_body = per_step(guard_in)
             pool = jax.tree_util.tree_leaves(batches)[0].shape[0]
             if pool == num_steps:
-                return jax.lax.scan(step_body, state, batches,
-                                    length=num_steps)
+                return scan(step_body, state, batches)
 
             def indexed_body(st, i):
                 mb = jax.tree_util.tree_map(
@@ -1030,9 +1038,8 @@ def make_train_step(
                 )
                 return step_body(st, mb)
 
-            return jax.lax.scan(
-                indexed_body, state, jnp.arange(num_steps, dtype=jnp.int32)
-            )
+            return scan(indexed_body, state,
+                        jnp.arange(num_steps, dtype=jnp.int32))
 
         run = loop
     else:
@@ -1043,7 +1050,7 @@ def make_train_step(
                 return step_body(st, gather_rows(data, idx_step,
                                                  sample_shapes))
 
-            return jax.lax.scan(indexed_body, state, idx, length=num_steps)
+            return scan(indexed_body, state, idx)
 
         run = loop
 

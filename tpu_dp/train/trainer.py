@@ -10,18 +10,21 @@ remainder divisor), end-of-training weights export (`:118-119`), and a
 synced-accuracy eval (`:124-136`) — plus what the reference lacks: resume,
 throughput metering, and profiler hooks (SURVEY.md §5).
 
-Hot-loop discipline: the Python loop only *dispatches* compiled steps and
-accumulates the returned replicated scalars with on-device adds — it never
-blocks on a device→host transfer except at log boundaries and epoch ends, so
-host dispatch runs ahead of device execution and the input pipeline's
-prefetch overlaps (unlike the reference, whose `loss.item()` syncs every
-step, `cifar_example.py:83`).
+Hot-loop discipline: between an epoch's first dispatch and its fence the
+Python loop launches no device program but the compiled step. It keeps the
+returned replicated scalars as they came and adds them up on the host where
+it fetches anyway, at log boundaries and epoch ends (`_EpochSums`), and it
+stays `MAX_INFLIGHT` dispatches ahead of the device, so the input
+pipeline's prefetch overlaps and the device never waits (unlike the
+reference, whose `loss.item()` syncs every step, `cifar_example.py:83`).
 """
 
 from __future__ import annotations
 
+import collections
 import functools
 import json
+import operator
 import time
 from pathlib import Path
 from typing import Any, Callable, NamedTuple
@@ -60,16 +63,65 @@ class _Program(NamedTuple):
 
     ``run(state, *fed, *guard_args)`` is the (recompile-guarded) program,
     ``tag`` its name to the cost registry, the efficiency meter and the
-    guard's reports, ``stacked`` whether its metrics come back with a
-    leading step axis (the scanned feeds) or as one step's scalars."""
+    guard's reports."""
 
     run: Callable
     tag: str
-    stacked: bool
 
-    def steps(self, out, n: int):
-        """Per-step views of a dispatch's metrics — lazy, no host sync."""
-        return _unstack(out, n) if self.stacked else (out,)
+    @staticmethod
+    def steps(out, n: int):
+        """Per-step views of a dispatch's metrics — lazy, no host sync. A
+        program of several steps returns them stacked by step, one of a
+        single step that step's own (`make_train_step`)."""
+        return _unstack(out, n) if n > 1 else (out,)
+
+
+#: Dispatches unfinished at most, the one the loop has just launched among
+#: them. Two: one running and one queued keep the device fed across the
+#: host's own work, and hooks (guard, snapshots, preemption, a profiler's
+#: window) act at the host's step, so every one more would make them answer
+#: a step later.
+MAX_INFLIGHT = 2
+
+
+class _EpochSums:
+    """An epoch's running sums (loss, correct, a model's counters), added up
+    on the host so that the loop launches no device add between two steps.
+
+    `keep` takes a dispatch's metrics as the program returned them: device
+    arrays, a step's own or stacked by step. `fetch` brings what is kept to
+    the host with one `jax.device_get` (the loop calls it where it
+    synchronises anyway: the log line and the epoch's fence) and adds it up
+    a step at a time in the arrays' own dtype: the order and the precision
+    of a running sum on the device, so the same bits.
+    """
+
+    NAMES = ("loss", "correct", "counters")
+
+    def __init__(self):
+        # Dispatches not fetched yet, oldest first: (steps, their metrics).
+        self.kept: list[tuple[int, dict]] = []
+        self.total: dict[str, Any] = {}  # name -> sum over the fetched steps
+        self._unlogged: list = []  # fetched losses no log line has shown
+
+    def keep(self, out, n: int) -> None:
+        self.kept.append((n, {k: out[k] for k in self.NAMES if k in out}))
+
+    def fetch(self) -> None:
+        host, self.kept = jax.device_get(self.kept), []
+        for n, metrics in host:
+            for step in _Program.steps(metrics, n):
+                self._unlogged.append(step["loss"])
+                for name, row in step.items():
+                    self.total[name] = (
+                        row if name not in self.total
+                        else self.total[name] + row)
+
+    def running_loss(self, steps: int) -> float:
+        """Mean loss of the ``steps`` oldest steps no log line has shown."""
+        self.fetch()
+        rows, self._unlogged = self._unlogged[:steps], self._unlogged[steps:]
+        return float(functools.reduce(operator.add, rows)) / steps
 
 
 def _iso_ts(epoch_seconds: float) -> str:
@@ -1588,13 +1640,12 @@ class Trainer:
         prog = self._programs.get(n)
         if prog is None:
             if self._resident_enabled:
-                # A scan of length 1 at n == 1 too: stacked all the same.
-                tag, stacked = f"resident_loop[w{n}]", True
+                tag = f"resident_loop[w{n}]"
                 run = self._guarded(tag, self._resident_loop(n))
             elif n == 1:
-                tag, stacked, run = "train_step", False, self.train_step
+                tag, run = "train_step", self.train_step
             else:
-                tag, stacked = "multi_step", True
+                tag = "multi_step"
                 run = self._guarded(tag, make_train_step(
                     **self._step_kwargs, feed="window", num_steps=n))
             if tag != "train_step":
@@ -1602,7 +1653,7 @@ class Trainer:
 
                 # One optimizer step costs the same however it is fed.
                 costs.registry.alias(tag, "train_step")
-            prog = self._programs[n] = _Program(run, tag, stacked)
+            prog = self._programs[n] = _Program(run, tag)
         return prog
 
     def _resident_loop(self, n: int):
@@ -1636,10 +1687,13 @@ class Trainer:
             start_step = tail.skip
         pipe.set_epoch(epoch)  # `cifar_example_ddp.py:92` parity
         gbs = self.global_batch_size
-        run_loss, run_steps = None, 0  # device-side running-loss accumulator
-        # `ep_counters`: the sums of a model's own counters (a step of a
-        # model that publishes some carries them as one vector).
-        ep_loss = ep_correct = ep_counters = None
+        # Local to the epoch: one that a hook raises out of (a rollback, a
+        # regroup) leaves no kept array behind for its re-entry.
+        sums = _EpochSums()
+        # The newest dispatches' losses, oldest first: what the loop's
+        # bound waits on.
+        inflight = collections.deque()
+        run_steps = 0  # steps since the last log line
         ep_steps, ep_count = 0, 0
         step_items = gbs * self._items_per_row
         i = start_step - 1
@@ -1692,43 +1746,44 @@ class Trainer:
                 if obs_full:
                     spans.begin("h2d")
                     jax.block_until_ready(item)
+                # Asked on arrival, before the wait: how far ahead the host
+                # got, which the wait then holds to the bound.
                 self._inflight.before_dispatch(first_of_epoch)
+                spans.begin("inflight_wait")
+            # The loop's bound, behaviour and not telemetry (made at obs=off
+            # too): block, with no launch and no transfer, until the dispatch
+            # about to be made is at most the `MAX_INFLIGHT`th unfinished
+            # (the device retires them in order, so the oldest says it). The
+            # dispatch follows the wake at once: a thread that waits on the
+            # same step (a hook's watcher) gets the interpreter while the
+            # runtime launches, not after the loop's own work.
+            if len(inflight) == MAX_INFLIGHT:
+                inflight.popleft().block_until_ready()
+            if spans is not None:
                 spans.begin("dispatch")
             prog = self._program(n)
             self.state, out = prog.run(self.state, *staged, item,
                                        *guard_args)
+            inflight.append(out["loss"])
             if spans is not None:
                 last_rec = self._window_telemetry(
                     prog, n, out, fence_t if first_of_epoch else None)
                 first_of_epoch = False
                 spans.begin("accumulate")
+            sums.keep(out, n)
             window = prog.steps(out, n)
-            for m in window:
+            for _ in range(n):
                 i += 1
-                # On-device async adds; no host sync inside the loop.
-                run_loss = (
-                    m["loss"] if run_loss is None else run_loss + m["loss"]
-                )
                 run_steps += 1
-                ep_loss = m["loss"] if ep_loss is None else ep_loss + m["loss"]
-                ep_correct = (
-                    m["correct"] if ep_correct is None
-                    else ep_correct + m["correct"]
-                )
-                if "counters" in m:
-                    ep_counters = (
-                        m["counters"] if ep_counters is None
-                        else ep_counters + m["counters"]
-                    )
                 ep_steps += 1
                 ep_count += gbs
                 self.meter.step(step_items)
                 if i % cfg.train.log_every == cfg.train.log_every - 1:
                     # Reference print format (`cifar_example.py:85-86`); the
-                    # float() here is the only sync per log interval.
+                    # fetch here is the only sync per log interval.
                     print0("[%d, %5d] loss: %.3f"
-                           % (epoch + 1, i + 1, float(run_loss) / run_steps))
-                    run_loss, run_steps = None, 0
+                           % (epoch + 1, i + 1, sums.running_loss(run_steps)))
+                    run_steps = 0
                     if self.health is not None:
                         # Rank 0 reads every rank's heartbeat file at the
                         # log cadence (already a sync boundary): stragglers
@@ -1765,18 +1820,20 @@ class Trainer:
                 hook.on_step_end(ev)
         if last_rec is not None:
             spans.begin("epoch_fence", rec=last_rec)
-        if ep_counters is not None:
+        sums.fetch()  # the fence: the epoch's last step has finished
+        if "counters" in sums.total:
             # Published where the epoch's loss is fetched: the same fence.
             totals = dict(zip(self.model.counter_names,
-                              np.asarray(ep_counters, np.float64)))
+                              np.asarray(sums.total["counters"], np.float64)))
             for name, value in totals.items():
                 _obs_counters.inc(name, float(value))
             # What `correct` is a share of, where the model counts it.
             ep_count = int(totals.get(
                 getattr(self.model, "count_counter", None), ep_count))
         stats = {
-            "loss": float(ep_loss) / max(1, ep_steps) if ep_steps else 0.0,
-            "accuracy": float(ep_correct) / ep_count if ep_count else 0.0,
+            "loss": float(sums.total["loss"]) / ep_steps if ep_steps else 0.0,
+            "accuracy": (float(sums.total["correct"]) / ep_count
+                         if ep_count else 0.0),
         }
         if start_step or base:
             # A resumed (or regrouped) epoch's accumulators cover only its
@@ -1809,8 +1866,11 @@ class Trainer:
         dispatched = spans.begin("device" if obs_full else "telemetry")
         self._inflight.dispatched(out["loss"], n)
         if obs_full:
-            # scalar fetch: honest fence
-            float(out["loss"][-1] if prog.stacked else out["loss"])
+            # An honest fence: the whole dispatch retired on every device
+            # (a fetch alone reads one shard of a replicated scalar, and a
+            # hook that stops a profiler would cut the others' last
+            # collective), then a fetch.
+            jax.device_get(jax.block_until_ready(out)["loss"])
             self.meter.mark()  # the same fence feeds the meter
             spans.begin("telemetry")
             self._publish_rate()
