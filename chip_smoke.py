@@ -355,6 +355,54 @@ def phase_kernels():
     print(f"kernel {name}: o err {errs[0]:.1e}, dq err {errs[1]:.1e}, "
           f"dk err {errs[2]:.1e}, dv err {errs[3]:.1e} (of max|ref|)")
 
+    from tpu_dp.models.nemotron_h import ssd_chunked
+    from tpu_dp.ops.ssd_scan import CHUNK, ssd_scan
+
+    # The scan's pair at the hybrid cell's shapes (a row of 8,192 positions,
+    # 64 heads of 64 in 8 groups, state 128) against the chunked form the
+    # compiler gets elsewhere, in float32 on the same operands.
+    length, heads, p, groups, n = 8192, 64, 64, 8, 128
+    keys = jax.random.split(jax.random.PRNGKey(35), 7)
+    x = jax.random.normal(keys[0], (1, length, heads * p)).astype(jnp.bfloat16)
+    b = jax.random.normal(keys[1], (1, length, groups * n)).astype(jnp.bfloat16)
+    c = jax.random.normal(keys[2], (1, length, groups * n)).astype(jnp.bfloat16)
+    delta = jax.nn.softplus(jax.random.normal(keys[3], (1, length, heads)) - 4)
+    a = -jnp.arange(1, heads + 1, dtype=jnp.float32)
+    d = jax.random.normal(keys[4], (heads,))
+    dy = jax.random.normal(keys[5], x.shape)
+
+    def scan_pair(x, delta, a, b, c, d):
+        # a group at a time, [1, groups, L, a group's channels]
+        y = ssd_scan(x, delta, a, b, c, d, groups)
+        return y.swapaxes(1, 2).reshape(x.shape)
+
+    def scan_plain(x, delta, a, b, c, d):
+        x4 = x.reshape(1, length, heads, p)
+        y = ssd_chunked(x4, delta, a, b.reshape(1, length, groups, n),
+                        c.reshape(1, length, groups, n), CHUNK)
+        return (y + d[:, None] * x4).reshape(x.shape)
+
+    def scan_grads(f):
+        def run(*operands):
+            out, vjp = jax.vjp(f, *operands)
+            return (out, *vjp(dy))
+        return run
+
+    name = f"ssd_scan x {x.shape} bf16"
+    got = _assert_kernel(scan_grads(scan_pair), (x, delta, a, b, c, d), name)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(scan_grads(scan_plain))(
+            x.astype(jnp.float32), delta, a, b.astype(jnp.float32),
+            c.astype(jnp.float32), d)
+    # Sums of products of bf16-rounded weights, states and cotangents; dx,
+    # dB and dC are rounded to bf16 on the way out.
+    whats = ("y", "dx", "ddelta", "da", "dB", "dC", "dD")
+    errs = [_close(u, v, 2e-2, f"{name} {what}")
+            for u, v, what in zip(got, want, whats)]
+    print(f"kernel {name}: " + ", ".join(
+        f"{what} err {err:.1e}" for what, err in zip(whats, errs))
+        + " (of max|ref|)")
+
 
 def phase_trainer_pallas_xent(clock, *, model="resnet18", batch=2048,
                               steps=8, extra=()):

@@ -324,13 +324,43 @@ def test_causal_attention_kernel_compiles_for_v5e(one_chip, compiled_kernels):
     assert all("splash_mqa_causal" in k for k in kernels)
 
 
+def _ssd_scan_fwd_bwd(x, delta, a, b, c, d):
+    from tpu_dp.ops.ssd_scan import ssd_scan
+
+    def loss(*operands):
+        return jnp.sum(ssd_scan(*operands, 8))
+    return jax.grad(loss, argnums=tuple(range(6)))(x, delta, a, b, c, d)
+
+
+def test_ssd_scan_kernels_compile_for_v5e(one_chip, compiled_kernels):
+    """The state-space scan's pair, forward and backward, at the hybrid
+    cell's widths (64 heads of 64 in 8 groups, state 128) on a row of 8,192
+    positions: two Mosaic kernels under the one name `ssd_scan_roofline`
+    reads; the temporaries are the entering states (128 MiB) and no array
+    of ``[tokens, heads, 128]`` float32 (512 MiB each)."""
+    from tpu_dp.ops import ssd_scan
+
+    assert ssd_scan.fits(8192, 128, 64, 64, 8, 128)
+    compiled = _compile(
+        _ssd_scan_fwd_bwd, one_chip,
+        ((1, 8192, 4096), jnp.bfloat16), ((1, 8192, 64), jnp.float32),
+        ((64,), jnp.float32), ((1, 8192, 1024), jnp.bfloat16),
+        ((1, 8192, 1024), jnp.bfloat16), ((64,), jnp.float32))
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**29
+    kernels = re.findall(r' custom-call\(.*op_name="([^"]*)/pallas_call"',
+                         compiled.as_text())
+    assert len(kernels) == 2
+    assert all(ssd_scan.NAME in k for k in kernels), kernels
+
+
 def test_the_hybrid_step_compiles_for_v5e_and_fits_the_chip(one_chip,
                                                             compiled_kernels):
     """The whole train step of `nemotron_h` at the published widths and the
     cell's shapes (preset `nemotron3_nano_30b_a3b_ep16`: 666,963,456
     parameters, 2 rows of 8,192 tokens, bfloat16 compute, AdamW with the
     clip), built by the step factory every model trains through, compiled
-    for one described v5e chip: the causal pair is in it, and the state,
+    for one described v5e chip: the causal pair and the scan's pair are in
+    it, and the state,
     the gradient and every temporary together fit the chip's 16 GiB with a
     GiB to spare for what the runtime holds beside a program (the resident
     rows, the loop's metrics)."""
@@ -374,6 +404,11 @@ def test_the_hybrid_step_compiles_for_v5e_and_fits_the_chip(one_chip,
     text = compiled.as_text()
     assert text.count("/splash_mqa_causal_pair/pallas_call") >= 2
     assert "splash_mqa_block_diffusion" not in text
+    # four state-space layers: the scan's forward, its recomputation and
+    # its backward are kernels, and no cumulative sum is the compiler's
+    scans = re.findall(r' custom-call\(.*/ssd_scan_pair/pallas_call"', text)
+    assert len(scans) == 12
+    assert not re.search(r'reduce-window\(.*tpu_dp\.ssm_scan', text)
     mem = compiled.memory_analysis()
     held = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes)

@@ -13,15 +13,23 @@ that states every equation is `benchmark/reference_nemotron_h.py`;
 
 What the TPU decides here:
 
-- **The state-space recurrence runs in its chunked form** (`ssd_chunked`):
-  within a chunk of ``ssm_chunk`` positions the output is a masked product
-  (``C B^T`` of a group, times the heads' decay between the two positions),
-  between chunks a carried state of ``[heads, head_dim, state]``. The result
-  does not depend on the chunk; 128 is the MXU's tile. Decays, cumulative
-  sums, ``softplus``, the carried state and the gated norm are float32, the
-  products' operands the compute dtype. A row that is not whole chunks is
-  padded at its end with steps of ``delta = 0``, which neither decay nor
-  add to the state, and the padding's outputs are cut off.
+- **The state-space recurrence runs in its chunked form**: within a chunk
+  of ``ssm_chunk`` positions the output is a masked product (``C B^T`` of a
+  group, times the heads' decay between the two positions), between chunks
+  a carried state of ``[heads, head_dim, state]``. The result does not
+  depend on the chunk; 128 is the MXU's tile. Decays, cumulative sums,
+  ``softplus``, the carried state and the gated norm are float32, the
+  products' operands the compute dtype. **Through a kernel pair wherever
+  `ssd_scan.runs`** (`tpu_dp/ops/ssd_scan.py`: on a TPU or inside
+  `interpret_kernels()`, rows of whole chunks of 128, a state of whole 128
+  lanes, a group's heads whole lanes; the published widths): a chunk's
+  decay matrix, its ``C B^T`` and the carried state stay in fast memory,
+  where the compiler's form (`ssd_chunked`, every other shape and backend,
+  and the tests' oracle) makes some thirty passes a layer over arrays of
+  ``[tokens, heads, 128]`` float32. The choice is made at trace time from
+  the shapes and the backend. In `ssd_chunked` a row that is not whole
+  chunks is padded at its end with steps of ``delta = 0``, which neither
+  decay nor add to the state, and the padding's outputs are cut off.
 - **Attention goes through the flash kernel pair** under its causal mask
   wherever `parts.kernels_run` (`tpu_dp/ops/flash_block_diffusion.py`: at
   8,192 positions the compiler's own full scores would be 17 GB), and
@@ -30,8 +38,9 @@ What the TPU decides here:
   the shared expert is two dense products beside it, computed once whatever
   the share.
 - A layer is wrapped in `jax.checkpoint` (`run_layer`): its input is saved
-  in the compute dtype, the rest recomputed; a state-space layer goes a row
-  at a time. Head and loss go a row at a time
+  in the compute dtype, the rest recomputed, every row at once: with the
+  scan's temporaries in fast memory a loop over rows costs more in its own
+  buffers than it saves (PERF.md §6, PR 35). Head and loss go a row at a time
   (`parts.rows_head_loss`): position ``t`` is scored against token ``t + 1``,
   the last position against nothing.
 """
@@ -49,6 +58,7 @@ import jax.numpy as jnp
 from tpu_dp.models import parts
 from tpu_dp.models.outputs import RowLoss
 from tpu_dp.models.parts import F32, rms_norm
+from tpu_dp.ops import ssd_scan
 from tpu_dp.ops.flash_block_diffusion import CausalMask, flash_attention
 
 COUNTER_NAMES = parts.MOE_COUNTER_NAMES + ("lm.tokens",)
@@ -126,33 +136,55 @@ def ssd_chunked(x, delta, a_head, b, c, chunk: int):
 
 
 def ssm_mixer(p, u, m: "NemotronH"):
-    """The Mamba-2 mixer on ``u [rows, L, hidden]`` (compute dtype)."""
+    """The Mamba-2 mixer on ``u [rows, L, hidden]`` (compute dtype).
+
+    What is ``[.., inner]`` wide after the scan (``y``, the gate ``z``, the
+    norm's result) is held a group's channels at a time, ``[rows, L, groups,
+    width]`` in the compiler's hands and ``[rows, groups, L, width]`` where
+    the scan's kernels run, which write ``y`` so: the grouped norm then
+    reduces over the last axis of every array as it stands, ``z`` is born
+    in that layout from a product of its own columns of ``in_proj`` and the
+    output projection contracts groups and channels. (With ``y`` as ``[rows,
+    L, inner]`` from the kernels the norm's reshape was a relayout of the
+    whole array, 21 ms a step at the published widths; PERF.md §6, PR 35.)"""
     rows, length, _ = u.shape
     heads, hp, n = m.ssm_heads, m.ssm_head_dim, m.ssm_state
     groups, dtype = m.ssm_groups, m.dtype
     inner, gn = heads * hp, groups * n
+    width = inner // groups
+    by_kernels = ssd_scan.runs(length, m.ssm_chunk, heads, hp, groups, n)
+    grouped = "rgld" if by_kernels else "rlgd"
     with jax.named_scope("tpu_dp.ssm_proj"):
-        zxbcdt = u @ p["in_proj"]["kernel"].astype(dtype)
-        z, xbc, dt = jnp.split(zxbcdt, [inner, 2 * inner + 2 * gn], axis=-1)
+        kernel = p["in_proj"]["kernel"].astype(dtype)
+        z = jnp.einsum(f"rlh,hgd->{grouped}", u,
+                       kernel[:, :inner].reshape(-1, groups, width))
+        xbc, dt = jnp.split(u @ kernel[:, inner:], [inner + 2 * gn], axis=-1)
     with jax.named_scope("tpu_dp.ssm_conv"):
         xbc = jax.nn.silu(causal_conv(xbc, p["conv"]["kernel"],
                                       p["conv"]["bias"])).astype(dtype)
         x, b, c = jnp.split(xbc, [inner, inner + gn], axis=-1)
     with jax.named_scope("tpu_dp.ssm_scan"):
-        x = x.reshape(rows, length, heads, hp)
         delta = jax.nn.softplus(dt.astype(F32) + p["dt_bias"])
-        y = ssd_chunked(x, delta, -jnp.exp(p["A_log"].astype(F32)),
-                        b.reshape(rows, length, groups, n),
-                        c.reshape(rows, length, groups, n), m.ssm_chunk)
-        y = y + p["D"].astype(F32)[:, None] * x.astype(F32)
+        a_head = -jnp.exp(p["A_log"].astype(F32))
+        if by_kernels:
+            y = ssd_scan.ssd_scan(x, delta, a_head, b, c, p["D"], groups)
+        else:
+            x = x.reshape(rows, length, heads, hp)
+            y = ssd_chunked(x, delta, a_head,
+                            b.reshape(rows, length, groups, n),
+                            c.reshape(rows, length, groups, n), m.ssm_chunk)
+            y = y + p["D"].astype(F32)[:, None] * x.astype(F32)
+            y = y.reshape(rows, length, groups, width)
     with jax.named_scope("tpu_dp.ssm_norm"):
         # gate first, then an RMSNorm over each group's channels
-        y = y.reshape(rows, length, inner) * jax.nn.silu(z.astype(F32))
-        y = y.reshape(rows, length, groups, inner // groups)
+        scale = p["norm"]["scale"].reshape(groups, width)
+        y = y * jax.nn.silu(z.astype(F32))
         y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + m.eps)
-        y = (y.reshape(rows, length, inner) * p["norm"]["scale"]).astype(dtype)
+        y = (y * (scale[:, None] if by_kernels else scale)).astype(dtype)
     with jax.named_scope("tpu_dp.ssm_proj"):
-        return y @ p["out_proj"]["kernel"].astype(dtype)
+        out = p["out_proj"]["kernel"].astype(dtype)
+        return jnp.einsum(f"{grouped},gdh->rlh", y,
+                          out.reshape(groups, width, -1))
 
 
 # ---------------------------------------------------------------- attention
@@ -251,19 +283,12 @@ def layer(p, x, kind: str, m: "NemotronH"):
 
 
 def run_layer(p, x, kind: str, m: "NemotronH"):
-    """`layer` under `jax.checkpoint` (its input is saved, the rest
-    recomputed; the flash kernel's output is kept). A state-space layer
-    goes a row at a time, so that the scan's float32 temporaries (some
-    thirty arrays of the size of the row's inner activations) are one
-    row's: at 2 x 8,192 tokens the step holds 1.8 GiB less."""
-    fn = jax.checkpoint(
+    """`layer` under `jax.checkpoint`: its input is saved, the rest
+    recomputed; the flash kernel's output is kept."""
+    return jax.checkpoint(
         functools.partial(layer, kind=kind, m=m),
         policy=jax.checkpoint_policies.save_only_these_names(
-            parts.ATTN_SAVED))
-    if kind != "ssm":
-        return fn(p, x)
-    y, counters = jax.lax.map(lambda row: fn(p, row[None]), x)
-    return y[:, 0], jnp.sum(counters, axis=0)
+            parts.ATTN_SAVED))(p, x)
 
 
 def _inverse_softplus(x):

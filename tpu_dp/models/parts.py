@@ -73,9 +73,7 @@ def kernels_run(length: int, head_dim: int) -> bool:
     they can run here (on a TPU, or inside `interpret_kernels()`)."""
     from tpu_dp.ops import _partition
 
-    return kernel_fits(length, head_dim) and (
-        jax.default_backend() == "tpu"
-        or bool(_partition._interpret_requests))
+    return kernel_fits(length, head_dim) and _partition.kernels_can_run()
 
 
 def attention_piece(q, k, v, k_own, v_own, first: int, block: int,

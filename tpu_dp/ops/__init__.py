@@ -7,6 +7,13 @@ without hand-written kernels; Pallas kernels where XLA underperforms), and
 the host-side runtime pieces — topology introspection and a Gloo-style CPU
 ring allreduce fallback for host coordination off-TPU — are native C++
 (`tpu_dp/ops/native/`), bound via ctypes.
+
+The Pallas kernels, a module each: `xent` (fused softmax cross-entropy),
+`conv_block` (BN-apply + ReLU + conv chains), `qk_norm_rope` (per-head
+RMSNorm and RoPE of q and k), `flash_block_diffusion` (flash attention under
+a mask that is a function of the two indices: block-diffusion, causal) and
+`ssd_scan` (the selective state-space scan in its chunked form). The token
+models import the last three from their modules.
 """
 
 from tpu_dp.ops import native

@@ -55,6 +55,13 @@ def interpret() -> bool:
     return False
 
 
+def kernels_can_run() -> bool:
+    """Whether a Pallas kernel traced now can run: on a TPU, or inside
+    `interpret_kernels()`. What a model asks before it takes a kernel's path
+    (with its own test of the shapes)."""
+    return jax.default_backend() == "tpu" or bool(_interpret_requests)
+
+
 def shard_map_interp(x) -> bool:
     """True when per-shard interpret-mode code must run the XLA statement."""
     return bool(_interpret_requests) and bool(jax.typeof(x).vma)
