@@ -291,7 +291,8 @@ def test_profiler_trace_holds_the_spans_with_their_step(tmp_path):
 
 def test_obs_off_reads_no_clock_and_annotates_nothing(tmp_path, monkeypatch):
     """`train.obs=off`: no recorder, no annotation object, no clock read of
-    the loop's own, no ``loop.*`` counter."""
+    the loop's own, no ``loop.*`` counter. Set-up's first-epoch phase is
+    annotated once, at every ``train.obs`` (`obs/spans.py` `setup_span`)."""
     import jax
 
     from tpu_dp.train import trainer as trainer_mod
@@ -316,7 +317,8 @@ def test_obs_off_reads_no_clock_and_annotates_nothing(tmp_path, monkeypatch):
     monkeypatch.setattr(trainer_mod, "time", CountedTime())
     tr.train_epoch(0)
     tr.train_epoch(1)
-    assert made == [] and CountedTime.reads == 0
+    assert made == [("tpu_dp.setup.first_epoch",)]
+    assert CountedTime.reads == 0
     assert tr._fence_t is None
     assert not [k for k in global_counters.snapshot() if k.startswith("loop.")]
     # The same loop with the recorder on does annotate and count.

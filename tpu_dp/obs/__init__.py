@@ -18,6 +18,10 @@ Three layers, host-side throughout:
   (no HTTP server, no new deps);
 - `chips`    — the unified chip-spec registry (bf16 peak + HBM + ICI
   GB/s per device kind) behind MFU and the wire-bandwidth gauges;
+- `compiles` — a `jax.monitoring` listener: every program traced, lowered,
+  compiled or loaded, by name, and the set-up's share of it, frozen at
+  the first epoch's fence beside the set-up spans of `spans` (every
+  ``train.obs``);
 - `commprof` + `xplane` — in-run comm/compute attribution: step-ranged
   capture windows auto-parsed into per-collective device time, wire
   GB/s, and the ``obs.comm_ms`` / ``obs.exposed_comm_ms`` /

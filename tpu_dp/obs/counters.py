@@ -162,6 +162,22 @@ METRICS: dict[str, str] = {
     "loop.inflight_sum": "steps enqueued and unfinished, summed at dispatch",
     "loop.inflight_steps": "steps enqueued and unfinished (gauge)",
     "loop.dispatch_onto_idle": "dispatches that found the device drained",
+    # set-up, once a construction (obs/spans.py setup_span, train/trainer.py)
+    "setup.before_trainer_s": "process start (the OS's) to the first trainer's construction (gauge)",
+    "setup.trainer_s": "Trainer.__init__, whole (gauge)",
+    "setup.init_state_s": "the weights and optimizer state drawn from train.seed, inside it (gauge)",
+    "setup.caller_s": "the return of __init__ to the first train_epoch (gauge)",
+    "setup.first_epoch_s": "the first train_epoch, entry to the return of its fence (gauge)",
+    "setup.trace_s": "tracing and lowering in set-up, frozen at the first epoch's fence (gauge)",
+    "setup.compile_s": "backend compiles and cache loads in set-up, frozen likewise (gauge)",
+    "setup.compiled_anew": "programs compiled in set-up that the cache did not serve (gauge)",
+    # program making, from jax.monitoring (obs/compiles.py)
+    "compile.trace_s": "seconds tracing functions to jaxprs (each stage its own)",
+    "compile.lower_s": "seconds lowering jaxprs to modules",
+    "compile.backend_s": "seconds compiling, or loading from the persistent cache",
+    "compile.programs": "programs compiled or loaded",
+    "compile.cache_hits": "programs the persistent cache served",
+    "compile.compiled_anew": "programs compiled that the cache did not serve",
     # fleet aggregation (obs/fleet.py — derived cross-rank signals)
     "fleet.step_skew_ms": "max-min step-boundary arrival skew (gauge, ms)",
     "fleet.skew_ratio": "slowest rank vs leave-one-out median (gauge)",

@@ -46,10 +46,22 @@ Windowed dispatch (`train.steps_per_call > 1`) measures per *window* and
 attributes the totals evenly across the window's steps (documented in
 docs/OBSERVABILITY.md — per-step attribution inside one device-side scan
 is not observable from the host).
+
+Set-up has spans of its own, once per construction and at every
+``train.obs`` (`SETUP_SPANS`, `setup_span`): on the same clock and under the
+same annotation convention (``tpu_dp.setup.<name>``), each published on
+close as the gauge ``setup.<name>_s``. ``before_trainer`` runs from the
+process's start as the OS gives it (`process_age_s`) to the first
+construction; ``trainer`` is the whole of ``Trainer.__init__``, with
+``init_state`` inside it; ``caller`` from its return to the first
+``train_epoch``; ``first_epoch`` from that call's entry to the return of
+its fence. What of them went to making programs is
+`tpu_dp.obs.compiles`'s to say.
 """
 
 from __future__ import annotations
 
+import os
 import time
 from collections import deque
 from typing import Callable, Iterable, Mapping
@@ -61,6 +73,10 @@ STEP_SPANS = ("data_wait", "pre_dispatch", "h2d", "inflight_wait", "dispatch",
               "device", "telemetry", "accumulate", "hooks")
 #: Per-epoch spans, each on one record: the last step's, the first step's.
 EPOCH_SPANS = ("epoch_fence", "epoch_gap")
+#: Set-up's spans, in the order a run goes through them (``init_state``
+#: inside ``trainer``); each is the gauge ``setup.<name>_s``.
+SETUP_SPANS = ("before_trainer", "trainer", "init_state", "caller",
+               "first_epoch")
 
 
 def tile_ms(spans: Mapping[str, float]) -> float:
@@ -305,3 +321,76 @@ class InflightSteps:
 
     def dispatched(self, loss, n_steps: int) -> None:
         self._queue.append((loss, int(n_steps)))
+
+
+# ------------------------------------------------------------------ set-up
+
+def process_age_s(proc: str = "/proc") -> float | None:
+    """Seconds since this process started, as the OS counts them: its start
+    in ``<proc>/self/stat`` (clock ticks since boot) against the boot clock.
+    ``/proc/stat``'s ``btime`` is whole seconds, too coarse for this; the
+    boot clock is the one the start is counted on. None where there is no
+    such file."""
+    try:
+        with open(os.path.join(proc, "self", "stat")) as f:
+            stat = f.read()
+        # Field 22, counted past the command name, which may hold spaces.
+        ticks = int(stat[stat.rindex(")") + 2:].split()[19])
+        return (time.clock_gettime(time.CLOCK_BOOTTIME)
+                - ticks / os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+_before_trainer_published = False
+
+
+def publish_before_trainer(registry=_registry) -> float | None:
+    """The gauge ``setup.before_trainer_s``: the process's age at the first
+    trainer's construction, once a process (a later trainer's time before
+    it is another trainer's and the caller's); none where the OS does not
+    say when the process started."""
+    global _before_trainer_published
+    if _before_trainer_published:
+        return None
+    _before_trainer_published = True
+    age = process_age_s()
+    if age is not None:
+        registry.gauge("setup.before_trainer_s", age)
+    return age
+
+
+class setup_span:
+    """One set-up phase, timed on `time.perf_counter` (the loop spans'
+    clock) under ``jax.profiler.TraceAnnotation("tpu_dp.setup.<name>")``
+    and published on close as the gauge ``setup.<name>_s``. A context
+    manager, or opened here and closed elsewhere where the phase crosses
+    calls (``caller``, ``first_epoch``)."""
+
+    def __init__(self, name: str, registry=_registry,
+                 clock: Callable[[], float] = time.perf_counter,
+                 annotate: Callable | None = None):
+        # The gauge's name is computed, so DP405 cannot see it: only the
+        # declared phases (`counters.METRICS`) are published.
+        if name not in SETUP_SPANS:
+            raise ValueError(f"{name!r} is not a set-up span {SETUP_SPANS}")
+        if annotate is None:
+            from jax.profiler import TraceAnnotation as annotate  # lazy
+        self.name = name
+        self._registry, self._clock = registry, clock
+        self._ann = annotate("tpu_dp.setup." + name)
+        self._ann.__enter__()
+        self.start = clock()
+
+    def close(self) -> float:
+        """End the phase; returns its seconds, which it publishes."""
+        seconds = self._clock() - self.start
+        self._ann.__exit__(None, None, None)
+        self._registry.gauge("setup." + self.name + "_s", seconds)
+        return seconds
+
+    def __enter__(self) -> "setup_span":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.close()

@@ -40,7 +40,9 @@ from tpu_dp.models import build_model
 from tpu_dp.parallel import dist
 from tpu_dp.train.optim import SGD
 from tpu_dp.train.schedule import make_schedule
+from tpu_dp.obs import compiles
 from tpu_dp.obs.counters import counters as _obs_counters
+from tpu_dp.obs.spans import publish_before_trainer, setup_span
 from tpu_dp.train.state import create_train_state
 from tpu_dp.train.step import make_eval_step, make_train_step
 from tpu_dp.utils import (
@@ -185,7 +187,20 @@ class Trainer:
     def __init__(self, cfg: Config, mesh=None, datasets=None):
         """``datasets`` hands the trainer its ``(train, test)`` data sets
         (`ArrayDataset`s or `TokenDataset`s) in place of those ``cfg.data``
-        would load."""
+        would load.
+
+        Set-up is timed from here, at every ``train.obs``
+        (docs/OBSERVABILITY.md "Set-up"): the process's time before this
+        first construction, the construction, the caller's time after it
+        and the first epoch, and what of them went to making programs."""
+        self._compiles = compiles.install()
+        self._compiles.begin()
+        publish_before_trainer()
+        with setup_span("trainer"):
+            self._construct(cfg, mesh, datasets)
+        self._setup: setup_span | None = setup_span("caller")
+
+    def _construct(self, cfg: Config, mesh, datasets) -> None:
         self.cfg = cfg
         # Elastic grow (docs/RESILIENCE.md "Grow"): before any classic
         # bootstrap, a starting process may instead JOIN a live run it
@@ -429,7 +444,8 @@ class Trainer:
                 )
         self._build_training()
 
-        self.state = self._fresh_state()
+        with setup_span("init_state"):
+            self.state = self._fresh_state()
         self.start_epoch = 0
         self.start_step = 0  # step within start_epoch (mid-epoch resume)
         self.meter = ThroughputMeter(warmup_steps=2)
@@ -1674,6 +1690,8 @@ class Trainer:
         — no batch replayed, none skipped.
         """
         cfg = self.cfg
+        if self._setup is not None and self._setup.name == "caller":
+            self._advance_setup()
         # Elastic tail: after a mid-epoch regroup (or a restart into one),
         # the interrupted epoch's remaining samples come from the re-split
         # pipe; `done` stays epoch-cumulative across the world change so
@@ -1841,9 +1859,24 @@ class Trainer:
             # their own discontinuity instead of faking full-epoch coverage.
             stats["resumed_at_step"] = base + start_step
         self.meter.mark()  # fence: epoch stats fetched, device drained
+        if self._setup is not None:
+            self._advance_setup()
         if last_rec is not None:
             self._fence_t = spans.end()
         return stats
+
+    def _advance_setup(self) -> None:
+        """Set-up's phases after construction: the caller's, from the return
+        of `__init__` to the first `train_epoch`'s entry, then that epoch's,
+        to the return of its fence. There the compile listener's set-up
+        totals freeze, and one line says where set-up went."""
+        span = self._setup
+        span.close()
+        if span.name == "caller":
+            self._setup = setup_span("first_epoch")
+        else:
+            self._setup = None
+            self._compiles.freeze()
 
     def _publish_rate(self) -> None:
         """The meter's rate as a gauge, under the name of what it counts."""
