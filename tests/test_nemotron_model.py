@@ -366,3 +366,91 @@ def test_total_imbalance_drops_no_pair_under_the_sigmoid_router(chunks):
     pairs, held, dropped, load_max, load_mean, run = np.asarray(counters)
     assert (pairs, held, dropped) == (192, 192, 0)
     assert load_max == 96 and load_mean == 48 and run >= 196
+
+
+def wide_layer(expert_width=128):
+    """An expert layer at widths the grouped-product pair takes (hidden
+    128), its routed experts ``expert_width`` wide, and ``u`` of 2 rows of
+    128 tokens: 512 pairs, chunks of 512 or 128 rows."""
+    model = tiny_model(hidden_size=128, expert_width=expert_width,
+                       layer_pattern="E")
+    p = model.init(jax.random.PRNGKey(4))["params"]["layers_0"]["mixer"]
+    p = jax.tree_util.tree_map(lambda x: 4 * x if x.ndim == 3 else x, p)
+    u = jax.random.normal(jax.random.PRNGKey(5), (2, 128, 128))
+    return model, p, u
+
+
+@pytest.mark.parametrize("chunks", [1, 4], ids=["one_chunk", "walked"])
+def test_the_grouped_kernels_give_the_compilers_experts(monkeypatch, chunks):
+    """`experts_share` with `squared_relu` at widths the grouped-product
+    pair takes, through the pair (interpreted) and through
+    `lax.ragged_dot`: the routed output and the gradients of the input and
+    both matrices agree within 1e-5 of the largest entry. With four chunks
+    the held pairs and the rows of weight zero walk more than one chunk."""
+    from tpu_dp.ops import _partition
+
+    _, p, u = wide_layer()
+    h = u.reshape(-1, 128)
+    dy = jax.random.normal(jax.random.PRNGKey(6), h.shape)
+
+    def loss(h, up, down):
+        out, counters = routed({**p, "up": {"kernel": up},
+                                "down": {"kernel": down}}, h, 0, chunks)
+        return jnp.sum(out * dy), (out, counters)
+
+    def run():
+        with jax.default_matmul_precision("highest"):
+            both = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)
+            args = (h, p["up"]["kernel"], p["down"]["kernel"])
+            (_, (out, counters)), grads = both(*args)
+            traced = str(jax.make_jaxpr(both)(*args))
+        return (out, grads), counters, traced
+
+    got, counters, traced = run()
+    monkeypatch.setattr(_partition, "_interpret_requests", 0)
+    want, want_counters, compilers = run()
+    assert "pallas_call" in traced and "pallas_call" not in compilers
+    for mine, theirs in zip(jax.tree_util.tree_leaves(got),
+                            jax.tree_util.tree_leaves(want)):
+        scale = float(jnp.max(jnp.abs(theirs)))
+        np.testing.assert_allclose(mine, theirs, rtol=0, atol=1e-5 * scale)
+    np.testing.assert_array_equal(counters, want_counters)
+    assert bool(counters[5] > 2 * h.shape[0] // chunks) is (chunks > 1)
+
+
+@pytest.mark.parametrize("width, lanes_128, lanes_256", [
+    (100, 128, 256), (200, 256, 256)], ids=["paddings_differ", "agree"])
+def test_the_routed_width_is_padded_to_the_products_lanes(
+        monkeypatch, width, lanes_128, lanes_256):
+    """A routed width of 100 (as 1,856 at the published widths) is padded
+    to whole 128 lanes where the pair runs (128, as 1,920) and to whole 256
+    where the compiler's kernel does (256, as 2,048): zeros either way, so
+    the mixer's output and gradients are the same to 1e-5 of the largest
+    entry, whichever padding and whichever kernel."""
+    from tpu_dp.models import nemotron_h
+    from tpu_dp.ops import _partition
+
+    model, p, u = wide_layer(expert_width=width)
+    widths = []
+    share = parts.experts_share
+
+    def spy(h, weights_, experts, expert_fn, kernels, *rest):
+        widths.append(kernels[0].shape[-1])
+        return share(h, weights_, experts, expert_fn, kernels, *rest)
+
+    def run():
+        monkeypatch.setattr(parts, "experts_share", spy)
+        with jax.default_matmul_precision("highest"):
+            return jax.value_and_grad(
+                lambda p, u: jnp.sum(jnp.sin(
+                    nemotron_h.moe_mixer(p, u, model)[0])),
+                argnums=(0, 1))(p, u)
+
+    got = run()
+    monkeypatch.setattr(_partition, "_interpret_requests", 0)
+    want = run()
+    assert widths == [lanes_128, lanes_256]
+    for mine, theirs in zip(jax.tree_util.tree_leaves(got),
+                            jax.tree_util.tree_leaves(want)):
+        scale = float(jnp.max(jnp.abs(theirs)))
+        np.testing.assert_allclose(mine, theirs, rtol=0, atol=1e-5 * scale)

@@ -254,16 +254,23 @@ def test_block_diffusion_attention_kernel_compiles_for_v5e(one_chip,
     assert len(kernels) == 2 and all("splash_mqa" in k for k in kernels)
 
 
-def test_grouped_expert_products_are_a_kernel_of_the_compilers(
+def test_grouped_expert_products_are_the_repos_kernel_pair(
         one_chip, compiled_kernels):
-    """`lax.ragged_dot` over the held experts is a tiled grouped product of
-    the TPU compiler's own (a ``tpu_custom_call``), forward and backward,
-    and not sixteen dense products masked afterwards: 8,192 positions, 16
-    experts of 2048 x 768, as a chunk of the cell's layer has them."""
-    _compile(
+    """The grouped products over the held experts are the repo's kernel
+    pair (`tpu_dp/ops/grouped_products.py`), forward and backward, and not
+    the compiler's ``ragged-dot`` nor sixteen dense products masked
+    afterwards: 8,192 positions, 16 experts of 2048 x 768, a chunk of 8,192
+    rows. Every kernel carries the one name that begins ``ragged-dot``, as
+    `expert_products_roofline` reads it."""
+    from tpu_dp.ops import grouped_products
+
+    compiled = _compile(
         _expert_products_fwd_bwd, one_chip,
         ((8192, 2048), jnp.bfloat16), ((16, 2048, 768), jnp.float32),
         ((16, 2048, 768), jnp.float32), ((16, 768, 2048), jnp.float32))
+    names = {re.sub(r"\.\d+$", "", n) for n in re.findall(
+        r"%(\S*ragged\S*) = ", compiled.as_text())}
+    assert names == {grouped_products.NAME}
 
 
 def _qk_norm_rope_fwd_bwd(x, w, cos, sin, dy):
@@ -359,8 +366,8 @@ def test_the_hybrid_step_compiles_for_v5e_and_fits_the_chip(one_chip,
     cell's shapes (preset `nemotron3_nano_30b_a3b_ep16`: 666,963,456
     parameters, 2 rows of 8,192 tokens, bfloat16 compute, AdamW with the
     clip), built by the step factory every model trains through, compiled
-    for one described v5e chip: the causal pair and the scan's pair are in
-    it, and the state,
+    for one described v5e chip: the causal pair, the scan's pair and the
+    grouped products' pair are in it, and the state,
     the gradient and every temporary together fit the chip's 16 GiB with a
     GiB to spare for what the runtime holds beside a program (the resident
     rows, the loop's metrics)."""
@@ -409,6 +416,9 @@ def test_the_hybrid_step_compiles_for_v5e_and_fits_the_chip(one_chip,
     scans = re.findall(r' custom-call\(.*/ssd_scan_pair/pallas_call"', text)
     assert len(scans) == 12
     assert not re.search(r'reduce-window\(.*tpu_dp\.ssm_scan', text)
+    # the expert layers' grouped products are the repo's pair alone
+    assert {re.sub(r"\.\d+$", "", n) for n in re.findall(
+        r"%(\S*ragged\S*) = ", text)} == {"ragged-dot-gmm"}
     mem = compiled.memory_analysis()
     held = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes)

@@ -34,9 +34,11 @@ What the TPU decides here:
   wherever `parts.kernels_run` (`tpu_dp/ops/flash_block_diffusion.py`: at
   8,192 positions the compiler's own full scores would be 17 GB), and
   chunked by queries in the compiler's hands elsewhere, as SDAR's does.
-- **The expert layer** is `parts.experts_share` with `parts.squared_relu`;
-  the shared expert is two dense products beside it, computed once whatever
-  the share.
+- **The expert layer** is `parts.experts_share` with `parts.squared_relu`
+  (grouped products through `tpu_dp/ops/grouped_products.py`'s pair where
+  it runs, the routed width padded to whole 128 lanes for it, to whole 256
+  for the compiler's kernel elsewhere); the shared expert is two dense
+  products beside it, computed once whatever the share.
 - A layer is wrapped in `jax.checkpoint` (`run_layer`): its input is saved
   in the compute dtype, the rest recomputed, every row at once: with the
   scan's temporaries in fast memory a loop over rows costs more in its own
@@ -58,12 +60,12 @@ import jax.numpy as jnp
 from tpu_dp.models import parts
 from tpu_dp.models.outputs import RowLoss
 from tpu_dp.models.parts import F32, rms_norm
-from tpu_dp.ops import ssd_scan
+from tpu_dp.ops import grouped_products, ssd_scan
 from tpu_dp.ops.flash_block_diffusion import CausalMask, flash_attention
 
 COUNTER_NAMES = parts.MOE_COUNTER_NAMES + ("lm.tokens",)
 MIXERS = {"M": "ssm", "*": "attn", "E": "moe"}
-EXPERT_LANES = 256   # the routed experts' width is padded to whole ones
+EXPERT_LANES = 256   # the routed width's padding for the compiler's kernel
 
 
 # -------------------------------------------------------------- state space
@@ -246,12 +248,20 @@ def moe_mixer(p, u, m: "NemotronH"):
             m.experts_per_token, m.routed_scaling, m.norm_topk_prob)
     flat = flat.astype(dtype)
     with jax.named_scope("tpu_dp.moe_experts"):
-        # The compiler's grouped product runs a width of whole 256 lanes
-        # at twice the speed of 1,856 (a chunk of 12,288 rows, forward and
-        # backward: 24.1 ms at 1,856 or 1,920, 12.2 at 2,048, 12.5 padded;
-        # PERF.md §6, PR 34): columns of zeros, whose squared ReLU is
-        # zero, against rows of zeros.
-        extra = -m.expert_width % EXPERT_LANES
+        # The routed width is padded with columns of zeros, whose squared
+        # ReLU is zero, against rows of zeros: to whole 128 lanes where the
+        # grouped products are the repo's kernels, to whole `EXPERT_LANES`
+        # where they are the compiler's, which runs a width of whole 256
+        # lanes at twice the speed of 1,856 or 1,920 (a chunk of 12,288
+        # rows, forward and backward: 24.1 ms at 1,856, 12.2 at 2,048;
+        # PERF.md §6).
+        rows = parts.chunk_rows(flat.shape[0] * m.experts_per_token,
+                                m.moe_chunks)
+        lanes = grouped_products.LANES
+        if not grouped_products.runs(rows, flat.shape[-1], m.expert_width
+                                     + -m.expert_width % lanes):
+            lanes = EXPERT_LANES
+        extra = -m.expert_width % lanes
         up = jnp.pad(p["up"]["kernel"], ((0, 0), (0, 0), (0, extra)))
         down = jnp.pad(p["down"]["kernel"], ((0, 0), (0, extra), (0, 0)))
         routed, counters = parts.experts_share(

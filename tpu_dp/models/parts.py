@@ -7,8 +7,11 @@ What the TPU decides here:
 - **The expert layer is told its share** (``held`` experts of the router's,
   from ``share_index * held``): it sorts the router's (position, expert)
   pairs by expert with those of absent experts last, and runs grouped
-  products (`jax.lax.ragged_dot`, a tiled kernel of the TPU compiler's own)
-  over the pairs it holds. What an expert computes is the caller's
+  products over the pairs it holds: `grouped_products.ragged_dot`, the
+  repo's kernel pair where it runs (`tpu_dp/ops/grouped_products.py`: on a
+  TPU or inside `interpret_kernels()`, a chunk of whole 128-row tiles,
+  widths of whole 128 lanes), `jax.lax.ragged_dot` (a tiled kernel of the
+  TPU compiler's own) elsewhere. What an expert computes is the caller's
   (`gated_silu`: three matrices; `squared_relu`: two), and so is the
   chunking. **No pair is dropped**: the sorted list is walked in chunks of a
   fixed size that together cover every pair there can be, and a chunk beyond
@@ -32,7 +35,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from tpu_dp.ops import flash_block_diffusion
+from tpu_dp.ops import flash_block_diffusion, grouped_products
 
 F32 = jnp.float32
 _NEG = -0.7 * float(jnp.finfo(jnp.float32).max)
@@ -158,21 +161,31 @@ def route_sigmoid(h, router, bias, top_k: int, scale: float,
 
 # ------------------------------------------------------------------ experts
 
+_grouped = grouped_products.ragged_dot
+
+
 def gated_silu(x, kernels, groups):
     """``down(silu(gate x) * up x)`` of each row's group: ``kernels`` are
     ``gate, up [held, hidden, width]``, ``down [held, width, hidden]``."""
     gate, up, down = kernels
-    act = (jax.nn.silu(jax.lax.ragged_dot(x, gate, groups))
-           * jax.lax.ragged_dot(x, up, groups))
-    return jax.lax.ragged_dot(act, down, groups, preferred_element_type=F32)
+    act = (jax.nn.silu(_grouped(x, gate, groups)) * _grouped(x, up, groups))
+    return _grouped(act, down, groups, preferred_element_type=F32)
 
 
 def squared_relu(x, kernels, groups):
     """``down(relu(up x)^2)`` of each row's group, no gate: ``kernels`` are
     ``up [held, hidden, width]``, ``down [held, width, hidden]``."""
     up, down = kernels
-    act = jnp.square(jax.nn.relu(jax.lax.ragged_dot(x, up, groups)))
-    return jax.lax.ragged_dot(act, down, groups, preferred_element_type=F32)
+    act = jnp.square(jax.nn.relu(_grouped(x, up, groups)))
+    return _grouped(act, down, groups, preferred_element_type=F32)
+
+
+def chunk_rows(pairs: int, chunks: int) -> int:
+    """The rows of a chunk of `experts_share`'s walk: ``chunks`` of them
+    hold ``pairs``, each whole tiles of eight rows (the compiler's kernel
+    takes no other; what it falls back on gave wrong sums on the v5e, and a
+    size that is not whole tiles of 128 runs eight times slower)."""
+    return -(-pairs // (8 * max(1, min(chunks, pairs)))) * 8
 
 
 def chunk_groups(starts, ends, first, size: int):
@@ -202,11 +215,8 @@ def experts_share(h, weights, experts, expert_fn, kernels, share_index: int,
     # The rows of the grouped products: the pairs, and one more row of
     # weight zero for each held expert, so that no group is ever empty.
     rows = pairs + held
-    # A chunk is whole tiles of eight rows (the compiler's kernel takes no
-    # other; what it falls back on gave wrong sums on the v5e, and a size
-    # that is not whole tiles of 128 runs eight times slower): ``chunks``
-    # of them hold the pairs, one more the rows of weight zero.
-    size = -(-pairs // (8 * max(1, min(chunks, pairs)))) * 8
+    # ``chunks`` chunks hold the pairs, one more the rows of weight zero.
+    size = chunk_rows(pairs, chunks)
     chunks = -(-rows // size)
     # The rows by expert, those of absent experts last; stable, so a held
     # expert's pairs stay in order of position, its row of zero after them.

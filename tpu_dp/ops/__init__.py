@@ -11,9 +11,10 @@ ring allreduce fallback for host coordination off-TPU — are native C++
 The Pallas kernels, a module each: `xent` (fused softmax cross-entropy),
 `conv_block` (BN-apply + ReLU + conv chains), `qk_norm_rope` (per-head
 RMSNorm and RoPE of q and k), `flash_block_diffusion` (flash attention under
-a mask that is a function of the two indices: block-diffusion, causal) and
-`ssd_scan` (the selective state-space scan in its chunked form). The token
-models import the last three from their modules.
+a mask that is a function of the two indices: block-diffusion, causal),
+`ssd_scan` (the selective state-space scan in its chunked form) and
+`grouped_products` (the expert layers' grouped matrix products). The token
+models import the last four from their modules.
 """
 
 from tpu_dp.ops import native
