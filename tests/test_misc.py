@@ -79,7 +79,7 @@ def test_config_overrides_and_presets():
     assert set(PRESETS) == {
         "reference", "resnet18_cifar10", "resnet50_cifar100",
         "resnet18_8chip_gb1024", "bf16_cosine_gb4096", "sdar_30b_a3b_ep8",
-        "nemotron3_nano_30b_a3b_ep16",
+        "nemotron3_nano_30b_a3b_ep16", "trinity_mini_ep8",
     }
     token = parse_cli(["--preset=sdar_30b_a3b_ep8", "--model.num_layers=2"])
     assert (token.model.name, token.optim.name) == ("sdar_moe", "adamw")

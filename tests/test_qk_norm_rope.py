@@ -7,7 +7,8 @@ turned heads major, and `jax.grad` of it, at the two shapes the decoder
 hands over, positions major: q's ``[rows, 2L, kv * g, d]`` (more heads than
 a block holds, so the grid's inner axis is walked) and k's ``[rows, 2L, kv,
 d]``; the results are ``[rows, heads, 2L, d]``, as the flash kernel takes
-them.
+them. Handed no tables (a layer with no positional encoding) the pair is
+``scale * rms_norm(x, w)``, turned heads major.
 """
 
 from __future__ import annotations
@@ -144,6 +145,43 @@ def test_the_tables_are_rotate_half_rope():
     want = np.concatenate([x1 * np.cos(angle) - x2 * np.sin(angle),
                            x2 * np.cos(angle) + x1 * np.sin(angle)], -1)
     np.testing.assert_allclose(sdar.rope(x, cos, sin), want, atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_without_tables_the_pair_is_the_norm_alone(layout, dtype):
+    """``cos = sin = None``: forward and both gradients against
+    ``scale * rms_norm(x, w)`` and `jax.grad` of it, to the tolerances of
+    the rotating pair; the kernels read no table."""
+    x, w, dy = operands(layout, jnp.dtype(dtype))
+    scale = SCALES[layout]
+
+    def plain_norm(x, w, scale):
+        return scale * sdar.rms_norm(x.astype(F32), w, EPS).transpose(
+            0, 2, 1, 3)
+
+    def bare(x, w, scale):
+        return qk_norm_rope(x, w, None, None, EPS, scale)
+
+    got = bare(x, w, scale)
+    assert got.shape == plain_norm(x, w, scale).shape and got.dtype == x.dtype
+    assert gap(got, plain_norm(x, w, scale)) < TOLERANCE[dtype]
+
+    def through(f):
+        def loss(x, w):
+            return jnp.sum(f(x, w, scale).astype(F32) * dy.astype(F32))
+        return jax.grad(loss, (0, 1))
+
+    dx, dw = through(bare)(x, w)
+    want_dx, want_dw = through(plain_norm)(x.astype(F32), w)
+    assert dx.dtype == x.dtype and dw.dtype == w.dtype
+    assert gap(dx, want_dx) < TOLERANCE[dtype]
+    assert gap(dw, want_dw) < 1e-5
+    # the rotation is all that the tables add: at angle zero they agree
+    cos, sin = jnp.ones((x.shape[1], D)), jnp.zeros((x.shape[1], D))
+    np.testing.assert_array_equal(
+        np.asarray(bare(x, w, scale), np.float32),
+        np.asarray(qk_norm_rope(x, w, cos, sin, EPS, scale), np.float32))
 
 
 @pytest.mark.parametrize("n2, d, why", [

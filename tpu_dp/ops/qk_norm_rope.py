@@ -13,7 +13,7 @@ on the way (PERF.md §6, PR 32).
 With ``x [rows, n, heads, d]`` as the projection's product leaves it
 (positions major, a head's lanes minor), ``w [d]``, float32 tables ``cos,
 sin [n, d]`` of the positions' angles over both halves of a head (``sin``
-carries the rotation's sign; `tpu_dp.models.sdar.rope_tables`) and ``rot(z)
+carries the rotation's sign; `tpu_dp.models.parts.rope_tables`) and ``rot(z)
 = [-z2, z1]``, the result is ``y [rows, heads, n, d]``, heads major as the
 flash kernel takes them::
 
@@ -23,6 +23,10 @@ flash kernel takes them::
     dz = s (dy cos + rot^T(dy sin))             rot^T(a) = [a2, -a1]
     dw = sum_rows dz u
     dx = r (w dz - u mean(w dz u))
+
+Handed no tables (``cos = sin = None``: a layer with no positional
+encoding) the pair leaves the rotation out, ``y = s z`` and ``dz = s dy``,
+and reads no table.
 
 **The kernels turn the heads on the way**: a block of the operand is ``[a
 tile of positions, some heads' lanes]`` of the positions-major array, the
@@ -72,18 +76,21 @@ def _normed(x_ref, h, d, eps):
     return x * r, r
 
 
-def _fwd_kernel(x_ref, w_ref, cos_ref, sin_ref, y_ref, *, eps, scale):
+def _fwd_kernel(x_ref, w_ref, *refs, eps, scale):
+    *tables, y_ref = refs         # tables: the cos and sin refs, or none
     heads, d = y_ref.shape[1], y_ref.shape[-1]
     w = w_ref[...] * scale
     for h in range(heads):
         u, _ = _normed(x_ref, h, d, eps)
-        z = u * w
-        y = z * cos_ref[...] + pltpu.roll(z, d // 2, 1) * sin_ref[...]
+        y = z = u * w
+        if tables:
+            cos_ref, sin_ref = tables
+            y = z * cos_ref[...] + pltpu.roll(z, d // 2, 1) * sin_ref[...]
         y_ref[0, h] = y.astype(y_ref.dtype)
 
 
-def _bwd_kernel(dy_ref, x_ref, w_ref, cos_ref, sin_ref, dx_ref, dw_ref, *,
-                eps, scale):
+def _bwd_kernel(dy_ref, x_ref, w_ref, *refs, eps, scale):
+    *tables, dx_ref, dw_ref = refs
     heads, d = dy_ref.shape[1], dy_ref.shape[-1]
     w = w_ref[...] * scale
 
@@ -96,8 +103,10 @@ def _bwd_kernel(dy_ref, x_ref, w_ref, cos_ref, sin_ref, dx_ref, dw_ref, *,
     dw = jnp.zeros((1, d), F32)
     for h in range(heads):
         u, r = _normed(x_ref, h, d, eps)
-        dy = dy_ref[0, h].astype(F32)
-        dz = dy * cos_ref[...] - pltpu.roll(dy, d // 2, 1) * sin_ref[...]
+        dz = dy = dy_ref[0, h].astype(F32)
+        if tables:
+            cos_ref, sin_ref = tables
+            dz = dy * cos_ref[...] - pltpu.roll(dy, d // 2, 1) * sin_ref[...]
         dzu = dz * u
         dx = r * (dz * w - u * jnp.mean(dzu * w, axis=-1, keepdims=True))
         dx_ref[0, :, h * d:(h + 1) * d] = dx.astype(dx_ref.dtype)
@@ -131,26 +140,34 @@ _PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
+def _tables(cos, sin):
+    """The tables the kernels read: both, or none without the rotation."""
+    return () if cos is None else (cos, sin)
+
+
 def _forward(x, w, cos, sin, eps, scale):
     rows, n, heads, d = x.shape
     grid, (by_position, by_head, table, weight) = _grid(x)
+    tables = _tables(cos, sin)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, eps=eps, scale=scale),
-        grid=grid, in_specs=[by_position, weight, table, table],
+        grid=grid, in_specs=[by_position, weight] + [table] * len(tables),
         out_specs=by_head,
-        out_shape=_shape_struct((rows, heads, n, d), x.dtype, x, w, cos, sin),
+        out_shape=_shape_struct((rows, heads, n, d), x.dtype, x, w, *tables),
         compiler_params=_PARAMS, interpret=_interpret(), name=NAME,
-    )(x.reshape(rows, n, heads * d), w.astype(F32)[None], cos, sin)
+    )(x.reshape(rows, n, heads * d), w.astype(F32)[None], *tables)
 
 
 def _backward(dy, x, w, cos, sin, eps, scale):
     rows, n, heads, d = x.shape
     grid, (by_position, by_head, table, weight) = _grid(x)
-    operands = (dy, x, w, cos, sin)
+    tables = _tables(cos, sin)
+    operands = (dy, x, w, *tables)
     tiles = grid[0] * grid[1]
     dx, dw = pl.pallas_call(
         functools.partial(_bwd_kernel, eps=eps, scale=scale),
-        grid=grid, in_specs=[by_head, by_position, weight, table, table],
+        grid=grid,
+        in_specs=[by_head, by_position, weight] + [table] * len(tables),
         # the weight's gradient as one partial sum a row's tile of
         # positions, added up over the heads of the inner axis
         out_specs=(by_position, pl.BlockSpec(
@@ -159,7 +176,7 @@ def _backward(dy, x, w, cos, sin, eps, scale):
         out_shape=(_shape_struct((rows, n, heads * d), x.dtype, *operands),
                    _shape_struct((tiles, 1, d), F32, *operands)),
         compiler_params=_PARAMS, interpret=_interpret(), name=NAME,
-    )(dy, x.reshape(rows, n, heads * d), w.astype(F32)[None], cos, sin)
+    )(dy, x.reshape(rows, n, heads * d), w.astype(F32)[None], *tables)
     return dx.reshape(x.shape), jnp.sum(dw, axis=(0, 1)).astype(w.dtype)
 
 
@@ -167,8 +184,9 @@ def _backward(dy, x, w, cos, sin, eps, scale):
 def qk_norm_rope(x, w, cos, sin, eps: float, scale: float):
     """``scale * rope(rms_norm(x, w))`` of ``x [rows, n, heads, d]``, heads
     major: ``[rows, heads, n, d]``, in one read and one write; ``sin`` is
-    signed ``[-sin, sin]``. Differentiable in ``x`` and ``w`` (the tables
-    get no gradient)."""
+    signed ``[-sin, sin]``; with ``cos = sin = None``, ``scale *
+    rms_norm(x, w)``. Differentiable in ``x`` and ``w`` (the tables get no
+    gradient)."""
     return _forward(x, w, cos, sin, eps, scale)
 
 
