@@ -37,6 +37,7 @@ SETUP_METRICS = {
     "setup_trace_s": "setup.trace_s",
     "setup_compile_s": "setup.compile_s",
     "setup_compiled_anew": "setup.compiled_anew",
+    "setup_step_entries": "setup.step_entries",
 }
 
 

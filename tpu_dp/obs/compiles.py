@@ -22,7 +22,9 @@ A trainer's construction starts a set-up (`CompileListener.begin`): the
 table starts empty and the listener is quiet. At the first epoch's fence
 the trainer freezes it (`CompileListener.freeze`): the table's totals
 become the gauges ``setup.trace_s`` (trace and lower), ``setup.compile_s``
-(backend) and ``setup.compiled_anew``, and one line says where set-up went.
+(backend) and ``setup.compiled_anew``, the train programs' jit cache
+entries the gauge ``setup.step_entries``, and one line says where set-up
+went.
 From then on every program compiled or loaded gets a line of its own with
 its seconds: which step compiled again, mid-run.
 """
@@ -146,25 +148,33 @@ class CompileListener:
         self.table = {}
         self.quiet = True
 
-    def freeze(self) -> dict[str, float]:
+    def freeze(self, step_entries: int | None = None) -> dict[str, float]:
         """The set-up's totals as the ``setup.*`` gauges, one line that says
         where set-up went (the spans, the totals, the three longest
-        programs), and a line a program from here on. Returns the totals."""
+        programs), and a line a program from here on. ``step_entries``, the
+        jit cache entries the train programs hold (one a program used, where
+        each was made once), is the gauge ``setup.step_entries`` and a field
+        of the line. Returns the totals."""
         rows = list(self.table.values())
         total = {k: sum(r[k] for r in rows) for k in ROW}
         reg = self._registry
         reg.gauge("setup.trace_s", total["trace_s"] + total["lower_s"])
         reg.gauge("setup.compile_s", total["backend_s"])
         reg.gauge("setup.compiled_anew", total["compiled_anew"])
+        entries = ""
+        if step_entries is not None:
+            reg.gauge("setup.step_entries", step_entries)
+            entries = f", step entries {step_entries}"
         snap = reg.snapshot()
         spans = {k: snap[f"setup.{k}_s"] for k in SETUP_SPANS
                  if f"setup.{k}_s" in snap}
         longest = sorted(self.table.items(), key=lambda kv: -_seconds(kv[1]))
         self.log(
-            "set-up: %s; programs %d (%d compiled anew), trace %.2f s, "
+            "set-up: %s; programs %d (%d compiled anew)%s, trace %.2f s, "
             "lower %.2f s, compile %.2f s; longest: %s",
             ", ".join(f"{k} {v:.2f} s" for k, v in spans.items()),
-            total["programs"], total["compiled_anew"], total["trace_s"],
+            total["programs"], total["compiled_anew"], entries,
+            total["trace_s"],
             total["lower_s"], total["backend_s"],
             "; ".join(
                 f"{name} {_seconds(r):.2f} s (traced {r['traces']:.0f}x "

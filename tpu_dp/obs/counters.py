@@ -171,6 +171,7 @@ METRICS: dict[str, str] = {
     "setup.trace_s": "tracing and lowering in set-up, frozen at the first epoch's fence (gauge)",
     "setup.compile_s": "backend compiles and cache loads in set-up, frozen likewise (gauge)",
     "setup.compiled_anew": "programs compiled in set-up that the cache did not serve (gauge)",
+    "setup.step_entries": "jit cache entries the train programs hold at the first epoch's fence: one a program used (gauge)",
     # program making, from jax.monitoring (obs/compiles.py)
     "compile.trace_s": "seconds tracing functions to jaxprs (each stage its own)",
     "compile.lower_s": "seconds lowering jaxprs to modules",

@@ -26,6 +26,7 @@ import functools
 import json
 import operator
 import time
+import weakref
 from pathlib import Path
 from typing import Any, Callable, NamedTuple
 
@@ -58,6 +59,19 @@ from tpu_dp.utils import (
 def _unstack(stacked, n):
     """Lazy per-step views over a window's stacked metrics — no host sync."""
     return tuple({k: v[j] for k, v in stacked.items()} for j in range(n))
+
+
+def _place_leaf(x, sharding):
+    """One leaf of a state committed to ``sharding`` (`Trainer._place_state`).
+    A process's own array behind a sharding across processes is placed a
+    piece a device where this process holds the target, as a jit's first
+    call places it: every process made the same value from one seed, so
+    nothing is compared across them."""
+    if (isinstance(x, jax.Array) and x.is_fully_addressable
+            and not sharding.is_fully_addressable):
+        return jax.make_array_from_callback(x.shape, sharding,
+                                            lambda idx: x[idx])
+    return jax.device_put(x, sharding, may_alias=True)
 
 
 class _Program(NamedTuple):
@@ -445,7 +459,7 @@ class Trainer:
         self._build_training()
 
         with setup_span("init_state"):
-            self.state = self._fresh_state()
+            self.state = self._place_state(self._fresh_state())
         self.start_epoch = 0
         self.start_step = 0  # step within start_epoch (mid-epoch resume)
         self.meter = ThroughputMeter(warmup_steps=2)
@@ -573,6 +587,8 @@ class Trainer:
         )
         self.spans = None
         self._fence_t = None  # when the last epoch's fence returned
+        # The state the last epoch's loop left (weakly): in place already.
+        self._epoch_state: weakref.ref | None = None
         self.heartbeat = None
         self.health = None
         if self.obs_mode != "off":
@@ -833,11 +849,11 @@ class Trainer:
     def _guarded(self, name: str, step_fn):
         """Wrap a compiled step in a RecompileGuard (train.recompile_guard).
 
-        warmup_calls=2: the first call consumes the host-staged
-        (uncommitted) init state, every later call the donated
-        device-resident output — that placement transition legitimately
-        traces a second cache entry, so only growth past call 2 is a real
-        retrace. Without drop_remainder the epoch's final partial batch
+        warmup_calls=1: every call, the first too, meets the state where
+        the program returns it (`train_epoch` places it first,
+        `_place_state`), so the first call makes the program's one cache
+        entry and any growth after it is a real retrace. Without
+        drop_remainder the epoch's final partial batch
         (padded, with a weight leaf) legitimately compiles another variant
         every epoch, so guarding would cry wolf — steps run unguarded
         there, like the eval step. No logger override: retrace divergence
@@ -849,7 +865,7 @@ class Trainer:
         from tpu_dp.analysis.recompile import RecompileGuard
 
         return RecompileGuard(
-            step_fn, name=name, on_retrace=self._guard, warmup_calls=2,
+            step_fn, name=name, on_retrace=self._guard, warmup_calls=1,
         )
 
     def _build_pipelines(self) -> None:
@@ -1741,6 +1757,14 @@ class Trainer:
 
         for hook in self._hooks:
             hook.on_epoch_start(epoch)
+        # The state meets the first dispatch where the programs return it,
+        # whatever a caller or a restore put in its place since the last
+        # epoch, so each program is made once (`_place_state`). The state
+        # the last epoch's loop left is the programs' own output, in place:
+        # the epoch's gap pays no check for it.
+        ref = self._epoch_state
+        if ref is None or ref() is not self.state:
+            self.state = self._place_state(self.state)
         it = iter(items)
         while True:
             if spans is not None:
@@ -1836,6 +1860,7 @@ class Trainer:
             ev = StepEvent(epoch=epoch, done=done, n=n, window=window)
             for hook in self._hooks:
                 hook.on_step_end(ev)
+        self._epoch_state = weakref.ref(self.state)
         if last_rec is not None:
             spans.begin("epoch_fence", rec=last_rec)
         sums.fetch()  # the fence: the epoch's last step has finished
@@ -1869,14 +1894,16 @@ class Trainer:
         """Set-up's phases after construction: the caller's, from the return
         of `__init__` to the first `train_epoch`'s entry, then that epoch's,
         to the return of its fence. There the compile listener's set-up
-        totals freeze, and one line says where set-up went."""
+        totals freeze with the train programs' jit cache entries (one a
+        program used), and one line says where set-up went."""
         span = self._setup
         span.close()
         if span.name == "caller":
             self._setup = setup_span("first_epoch")
         else:
             self._setup = None
-            self._compiles.freeze()
+            self._compiles.freeze(step_entries=sum(
+                p.run._cache_size() for p in self._programs.values()))
 
     def _publish_rate(self) -> None:
         """The meter's rate as a gauge, under the name of what it counts."""
@@ -2326,7 +2353,7 @@ class Trainer:
             if state is not None:
                 self.state = self._place_state(state)
             else:
-                self.state = self._fresh_state()
+                self.state = self._place_state(self._fresh_state())
         else:
             from jax.experimental import multihost_utils
 
@@ -2580,7 +2607,7 @@ class Trainer:
             # land distributed, not replicated).
             self.state = self._place_state(state)
         else:
-            self.state = target  # nothing on disk: restart from init
+            self.state = self._place_state(target)  # nothing on disk: init
         self._host_step = int(resume.get("global_step", 0))
         # The codec-stats publish marker rewinds with the step clock (a
         # rollback-flavor regroup replays below the old high-water mark).
@@ -2695,8 +2722,20 @@ class Trainer:
         return position
 
     def _place_state(self, state):
-        """Device-place a host-restored TrainState under the current
-        mesh + update-sharding layout (`train/step._state_shardings`)."""
+        """The TrainState with every leaf committed where the train
+        programs return it: the current mesh + update-sharding layout
+        (`train/step._state_shardings`).
+
+        The train programs' jit keys its cache on where each argument
+        sits: a state that reaches the first dispatch anywhere else (a
+        fresh state, uncommitted on one device; a caller's swapped-in
+        leaves) makes every program again, traced, lowered and loaded, at
+        its second dispatch. Only the leaves off their target move, and a
+        state already in place is returned as it is: a check of each
+        leaf's placement, no device work. A leaf on its one target device
+        is re-placed in its own buffer (aliased), so the state is never
+        held twice; host leaves (a restore's numpy) are transferred.
+        """
         from tpu_dp.train.state import TrainState
         from tpu_dp.train.step import _state_shardings
 
@@ -2715,7 +2754,16 @@ class Trainer:
             )
         else:
             sh = jax.tree_util.tree_map(lambda _: sh, state)
-        return jax.device_put(state, sh)
+        leaves, treedef = jax.tree_util.tree_flatten(state)
+        targets = treedef.flatten_up_to(sh)
+        off = [i for i, (x, s) in enumerate(zip(leaves, targets))
+               if not (isinstance(x, jax.Array) and x.committed
+                       and x.sharding == s)]
+        if not off:
+            return state
+        for i in off:
+            leaves[i] = _place_leaf(leaves[i], targets[i])
+        return treedef.unflatten(leaves)
 
     def _rebuild_observers(self, record) -> None:
         """Re-home heartbeats/health for a new membership epoch."""
